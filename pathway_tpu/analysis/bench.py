@@ -212,7 +212,6 @@ BENCH_METRIC_PLANS: dict[str, tuple[str, int]] = {
     "wordcount_2rank_rows_per_s": ("wordcount", 2),
     "stream_join_rows_per_s": ("stream_join", 1),
     "transform_rows_per_s": ("transform", 1),
-    "rag_colocated_qps": ("serving", 1),
 }
 
 # BENCH_full.json DEVICE metric name -> Device Doctor chain whose static
@@ -226,8 +225,6 @@ BENCH_DEVICE_METRIC_CHAINS: dict[str, str] = {
     "rag_query_p50_ms": "knn",
     "rag_under_load_p50_ms": "knn",
     "rag_qps_vs_clients": "knn",
-    "rag_latency_model": "knn",
-    "rag_colocated_qps": "knn",
     "rag_update_while_serving_p50_ms": "knn",
     "ann_recall_at_10": "knn",
     "device_trace_overhead": "encoder",

@@ -172,7 +172,7 @@ KNOBS: dict[str, Knob] = {
            "Back vector-index adapters with the pod-sharded HBM index "
            "over an N-device data-parallel mesh (one corpus shard per "
            "chip, queries broadcast, per-shard fused matmul+top-k, "
-           "merged over ICI). Unset/0/1 = single-chip shard; ignored "
+           "merged over ICI). Unset/0/1 = single-chip shard; an error "
            "when fewer than N devices are visible.", lo=0, hi=4096),
         _k("PATHWAY_INDEX_MERGE", "enum", "auto",
            "Cross-shard top-k merge strategy for the sharded index: "

@@ -12,6 +12,41 @@ import os
 import runpy
 import subprocess
 import sys
+import time
+
+
+def _wait_ranks(procs: list) -> int:
+    """Wait for every rank. The first rank to fail stops the others and
+    its code is the launcher's: a rank that died at start-up (two ranks
+    that each build an embedder fight for the one chip, and the loser
+    dies with libtpu's "already in use" / lockfile message) otherwise
+    leaves its peers waiting out the mesh connect timeout before anyone
+    learns why."""
+    pending = dict(enumerate(procs))
+    while pending:
+        for rank, proc in list(pending.items()):
+            rc = proc.poll()
+            if rc is None:
+                continue
+            del pending[rank]
+            if rc == 0:
+                continue
+            print(
+                f"pathway spawn: rank {rank} exited with code {rc}; "
+                f"stopping {len(pending)} other rank(s)",
+                file=sys.stderr,
+            )
+            for other in pending.values():
+                other.terminate()
+            for other in pending.values():
+                try:
+                    other.wait(timeout=10)
+                except subprocess.TimeoutExpired:
+                    other.kill()
+                    other.wait()
+            return rc
+        time.sleep(0.05)
+    return 0
 
 
 def _spawn(args) -> int:
@@ -30,10 +65,7 @@ def _spawn(args) -> int:
                     [sys.executable, program, *args.arguments], env=child_env
                 )
             )
-        rc = 0
-        for p in procs:
-            rc = rc or p.wait()
-        return rc
+        return _wait_ranks(procs)
     env["PATHWAY_PROCESS_ID"] = "0"
     os.environ.update(env)
     sys.argv = [program, *args.arguments]

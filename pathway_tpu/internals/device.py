@@ -230,6 +230,44 @@ def platform_info() -> dict | None:
     }
 
 
+# -- persistent compile cache -------------------------------------------------
+
+# the checkout this package was loaded from: pathway_tpu/internals/device.py
+# -> three levels up. Derived from the file path, not from git — the copy
+# on the chip machine is not a repository.
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def place_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a fixed home before the
+    first compile; returns the directory in effect.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this sets
+    nothing. Unset: ``<checkout>/.jax_cache`` — the directory is part of
+    the cache key, so it is never a temp name, a pid or a time. A
+    process pinned to the CPU (``JAX_PLATFORMS=cpu``: tests, CI lanes)
+    gets no cache: XLA:CPU logs a machine-feature mismatch error on
+    every reload of its own cached executable, and nothing at test size
+    compiles long enough to be kept. Called at import by
+    models/encoder.py and ops/topk.py — every dense-plane module
+    imports one of them (``pathway_tpu.ops`` and ``pathway_tpu.parallel``
+    load topk from their ``__init__``) and both import jax at module
+    scope themselves: this function is never the reason jax loads into
+    a relational-only process."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    if jax.config.jax_platforms == "cpu":
+        return ""
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # -- compiled cost analysis (cached per shape key) ---------------------------
 
 _COST_CACHE: dict = {}
@@ -273,8 +311,6 @@ def compiled_cost(
     if fn is not None and not _env_off("PATHWAY_DEVICE_COST_ANALYSIS"):
         try:
             ca = fn.lower(*args).compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
             ca_flops = float(ca.get("flops", 0.0) or 0.0)
             ca_bytes = float(ca.get("bytes accessed", 0.0) or 0.0)
             if ca_flops > 0:
@@ -767,8 +803,24 @@ _TRANSIENT_MARKERS = (
 )
 # a failed dispatch may have consumed its donated input buffers — a
 # retry would compute on deleted arrays; classify as permanent so the
-# epoch rolls back to buffers the snapshot actually holds
-_PERMANENT_MARKERS = ("donated", "deleted", "invalid buffer")
+# epoch rolls back to buffers the snapshot actually holds.
+# The second row is libtpu 0.0.34's own wording, seen on a v5e (PR 21):
+# a kernel whose window outgrows VMEM fails to COMPILE with
+#   "RESOURCE_EXHAUSTED: Allocation (size=..) would exceed memory
+#    (size=134217728) :: #allocation7 [shape = 'u8[..]', space=vmem, .."
+# — deterministic, so it must abort rather than read as HBM pressure
+# and brown the gateway out; and a chip held by another process is
+#   "ABORTED: The TPU is already in use by process with pid N" or, when
+#   two start at once, "ABORTED: Internal error when accessing libtpu
+#   multi-process lockfile" (both under "Unable to initialize backend")
+# — no retry frees it. HBM exhaustion reads "RESOURCE_EXHAUSTED: Error
+# allocating device buffer: Attempting to allocate 24.00G. ..(0x0x0_HBM0)"
+# and stays with the OOM markers.
+_PERMANENT_MARKERS = (
+    "donated", "deleted", "invalid buffer",
+    "space=vmem", "already in use", "lockfile",
+    "unable to initialize backend",
+)
 
 
 class DeviceOom(RuntimeError):

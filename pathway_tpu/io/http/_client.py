@@ -99,6 +99,13 @@ class KeepAliveSession:
         conn.connect()
         return conn
 
+    @property
+    def last_headers(self) -> dict:
+        """Response headers of this thread's most recent request — where
+        the backpressure contract rides on a 200 too (``Degraded`` on a
+        brownout answer)."""
+        return getattr(self._local, "headers", {})
+
     def close(self) -> None:
         conn = getattr(self._local, "conn", None)
         if conn is not None:
@@ -130,10 +137,9 @@ class KeepAliveSession:
                 time.sleep(max(0.0, min(delay, self.max_retry_wait_s)))
                 continue
             break
+        self._local.headers = dict(resp.getheaders())
         if resp.status >= 400:
-            raise HttpError(
-                resp.status, data, headers=dict(resp.getheaders())
-            )
+            raise HttpError(resp.status, data, headers=self._local.headers)
         if not data:
             return None
         return _json.loads(data.decode())

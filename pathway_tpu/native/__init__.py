@@ -11,6 +11,9 @@ working when no toolchain is present.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import importlib.util
+import logging
 import os
 import subprocess
 import sysconfig
@@ -19,62 +22,121 @@ from typing import Any, Sequence
 
 import numpy as np
 
+_LOG = logging.getLogger(__name__)
+
 _REPO_NATIVE = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "native")
 # override point for instrumented builds (scripts/sanitize_native.sh
-# compiles the extensions with ASAN/TSAN into a scratch dir)
-_BUILD_DIR = os.environ.get("PATHWAY_NATIVE_BUILD_DIR") or os.path.join(
+# compiles the extensions with ASAN/TSAN into a scratch dir). Whoever
+# set the override owns that directory: a binary found there is loaded
+# as it is, never rebuilt over.
+_PREBUILT_DIR = os.environ.get("PATHWAY_NATIVE_BUILD_DIR")
+_BUILD_DIR = _PREBUILT_DIR or os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "_build"
 )
 _LOCK = threading.Lock()
 _LIB: ctypes.CDLL | None = None
 _TRIED = False
+# name -> fingerprint of the sources + compile command each loaded
+# binary was built from (chip_smoke.py prints these)
+_FINGERPRINTS: dict[str, str] = {}
 
 
-def _newest_mtime(src_dir: str, src: str) -> float:
-    """Staleness input for an extension build: the source file plus any
-    shared headers it includes (pw_blake2b.h) — a header-only change must
-    trigger a rebuild too."""
-    newest = os.path.getmtime(src)
-    hdr = os.path.join(src_dir, "pw_blake2b.h")
-    if os.path.exists(hdr):
-        newest = max(newest, os.path.getmtime(hdr))
-    return newest
+def _source_dir() -> str:
+    if os.path.isdir(_REPO_NATIVE):
+        return _REPO_NATIVE
+    # installed layout: sources shipped next to this package
+    return os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
 
 
-def _sources() -> list[str]:
-    src_dir = _REPO_NATIVE
-    if not os.path.isdir(src_dir):
-        # installed layout: sources shipped next to this package
-        src_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
-    return [
-        os.path.join(src_dir, "bm25.cpp"),
-        os.path.join(src_dir, "hnsw.cpp"),
-    ]
+def _fingerprint(compiler: Sequence[str], files: Sequence[str]) -> str:
+    """Hash of the compile command and the CONTENTS of every source and
+    header it reads. Staleness is decided on this, not on mtimes: a tree
+    copied with an old ``_build/`` beside fresh-mtimed sources (or the
+    reverse) must not load a binary that does not match its source."""
+    h = hashlib.sha256("\0".join(compiler).encode())
+    for path in files:
+        h.update(b"\0" + os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
 
 
-def _build() -> str | None:
-    sources = _sources()
+def _compile(
+    name: str,
+    out_name: str,
+    compiler: Sequence[str],
+    sources: Sequence[str],
+    headers: Sequence[str] = (),
+    *,
+    timeout: float = 180,
+) -> str | None:
+    """Path of the up-to-date binary for ``sources``, building it when
+    the stamp beside it does not match :func:`_fingerprint`; None (with
+    a logged warning carrying the compiler's stderr tail) when a source
+    is missing or the build fails — callers fall back to pure Python."""
     if not all(os.path.exists(s) for s in sources):
         return None
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    out = os.path.join(_BUILD_DIR, "libpathway_native.so")
-    stamp = os.path.join(_BUILD_DIR, "build.stamp")
-    newest_src = max(os.path.getmtime(s) for s in sources)
-    if os.path.exists(out) and os.path.exists(stamp):
-        if os.path.getmtime(stamp) >= newest_src:
-            return out
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-        "-o", out, *sources,
-    ]
+    out = os.path.join(_BUILD_DIR, out_name)
+    if _PREBUILT_DIR and os.path.exists(out):
+        _FINGERPRINTS[name] = "prebuilt"
+        return out
+    headers = [h for h in headers if os.path.exists(h)]
+    want = _fingerprint(compiler, [*sources, *headers])
+    stamp = out + ".stamp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-    except Exception:
-        return None
-    with open(stamp, "w") as f:
-        f.write("ok")
+        with open(stamp) as f:
+            fresh = os.path.exists(out) and f.read().strip() == want
+    except OSError:
+        fresh = False
+    if not fresh:
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        # build beside the target and rename into place: a concurrent
+        # process (another rank, another test) never loads a half-
+        # written binary
+        tmp = f"{out}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                [*compiler, "-o", tmp, *sources],
+                check=True, capture_output=True, timeout=timeout,
+            )
+            os.replace(tmp, out)
+        except (subprocess.SubprocessError, OSError) as exc:
+            # a failed build silently drops this library (callers run in
+            # pure Python) — make the degradation visible. g++ 10 works
+            # (exec.cpp gates its C++20 library uses); g++ < 10 rejects
+            # -std=c++20
+            stderr = getattr(exc, "stderr", None) or b""
+            _LOG.warning(
+                "native build of %s failed (%s): %s",
+                out_name, exc, stderr[-500:],
+            )
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+            return None
+        with open(stamp, "w") as f:
+            f.write(want)
+    _FINGERPRINTS[name] = want
     return out
+
+
+def loaded_fingerprints() -> dict[str, str]:
+    """``{library: source+command fingerprint}`` for every native binary
+    this process built or found fresh — what the loaded code was
+    compiled from."""
+    return dict(_FINGERPRINTS)
+
+
+def _import_extension(name: str, path: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    try:
+        spec.loader.exec_module(mod)
+    except ImportError as exc:
+        _LOG.warning("native extension %s failed to import: %s", path, exc)
+        _FINGERPRINTS.pop(name, None)
+        return None
+    return mod
 
 
 def get_lib() -> ctypes.CDLL | None:
@@ -84,7 +146,13 @@ def get_lib() -> ctypes.CDLL | None:
         if _TRIED:
             return _LIB
         _TRIED = True
-        path = _build()
+        src_dir = _source_dir()
+        path = _compile(
+            "libpathway_native", "libpathway_native.so",
+            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC"],
+            [os.path.join(src_dir, "bm25.cpp"),
+             os.path.join(src_dir, "hnsw.cpp")],
+        )
         if path is None:
             return None
         lib = ctypes.CDLL(path)
@@ -106,13 +174,12 @@ def get_lib() -> ctypes.CDLL | None:
         lib.hnsw_add.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.POINTER(ctypes.c_float)
         ]
-        if hasattr(lib, "hnsw_add_batch"):
-            lib.hnsw_add_batch.argtypes = [
-                ctypes.c_void_p,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_float),
-                ctypes.c_int64,
-            ]
+        lib.hnsw_add_batch.argtypes = [
+            ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_float),
+            ctypes.c_int64,
+        ]
         lib.hnsw_remove.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         lib.hnsw_len.restype = ctypes.c_int64
         lib.hnsw_len.argtypes = [ctypes.c_void_p]
@@ -129,6 +196,23 @@ def available() -> bool:
     return get_lib() is not None
 
 
+def _python_extension(name: str, compiler: list[str], source: str,
+                      timeout: float):
+    """Build (if stale) and import one CPython extension from
+    ``native/<source>``; shared headers count toward staleness."""
+    src_dir = _source_dir()
+    suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
+    include = sysconfig.get_paths()["include"]
+    path = _compile(
+        name, name + suffix,
+        [*compiler, f"-I{include}"],
+        [os.path.join(src_dir, source)],
+        [os.path.join(src_dir, "pw_blake2b.h")],
+        timeout=timeout,
+    )
+    return None if path is None else _import_extension(name, path)
+
+
 _FASTPATH = None
 _FASTPATH_TRIED = False
 
@@ -142,37 +226,10 @@ def get_fastpath():
         if _FASTPATH_TRIED:
             return _FASTPATH
         _FASTPATH_TRIED = True
-        src_dir = _REPO_NATIVE if os.path.isdir(_REPO_NATIVE) else os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "src"
+        _FASTPATH = _python_extension(
+            "fastpath", ["gcc", "-O3", "-shared", "-fPIC"], "fastpath.c",
+            timeout=120,
         )
-        src = os.path.join(src_dir, "fastpath.c")
-        if not os.path.exists(src):
-            return None
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-        suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-        out = os.path.join(_BUILD_DIR, "fastpath" + suffix)
-        if not (
-            os.path.exists(out)
-            and os.path.getmtime(out) >= _newest_mtime(src_dir, src)
-        ):
-            include = sysconfig.get_paths()["include"]
-            cmd = [
-                "gcc", "-O3", "-shared", "-fPIC",
-                f"-I{include}", "-o", out, src,
-            ]
-            try:
-                subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location("fastpath", out)
-        mod = importlib.util.module_from_spec(spec)
-        try:
-            spec.loader.exec_module(mod)
-        except Exception:
-            return None
-        _FASTPATH = mod
         return _FASTPATH
 
 
@@ -189,49 +246,11 @@ def get_pwexec():
         if _PWEXEC_TRIED:
             return _PWEXEC
         _PWEXEC_TRIED = True
-        src_dir = _REPO_NATIVE if os.path.isdir(_REPO_NATIVE) else os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "src"
+        _PWEXEC = _python_extension(
+            "pwexec",
+            ["g++", "-O3", "-std=c++20", "-shared", "-fPIC", "-pthread"],
+            "exec.cpp", timeout=180,
         )
-        src = os.path.join(src_dir, "exec.cpp")
-        if not os.path.exists(src):
-            return None
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-        suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-        out = os.path.join(_BUILD_DIR, "pwexec" + suffix)
-        if not (
-            os.path.exists(out)
-            and os.path.getmtime(out) >= _newest_mtime(src_dir, src)
-        ):
-            include = sysconfig.get_paths()["include"]
-            cmd = [
-                "g++", "-O3", "-std=c++20", "-shared", "-fPIC", "-pthread",
-                f"-I{include}", "-o", out, src,
-            ]
-            try:
-                subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-            except Exception as exc:
-                # a failed build silently drops the whole native executor
-                # (group-by/join fall back to pure Python) — make the
-                # degradation visible. g++ 10 works (exec.cpp gates its
-                # C++20 library uses); g++ < 10 rejects -std=c++20
-                import logging
-
-                stderr = getattr(exc, "stderr", None) or b""
-                logging.getLogger(__name__).warning(
-                    "native executor build failed (%s): %s",
-                    exc,
-                    stderr[-500:],
-                )
-                return None
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location("pwexec", out)
-        mod = importlib.util.module_from_spec(spec)
-        try:
-            spec.loader.exec_module(mod)
-        except Exception:
-            return None
-        _PWEXEC = mod
         return _PWEXEC
 
 
@@ -300,16 +319,11 @@ class NativeHnsw:
     def add_batch(self, keys, vecs) -> None:
         """Insert n rows in ONE library crossing (ISSUE 16: the
         one-doc-per-dispatch ann build was dominated by per-row call
-        overhead). Falls back to per-row adds on a stale library built
-        before the batch entry point existed."""
+        overhead)."""
         ks = np.ascontiguousarray(keys, dtype=np.int64)
         vs = np.ascontiguousarray(vecs, dtype=np.float32)
         if vs.ndim != 2 or vs.shape[0] != ks.shape[0]:
             raise ValueError("keys/vectors shape mismatch")
-        if not hasattr(self._lib, "hnsw_add_batch"):
-            for k, v in zip(ks, vs):
-                self.add(int(k), v)
-            return
         self._lib.hnsw_add_batch(
             self._h,
             ks.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),

@@ -10,7 +10,13 @@ which bounds memory by query-batching instead; we bound it by db-blocking,
 which keeps query batches intact for the MXU.
 
 Top-k inside the kernel is K-step selection (max + mask-out), K static and
-small; `jax.lax.top_k` does not lower inside Pallas TPU kernels.
+small; `jax.lax.top_k` does not lower inside Pallas TPU kernels. Every
+array the kernel touches is 2-D with a lane (last) dimension that is a
+multiple of 128, which is what Mosaic lowers: the running top-k is held
+K-padded to ``_LANES``, the candidate tile is the lane-aligned
+concatenation [running | block], each step's winner is located with
+max + first-matching-lane (no lane argmax) and written back with a lane
+select (no stacking of 1-D vectors).
 """
 
 from __future__ import annotations
@@ -29,6 +35,12 @@ from pathway_tpu.internals.device import (
 )
 
 NEG_INF = float("-inf")
+_LANES = 128
+_SUBLANES = 8
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _knn_kernel(q_ref, db_ref, mask_ref, out_v_ref, out_i_ref, sv_ref, si_ref,
@@ -41,32 +53,40 @@ def _knn_kernel(q_ref, db_ref, mask_ref, out_v_ref, out_i_ref, sv_ref, si_ref,
         sv_ref[:] = jnp.full(sv_ref.shape, NEG_INF, jnp.float32)
         si_ref[:] = jnp.zeros(si_ref.shape, jnp.int32)
 
-    scores = jnp.dot(
-        q_ref[:], db_ref[:].T,
+    # [Q, D] x [B, D] contracted on D: the transposed-rhs matmul the MXU
+    # takes natively, no in-kernel transpose of the database block
+    scores = jax.lax.dot_general(
+        q_ref[:], db_ref[:],
+        dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST,
-    ) + mask_ref[:]                                       # [Q, B]
-    q = scores.shape[0]
-    base = j * block
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, (q, block), 1) + base
+    ) + mask_ref[:]                                        # [Q, B]
+    q, kp = sv_ref.shape
+    col_ids = jax.lax.broadcasted_iota(jnp.int32, (q, block), 1) + j * block
 
-    cand_v = jnp.concatenate([sv_ref[:], scores], axis=1)  # [Q, K+B]
+    # running entries first: they come from earlier blocks, so on equal
+    # scores the first matching lane is the lower database index — the
+    # same tie-break as lax.top_k over the whole row
+    cand_v = jnp.concatenate([sv_ref[:], scores], axis=1)  # [Q, KP+B]
     cand_i = jnp.concatenate([si_ref[:], col_ids], axis=1)
-    width = k + block
-    iota = jax.lax.broadcasted_iota(jnp.int32, (q, width), 1)
+    width = kp + block
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q, width), 1)
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, (q, kp), 1)
 
-    new_v = []
-    new_i = []
-    for _ in range(k):
-        m = jnp.max(cand_v, axis=1)                        # [Q]
-        am = jnp.argmax(cand_v, axis=1)                    # [Q]
-        hit = iota == am[:, None]
-        sel_i = jnp.sum(jnp.where(hit, cand_i, 0), axis=1)
-        new_v.append(m)
-        new_i.append(sel_i)
+    new_v = jnp.full((q, kp), NEG_INF, jnp.float32)
+    new_i = jnp.zeros((q, kp), jnp.int32)
+    for t in range(k):
+        m = jnp.max(cand_v, axis=1, keepdims=True)         # [Q, 1]
+        first = jnp.min(
+            jnp.where(cand_v == m, lane, width), axis=1, keepdims=True
+        )
+        hit = lane == first
+        sel_i = jnp.sum(jnp.where(hit, cand_i, 0), axis=1, keepdims=True)
+        new_v = jnp.where(out_lane == t, m, new_v)
+        new_i = jnp.where(out_lane == t, sel_i, new_i)
         cand_v = jnp.where(hit, NEG_INF, cand_v)
-    sv_ref[:] = jnp.stack(new_v, axis=1)
-    si_ref[:] = jnp.stack(new_i, axis=1)
+    sv_ref[:] = new_v
+    si_ref[:] = new_i
 
     @pl.when(j == nb - 1)
     def _flush():
@@ -80,13 +100,17 @@ def pallas_knn_cost(
     """Analytical ``(flops, hbm_bytes_accessed)`` of the fused kernel —
     the device plane's cost model for this dispatch site. FLOPs: the
     per-block score matmul (2·q·block·d MACs per grid step = 2·q·cap·d
-    total) plus K selection sweeps over the [q, k+block] candidate tile
-    (~3 ops per candidate per step). Bytes: the database streams from
-    HBM once, the query tile re-reads per grid step (its BlockSpec maps
-    every step to the same [q, d] tile), and the running top-k lives in
-    VMEM scratch — only the final [q, k] pair lands back in HBM."""
+    total) plus K selection sweeps over the [q, kp+block] candidate tile
+    (k padded to the lane width; ~3 ops per candidate per step). Bytes:
+    the database streams from HBM once, the query tile re-reads per grid
+    step (its BlockSpec maps every step to the same [q, d] tile), and
+    the running top-k lives in VMEM scratch — only the final [q, k] pair
+    lands back in HBM."""
     nb = max(1, cap // block)
-    flops = 2.0 * q * cap * d + 3.0 * k * q * (k + block) * nb
+    flops = (
+        2.0 * q * cap * d
+        + 3.0 * k * q * (_round_up(k, _LANES) + block) * nb
+    )
     bytes_accessed = (
         4.0 * cap * d          # database blocks, streamed once
         + 4.0 * q * d * nb     # query tile, re-fetched per grid step
@@ -162,30 +186,42 @@ def _pallas_topk_scores_jit(
 ):
     q, d = queries.shape
     cap = database.shape[0]
-    assert cap % block == 0, "capacity must be a multiple of block"
+    if cap % block:
+        raise ValueError("capacity must be a multiple of block")
+    if block % _LANES and not interpret:
+        raise ValueError(
+            f"block must be a multiple of {_LANES} lanes on the compiled "
+            f"path, got {block}"
+        )
     nb = cap // block
+    # K padded to a lane multiple, Q to a sublane multiple: the kernel
+    # only ever sees (8, 128)-tileable arrays; both pads are sliced off
+    kp = _round_up(k, _LANES)
+    qp = _round_up(q, _SUBLANES)
+    if qp != q:
+        queries = jnp.pad(queries, ((0, qp - q), (0, 0)))
 
     kernel = functools.partial(_knn_kernel, k=k, block=block)
     out_v, out_i = pl.pallas_call(
         kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((q, d), lambda j: (0, 0)),
+            pl.BlockSpec((qp, d), lambda j: (0, 0)),
             pl.BlockSpec((block, d), lambda j: (j, 0)),
             pl.BlockSpec((1, block), lambda j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((q, k), lambda j: (0, 0)),
-            pl.BlockSpec((q, k), lambda j: (0, 0)),
+            pl.BlockSpec((qp, kp), lambda j: (0, 0)),
+            pl.BlockSpec((qp, kp), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q, k), jnp.float32),
-            jax.ShapeDtypeStruct((q, k), jnp.int32),
+            jax.ShapeDtypeStruct((qp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((qp, kp), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((q, k), jnp.float32),
-            pltpu.VMEM((q, k), jnp.int32),
+            pltpu.VMEM((qp, kp), jnp.float32),
+            pltpu.VMEM((qp, kp), jnp.int32),
         ],
         interpret=interpret,
     )(queries, database, add_mask[None, :])
-    return out_v, out_i
+    return out_v[:q, :k], out_i[:q, :k]

@@ -82,7 +82,7 @@ class QueryEngine:
         """Phase 1: tokenize + launch the fused executable. Returns an
         opaque (device_array, n) ticket without blocking — dispatch is
         asynchronous, so the caller can have several tickets in flight
-        (the readbacks overlap on tunneled transports)."""
+        and their readbacks overlap."""
         from pathway_tpu.models.encoder import pad_batch
 
         ids, mask = self.encoder.tokenizer(texts)
@@ -171,9 +171,9 @@ class MicroBatcher:
 
     Two-stage pipeline: the collector thread tokenizes + dispatches
     (asynchronous, sub-ms), a pool of readback threads blocks on the
-    device->host transfers — so on a tunneled transport several batches'
-    readbacks ride the link concurrently and throughput is bounded by
-    device work, not one round-trip per batch.
+    device->host transfers — several batches' readbacks are in flight
+    at once, so throughput is bounded by device work, not by one
+    readback round trip per batch.
     """
 
     def __init__(

@@ -12,6 +12,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from pathway_tpu.internals.device import place_compile_cache
+
+place_compile_cache()
+
 # plain float, like pallas_knn: a module-scope jnp.float32() would jit a
 # convert_element_type at IMPORT time (slow, and it drags XLA compilation
 # into processes that only need the relational plane — e.g. the ASan CI

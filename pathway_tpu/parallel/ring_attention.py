@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pathway_tpu.parallel._compat import compat_shard_map
-
 
 def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
                           sm_scale: float):
@@ -120,8 +118,9 @@ def ring_attention(
         causal=causal,
         sm_scale=sm_scale,
     )
-    fn = compat_shard_map(
-        local, mesh, in_specs=(spec, spec, spec), out_specs=spec
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)
 

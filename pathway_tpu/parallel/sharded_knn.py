@@ -54,7 +54,6 @@ from pathway_tpu.ops.topk import (
     topk_scan_cost,
     tree_merge_topk,
 )
-from pathway_tpu.parallel._compat import compat_shard_map
 from pathway_tpu.parallel.procgroup import shard_hash
 from pathway_tpu.parallel.protocol import shard_owner
 
@@ -90,6 +89,22 @@ device_site(
     description="per-shard fused matmul+top-k with tree/gather merge "
                 "over the mesh axis",
 )
+
+
+def _empty_triple(capacity: int, dimension: int, db_sharding, row_sharding):
+    """The zeroed (vectors, valid, sq_norms) triple, allocated sharded
+    from the start: each device materializes only its own rows, so no
+    full-capacity buffer is first built on one chip and then scattered
+    (which works at smoke size and cannot at a size that fills the
+    mesh)."""
+    return jax.jit(
+        lambda: (
+            jnp.zeros((capacity, dimension), jnp.float32),
+            jnp.zeros((capacity,), bool),
+            jnp.zeros((capacity,), jnp.float32),
+        ),
+        out_shardings=(db_sharding, row_sharding, row_sharding),
+    )()
 
 
 def make_sharded_write(mesh: Mesh, axis: str):
@@ -174,10 +189,10 @@ def sharded_topk(
         return best_v, best_i
 
     # all_gather/ppermute make the outputs replicated, but the vma
-    # checker can't see that through lax.top_k — the shared compat shim
-    # disables the check
-    smapped = compat_shard_map(
-        local, mesh, in_specs=tuple(in_specs), out_specs=(P(), P())
+    # checker can't see that through lax.top_k
+    smapped = jax.shard_map(
+        local, mesh=mesh, in_specs=tuple(in_specs), out_specs=(P(), P()),
+        check_vma=False,
     )
     return smapped(queries, database, valid, *((sq_norms,) if use_sq else ()))
 
@@ -253,15 +268,9 @@ class ShardedKnnIndex:
         self._db_sharding = NamedSharding(mesh, P(axis, None))
         self._row_sharding = NamedSharding(mesh, P(axis))
         self._repl = NamedSharding(mesh, P())
-        self.vectors = jax.device_put(
-            jnp.zeros((self.capacity, self.dimension), jnp.float32),
-            self._db_sharding,
-        )
-        self.valid = jax.device_put(
-            jnp.zeros((self.capacity,), bool), self._row_sharding
-        )
-        self.sq_norms = jax.device_put(
-            jnp.zeros((self.capacity,), jnp.float32), self._row_sharding
+        self.vectors, self.valid, self.sq_norms = _empty_triple(
+            self.capacity, self.dimension,
+            self._db_sharding, self._row_sharding,
         )
         # writers donate the buffer triple — same update-while-serving
         # lock discipline as ops.knn.KnnShard
@@ -381,11 +390,11 @@ class ShardedKnnIndex:
             )
             new_free.append(fresh + shifted)
         try:
-            dev_vec = jax.device_put(jnp.asarray(new_vec), self._db_sharding)
-            dev_valid = jax.device_put(
-                jnp.asarray(new_valid), self._row_sharding
-            )
-            dev_sq = jax.device_put(jnp.asarray(new_sq), self._row_sharding)
+            # host arrays go straight to their owning shards (a
+            # jnp.asarray first would land the whole buffer on chip 0)
+            dev_vec = jax.device_put(new_vec, self._db_sharding)
+            dev_valid = jax.device_put(new_valid, self._row_sharding)
+            dev_sq = jax.device_put(new_sq, self._row_sharding)
         except BaseException as exc:
             if _devsup.classify_device_error(exc) == "oom":
                 _devsup.notify_oom("knn.sharded_grow")
@@ -553,15 +562,9 @@ class ShardedKnnIndex:
         ]
         self.remove_epoch = 0
         self.slot_freed_epoch = np.full(self.capacity, -1, np.int64)
-        self.vectors = jax.device_put(
-            jnp.zeros((self.capacity, self.dimension), jnp.float32),
-            self._db_sharding,
-        )
-        self.valid = jax.device_put(
-            jnp.zeros((self.capacity,), bool), self._row_sharding
-        )
-        self.sq_norms = jax.device_put(
-            jnp.zeros((self.capacity,), jnp.float32), self._row_sharding
+        self.vectors, self.valid, self.sq_norms = _empty_triple(
+            self.capacity, self.dimension,
+            self._db_sharding, self._row_sharding,
         )
         if not n:
             return

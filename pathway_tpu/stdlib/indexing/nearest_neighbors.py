@@ -172,8 +172,9 @@ def _auto_mesh():
     """PATHWAY_INDEX_SHARDS=N (N>1): back the adapter with the
     pod-sharded HBM index over an N-device data-parallel mesh without
     any code change — one shard of the corpus per chip (ISSUE 16).
-    Returns None (single-chip KnnShard) when unset, 0/1, malformed, or
-    when fewer than N devices are visible."""
+    Returns None (single-chip KnnShard) when unset, 0/1 or malformed.
+    Asking for N shards with fewer than N visible devices raises: a
+    silent one-chip index would hide the missing devices."""
     raw = os.environ.get("PATHWAY_INDEX_SHARDS", "").strip()
     if not raw:
         return None
@@ -185,8 +186,12 @@ def _auto_mesh():
         return None
     import jax
 
-    if len(jax.devices()) < n:
-        return None
+    visible = len(jax.devices())
+    if visible < n:
+        raise RuntimeError(
+            f"PATHWAY_INDEX_SHARDS={n} but only {visible} "
+            f"{jax.default_backend()} device(s) are visible"
+        )
     from pathway_tpu.parallel.mesh import make_mesh
 
     return make_mesh(n, axes=("dp",), shape=(n,))

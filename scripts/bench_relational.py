@@ -831,9 +831,6 @@ def child(n_rows: int, distinct: int, batch: int, emit=_print_emit) -> None:
     the threads=1 baseline so parent and thread-curve children share one
     measurement policy."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     _wordcount_once(n_rows, distinct, batch)  # warmup: build + imports
     runs = [_wordcount_once(n_rows, distinct, batch)[1] for _ in range(3)]
     emit(_median_of(runs, [r["value"] for r in runs]))
@@ -844,7 +841,9 @@ def child(n_rows: int, distinct: int, batch: int, emit=_print_emit) -> None:
 def _run_child_capture(args: list[str], env: dict, emit) -> None:
     """Run a child bench process, re-emitting its JSON lines through the
     parent's emit so BENCH_full.json holds the full curve. A timeout
-    still salvages whatever lines the child managed to print."""
+    still salvages whatever lines the child managed to print; a failed
+    child becomes a ``bench_child_error`` line, which main() turns into
+    a non-zero exit once the remaining lanes have run."""
     stdout, stderr, exit_code = b"", b"", 0
     try:
         proc = subprocess.run(args, env=env, capture_output=True, timeout=900)
@@ -881,6 +880,15 @@ def main(
     # Always recorded — host_cores in the artifact says whether the host can
     # actually show the shard-thread speedup (a 1-core host shows parity).
     if os.environ.get("PATHWAY_THREADS", "1") == "1":
+        # a child lane that failed still lets the later lanes run, but
+        # the run as a whole must not exit 0 with a hole in its curve
+        failed: list[str] = []
+
+        def tracked(metric: dict) -> None:
+            if metric.get("metric") == "bench_child_error" or "error" in metric:
+                failed.append(str(metric.get("metric")))
+            emit(metric)
+
         for nthreads in ("4", "8"):
             env = dict(
                 os.environ, PATHWAY_THREADS=nthreads, JAX_PLATFORMS="cpu"
@@ -891,12 +899,16 @@ def main(
                     str(n_rows), str(distinct), str(batch), "--child",
                 ],
                 env,
-                emit,
+                tracked,
             )
-        bench_wordcount_2rank(n_rows, distinct, batch, emit=emit)
+        bench_wordcount_2rank(n_rows, distinct, batch, emit=tracked)
         # flight-recorder overhead lane: traced wordcount + stream_join
         # paired with fresh untraced runs (<= 3% acceptance bar)
-        bench_traced_overhead(n_rows, distinct, batch, emit=emit)
+        bench_traced_overhead(n_rows, distinct, batch, emit=tracked)
+        if failed:
+            raise SystemExit(
+                f"bench_relational: child lane(s) failed: {', '.join(failed)}"
+            )
 
 
 _RELATIONAL_METRICS = {

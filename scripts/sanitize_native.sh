@@ -61,7 +61,6 @@ gcc -O1 -g -shared -fPIC $SAN \
     -I"$PYINC" -o "$BUILD/fastpath$EXT" native/fastpath.c
 g++ -O1 -g -std=c++20 -shared -fPIC $SAN \
     -o "$BUILD/libpathway_native.so" native/bm25.cpp native/hnsw.cpp
-touch "$BUILD/build.stamp"
 
 echo "== running native batteries under $MODE =="
 # PATHWAY_THREADS=4 exercises the GIL-released shard threads (the TSAN

@@ -36,8 +36,6 @@ def test_flops_model_matches_xla_cost_analysis(monkeypatch):
         .compile()
     )
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns one entry per device
-        cost = cost[0]
     xla_flops = float(cost.get("flops", 0.0))
     assert xla_flops > 0, "cost_analysis returned no flops"
     model = forward_flops_per_token(cfg, L) * n * L

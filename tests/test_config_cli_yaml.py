@@ -62,6 +62,27 @@ def test_cli_spawn_runs_program(tmp_path):
     assert b"pid 0" in proc.stdout
 
 
+def test_cli_spawn_fails_with_the_first_failed_rank(tmp_path, capsys):
+    """A rank that dies at start-up (on a chip host: the rank that lost
+    the race for the device) must fail the launch at once, not after its
+    peers waited out the mesh connect timeout."""
+    import time
+
+    from pathway_tpu import cli
+
+    prog = tmp_path / "prog.py"
+    prog.write_text(
+        "import os, sys, time\n"
+        "if os.environ['PATHWAY_PROCESS_ID'] == '1':\n"
+        "    sys.exit(3)\n"
+        "time.sleep(120)\n"
+    )
+    t0 = time.monotonic()
+    assert cli.main(["spawn", "-n", "2", str(prog)]) == 3
+    assert time.monotonic() - t0 < 30
+    assert "rank 1 exited with code 3" in capsys.readouterr().err
+
+
 def test_metrics_http_server(monkeypatch):
     import os
 

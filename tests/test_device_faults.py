@@ -126,6 +126,24 @@ def test_classifier_feeds_the_transition():
     assert devsup.classify_device_error(
         devsup.WatchdogTimeout("hung")
     ) == "permanent"
+    # libtpu 0.0.34's own messages, as a v5e printed them (PR 21): a
+    # kernel that outgrows VMEM at compile time and a chip held by
+    # another process are permanent; only HBM exhaustion browns out
+    for text, kind in (
+        ("RESOURCE_EXHAUSTED: Allocation (size=201326592) would exceed "
+         "memory (size=134217728) :: #allocation7 [shape = "
+         "'u8[201326592]{0}', space=vmem, size = 0xc000000, tag = 'input "
+         "window allocation for operator input 1.']", "permanent"),
+        ("Unable to initialize backend 'tpu': ABORTED: The TPU is already "
+         "in use by process with pid 608. Not attempting to load "
+         "libtpu.so in this process.", "permanent"),
+        ("Unable to initialize backend 'tpu': ABORTED: Internal error "
+         "when accessing libtpu multi-process lockfile.", "permanent"),
+        ("RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting "
+         "to allocate 24.00G. That was not possible. There are 15.56G "
+         "free.; (0x0x0_HBM0)", "oom"),
+    ):
+        assert devsup.classify_device_error(RuntimeError(text)) == kind, text
     inj = faults.InjectedFault("device.dispatch", 1, retryable=True)
     assert devsup.classify_device_error(inj) == "transient"
     inj = faults.InjectedFault("device.dispatch", 1, retryable=False)

@@ -41,3 +41,32 @@ def test_cross_encoder_scores():
     assert np.isfinite(scores).all()
     again = ce.score([("query", "relevant doc")])
     np.testing.assert_allclose(scores[0], again[0], atol=2e-2)
+
+
+def test_reference_forward_matches_flax_module_in_float32():
+    """The plain-jnp float32 oracle (what chip_smoke.py compares the bf16
+    device path against) must itself equal the Flax module when both run
+    in float32: ragged masks, a one-token row, padding past every row.
+    Tolerance: float32 rounding of two orderings of the same sums."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.encoder import (
+        TransformerEncoder,
+        reference_forward,
+    )
+
+    cfg = dataclasses.replace(EncoderConfig.tiny(), dtype=jnp.float32)
+    model = TransformerEncoder(cfg)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(3, cfg.vocab_size, size=(5, 40)).astype(np.int32)
+    lengths = np.array([38, 7, 23, 1, 33])
+    mask = (np.arange(40)[None, :] < lengths[:, None]).astype(np.int32)
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(1), ids[:1, :8], mask[:1, :8]
+    )["params"]
+    got = np.asarray(model.apply({"params": params}, ids, mask))
+    want = np.asarray(reference_forward(params, cfg, ids, mask))
+    np.testing.assert_allclose(got, want, atol=2e-6)

@@ -376,64 +376,6 @@ def test_rest_response_sink_is_batched_in_plan():
     ]
 
 
-def _load_bench():
-    import importlib.util
-    import os
-
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "bench.py",
-    )
-    spec = importlib.util.spec_from_file_location("bench_mod", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-# the measured round-5 tunneled curve (BENCH_full.json) the model must
-# validate against: the OLD model's error GREW with load (0.04 → 0.21 →
-# 0.56); the extended pipelined model must hold it flat
-_ROUND5_CURVE = {
-    "metric": "rag_qps_vs_clients",
-    "curve": [
-        {"n_clients": 32, "qps": 316.2, "mean_ms": 101.17},
-        {"n_clients": 128, "qps": 1458.5, "mean_ms": 87.35},
-        {"n_clients": 512, "qps": 7514.1, "mean_ms": 67.47},
-    ],
-    "device_capacity_qps": 5870.6,
-    "device_ms_per_batch32": 5.45,
-    "transport_floor_p50_ms": 94.8,
-}
-
-
-def test_extended_latency_model_error_flat_under_load():
-    bench = _load_bench()
-    model = bench.bench_latency_model(_ROUND5_CURVE)
-    errs = [p["rel_err"] for p in model["validation"]]
-    assert model["mean_rel_err"] <= 0.10, model["mean_rel_err"]
-    # the high-load point must no longer be the worst one
-    assert errs[-1] <= 0.05, errs
-    assert max(errs) <= 0.15, errs
-    # calibrated transport/pipeline parameters are recorded
-    assert 0.0 < model["inputs"]["rho_transport_overlap_loss"] < 1.0
-    assert model["inputs"]["kappa_pipelined_capacity_ratio"] >= 1.0
-    # colocated prediction clears the acceptance bar: >= 5k qps/chip at
-    # < 15 ms p50
-    knee = model["colocated_knee"]
-    assert knee["qps"] >= 5000.0 and knee["p50_ms"] < 15.0
-
-
-def test_colocated_projection_entry_shape():
-    bench = _load_bench()
-    model = bench.bench_latency_model(_ROUND5_CURVE)
-    entry = bench._colocated_projection(model, 1_000_000)
-    assert entry["metric"] == "rag_colocated_qps"
-    assert entry["projected"] is True and entry["colocated"] is False
-    assert entry["value"] >= 5000.0 and entry["p50_ms"] < 15.0
-    assert entry["n_docs"] == 1_000_000
-    assert entry["vs_baseline"] >= 1.0
-
-
 def test_serve_knobs_registered_and_wired(monkeypatch):
     from pathway_tpu.analysis.knobs import KNOBS, validate_environment
 
@@ -827,6 +769,7 @@ def test_keepalive_session_retries_503_honoring_retry_after():
             else:
                 body = b'42'
                 self.send_response(200)
+                self.send_header("Degraded", "true")
                 self.send_header("Content-Length", str(len(body)))
                 self.end_headers()
                 self.wfile.write(body)
@@ -842,6 +785,8 @@ def test_keepalive_session_retries_503_honoring_retry_after():
         s = KeepAliveSession(f"http://127.0.0.1:{port}", retries=3)
         assert s.post("/", {}) == 42
         assert hits["n"] == 3
+        # a 200 still carries the contract's headers (brownout answers)
+        assert s.last_headers.get("Degraded") == "true"
         # budget exhausted -> the last 503 propagates with headers
         hits["n"] = -10
         s2 = KeepAliveSession(f"http://127.0.0.1:{port}", retries=1)
