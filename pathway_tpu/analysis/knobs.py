@@ -101,7 +101,8 @@ KNOBS: dict[str, Knob] = {
            "Arm the flight recorder and write a Perfetto/Chrome-trace "
            "JSON to this path (multi-rank runs merge per-rank partials "
            "into it; feed it to `python -m pathway_tpu.analysis "
-           "--profile`)."),
+           "--profile`). The always-on span ring needs no knob; this "
+           "one exports it."),
         _k("PATHWAY_TRACE_RING_EVENTS", "int", 65536,
            "Capacity (events per thread) of the native executor's "
            "GIL-free trace ring buffers.", lo=1024, hi=16_777_216),
@@ -284,7 +285,9 @@ KNOBS: dict[str, Knob] = {
         _k("PATHWAY_SERVE_TIMING", "bool", False,
            "Server-Timing response header on the gateway: per-request "
            "queue/window/dispatch/egress milliseconds, so a "
-           "client-observed p50 decomposes without a trace file."),
+           "client-observed p50 decomposes without a trace file. The "
+           "stamps themselves are always taken (span ring, "
+           "`serve_window_wait_ms`); the knob only adds the header."),
         # -- serving through rollback (io/http/_frontend.py + breaker) ----
         _k("PATHWAY_SERVE_BROWNOUT", "bool", False,
            "Degraded-answer mode: with the dispatch circuit breaker open "

@@ -21,6 +21,8 @@ import json
 from collections import defaultdict
 from typing import Any
 
+from pathway_tpu.internals.flight import RING_LAYERS_OVERLAPPING
+
 TOP_K_DEFAULT = 10
 
 
@@ -32,6 +34,12 @@ def load_trace(path: str) -> dict:
             f"{path}: not a flight-recorder trace (no traceEvents)"
         )
     return doc
+
+
+# the recorder's own kinds; any other cat is a layer of the span ring
+_RECORDER_CATS = frozenset(
+    ("node", "step", "wave", "mesh", "device", "native", "mark", "lag")
+)
 
 
 def validate_trace(doc: dict) -> list[str]:
@@ -49,14 +57,17 @@ def validate_trace(doc: dict) -> list[str]:
       WHICHEVER thread entered a GIL-free region (main thread encodes
       while a receiver thread decodes), so its track is a sample stream,
       not a call stack;
-    * node spans carry the args the profile joins on (node/rows/rep).
+    * node spans carry the args the profile joins on (node/rows/rep);
+    * spans of the always-on ring (schema 2; cat = the span's layer)
+      carry their id, and nest per recording thread except the
+      gateway's, which are stamps of concurrent requests.
     """
     problems: list[str] = []
     events = doc.get("traceEvents")
     if not isinstance(events, list) or not events:
         return ["traceEvents missing or empty"]
     pw = doc.get("pathway", {})
-    if pw.get("schema") != 1:
+    if pw.get("schema") not in (1, 2):
         problems.append(f"unknown pathway.schema {pw.get('schema')!r}")
     last_ts: dict[tuple, float] = {}
     stacks: dict[tuple, list] = defaultdict(list)
@@ -102,6 +113,14 @@ def validate_trace(doc: dict) -> list[str]:
             continue
         if e.get("cat") == "native":
             continue  # sample stream, not a call stack (see docstring)
+        if e.get("cat") is not None and e["cat"] not in _RECORDER_CATS:
+            # schema 2: a span of the always-on ring (internals/flight.py),
+            # cat = its layer. Each carries the id its children and the
+            # slow-request report name it by.
+            if "id" not in (e.get("args") or {}):
+                problems.append(f"event {i}: ring span missing id arg")
+            if e.get("cat") in RING_LAYERS_OVERLAPPING:
+                continue  # stamps of concurrent requests, not a call stack
         stack = stacks[key]
         while stack and ts >= stack[-1][1] - eps:
             stack.pop()

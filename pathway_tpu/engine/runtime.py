@@ -25,6 +25,7 @@ from pathway_tpu.engine.scope import Scope
 from pathway_tpu.engine.stream import Delta, is_native_batch
 from pathway_tpu.internals import device as _device
 from pathway_tpu.internals import faults as _faults
+from pathway_tpu.internals import flight as _flight
 
 # the mesh protocol's decisions (wave partition, quiesce guard, leg
 # elision, frontier agreement, commit walk) are NOT implemented here:
@@ -81,6 +82,18 @@ class _Connector:
         self.bytes_put = 0
         self.rows_drained = 0
         self.bytes_drained = 0
+
+
+def _rows_in(batches) -> tuple[int, bool]:
+    """(rows, whether the first batch is a columnar NativeBatch) of a
+    node's input, for its span and its metrics."""
+    rows = 0
+    for b in batches:
+        try:
+            rows += len(b)
+        except TypeError:
+            pass
+    return rows, bool(batches) and is_native_batch(batches[0])
 
 
 class Runtime:
@@ -156,6 +169,8 @@ class Runtime:
         self.recorder = FlightRecorder.from_env(local_only=local_only)
         self._prof = self.recorder is not None or with_http_server
         self._node_labels: list[str] | None = None
+        # the open engine.step span of the always-on ring
+        self._step_span = None
         # event-time lag watermarks: commit timestamp -> earliest ingest
         # stamp (perf_counter_ns at connector flush); sinks report
         # commit→emit freshness against it (note_output_emit)
@@ -406,6 +421,16 @@ class Runtime:
             ]
         return labels[nid]
 
+    def _node_where(self, nid: int) -> str:
+        """The user frame that declared the node (Plan Doctor provenance),
+        for the ring's ``engine.node`` spans: ``GroupByNode#35`` alone
+        does not say which groupby."""
+        tr = getattr(self.scope.nodes[nid], "trace", None)
+        if tr is None:
+            return ""
+        name = str(getattr(tr, "filename", "?")).rsplit("/", 1)[-1]
+        return f"{name}:{getattr(tr, 'lineno', '?')} in {getattr(tr, 'name', '?')}"
+
     def note_output_emit(self, node, time: int, rows: int) -> None:
         """Sink-side half of the event-time lag watermark: freshness =
         emit time minus the commit's earliest connector ingest stamp.
@@ -428,9 +453,14 @@ class Runtime:
         stamp (journal replay, static injection) freshens from engine
         admission instead."""
         try:
-            ns = conn._ingest_ns.popleft()
+            ns, span_args = conn._ingest_ns.popleft()
         except (AttributeError, IndexError):
-            ns = _time.perf_counter_ns()
+            ns, span_args = _time.perf_counter_ns(), None
+        if span_args is not None:
+            # the span that was open where the connector flushed (the
+            # gateway's ``gateway.commit``) learns the timestamp its
+            # commit produced: the link from a request to its step
+            span_args["t"] = t
         prev = self._ingest_ns.get(t)
         if prev is None or ns < prev:
             self._ingest_ns[t] = ns
@@ -438,16 +468,13 @@ class Runtime:
     def _step_node(self, time: int, nid: int) -> None:
         node = self.scope.nodes[nid]
         batches = node.take(time)
-        if not self._prof:
+        step = self._step_span
+        prof = self._prof
+        if step is None and not prof:
             self._process_node(node, time, batches)
             return
-        rows = 0
-        for b in batches:
-            try:
-                rows += len(b)
-            except TypeError:
-                pass
-        nb = bool(batches) and is_native_batch(batches[0])
+        if prof:
+            rows, nb = _rows_in(batches)
         # device-plane node context: dispatches issued inside process()
         # (KNN scans, embedder forwards) stamp this node id into their
         # records — the correlation key between the trace's device
@@ -455,19 +482,47 @@ class Runtime:
         dev = _device.PLANE.on
         if dev:
             _device.PLANE.set_node(nid, time)
-        t0 = _time.perf_counter_ns()
+        # the span ring (internals/flight.py): two clock reads a node.
+        # Opened before the node runs so that what it calls nests beneath
+        # it; recorded only if it ran NODE_SPAN_NS or has spans beneath
+        # it, else counted into the step's args.
+        sp = _flight.span("engine.node")
+        sp.open(annotate=False)
         try:
             self._process_node(node, time, batches)
         finally:
             if dev:
                 _device.PLANE.clear_node()
-        t1 = _time.perf_counter_ns()
-        self.stats.on_node_step(
-            self._node_label(nid), (t1 - t0) / 1e9, rows, nb
+            t1 = sp.close()
+        t0 = sp.t0
+        long = step is not None and (
+            t1 - t0 >= _flight.NODE_SPAN_NS or sp.kids > 0
         )
-        rec = self.recorder
-        if rec is not None:
-            rec.note_node(nid, time, t0, t1, rows, nb)
+        if long and not prof:
+            # counted only for the few nodes that are recorded, after the
+            # fact: the batches are the node's input, still as taken
+            rows, nb = _rows_in(batches)
+        if step is not None:
+            counts = step.args
+            counts["nodes"] += 1
+            if long:
+                sp.args = {
+                    "node": nid, "label": self._node_label(nid),
+                    "where": self._node_where(nid), "rows": rows,
+                    "native": nb,
+                }
+                sp.record(t1)
+            else:
+                counts["short_nodes"] += 1
+                counts["short_ns"] += t1 - t0
+        if prof:
+            self.stats.on_node_step(
+                self._node_label(nid), (t1 - t0) / 1e9, rows, nb
+            )
+            rec = self.recorder
+            if rec is not None:
+                off = _flight.MONO_MINUS_PERF_NS
+                rec.note_node(nid, time, t0 - off, t1 - off, rows, nb)
 
     def _process_node(self, node: Node, time: int, batches) -> None:
         try:
@@ -491,6 +546,25 @@ class Runtime:
             self._deliver(node, time, out)
 
     def _step_time(self, time: int) -> None:
+        """One commit's step, as an ``engine.step`` span of the always-on
+        ring (internals/flight.py): ``trace_id`` is the commit timestamp,
+        for the step and everything beneath it. An iterate body's
+        throwaway runtime records none: its steps are its owner's node."""
+        if self.local_only:
+            self._run_step(time)
+            return
+        prev = self._step_span
+        step = self._step_span = _flight.span(
+            "engine.step", trace_id=time, t=time, nodes=0, short_nodes=0,
+            short_ns=0,
+        )
+        with step:
+            try:
+                self._run_step(time)
+            finally:
+                self._step_span = prev
+
+    def _run_step(self, time: int) -> None:
         """Run all nodes with pending input at `time`, in topo order.
 
         Distributed runs first walk the timestamp's exchange boundaries
@@ -1355,7 +1429,7 @@ class Runtime:
     def _drain_event_queue(self, timeout: float) -> list:
         """One bounded wait, then drain everything queued."""
         entries = []
-        t0 = _time.perf_counter()
+        t0 = _time.monotonic_ns()
         try:
             entries.append(self.event_queue.get(timeout=timeout))
         except queue.Empty:
@@ -1363,7 +1437,12 @@ class Runtime:
             # (runtime_idle_seconds_total — the third leg of the cluster
             # view's per-rank comms/compute/idle split; a drain that
             # returned work is engine time, not idle)
-            self.stats.on_idle(_time.perf_counter() - t0)
+            self.stats.on_idle((_time.monotonic_ns() - t0) / 1e9)
+        t1 = _time.monotonic_ns()
+        if t1 - t0 >= _flight.NODE_SPAN_NS:
+            # what the main loop does between steps, for the ring: here
+            # it waited for a connector to commit
+            _flight.note_span("engine.wait", t0, t1, got=len(entries))
         while True:
             try:
                 entries.append(self.event_queue.get_nowait())
@@ -1529,6 +1608,7 @@ class Runtime:
             )
             drained_subject_states: dict = {}
             saw_data = False
+            t_drain0 = _time.monotonic_ns()
             for conn, deltas, state, journal_rows in entries:
                 if deltas is None:
                     conn.finished = True
@@ -1569,6 +1649,14 @@ class Runtime:
                     self.stats.on_ingest(conn.name, len(deltas))
                     self._note_ingest(t, conn)
                     conn.node.accept(t, 0, deltas)
+            t_drain1 = _time.monotonic_ns()
+            if t_drain1 - t_drain0 >= _flight.NODE_SPAN_NS:
+                # between steps, for the ring: the commits taken off the
+                # queue were journaled, accounted and handed to their
+                # source nodes
+                _flight.note_span(
+                    "engine.drain", t_drain0, t_drain1, commits=len(entries)
+                )
             # step strictly in time order, re-reading pending_times each
             # round: stepping may schedule NEW times (forget-immediately
             # retractions at t+1) that must run before later commits.

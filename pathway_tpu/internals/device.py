@@ -28,11 +28,15 @@ aggregated into ``/metrics/cluster``), and the roofline verdicts of
 ``--profile`` / ``--critical-path`` (analysis/profile.py consumes the
 same pure ``roofline_verdict`` below — no drift).
 
-Discipline matches PR 8: armed only while the runtime's profiling plane
-is on (``PATHWAY_TRACE`` or a live /metrics endpoint), ONE attribute
-check (``PLANE.on``) on every dispatch path when off, and the
-``block_until_ready`` sync happens only on armed runs (an armed run
-trades dispatch-pipelining for attribution — the documented cost).
+Discipline: ``PLANE.begin`` / ``end`` are the one hook at a dispatch
+site. They always record the site's span into the always-on ring
+(internals/flight.py: two clock reads and a tuple append, and the span
+on a running jax.profiler session's host plane); the timed record —
+the ``block_until_ready`` sync, the compiled-cost lookup, the metrics
+— is made only while the runtime's profiling plane is armed
+(``PATHWAY_TRACE`` or a live /metrics endpoint): an armed run trades
+dispatch-pipelining for attribution, the documented cost. The guard
+lives in ``end``, not at the sites.
 
 This module never imports jax at module scope: the relational plane
 (and the ASan/fork CI lanes, where importing jaxlib is fatal) must be
@@ -47,6 +51,19 @@ import sys
 import threading
 import time as _time
 from typing import Any
+
+# the span ring (internals/flight.py), bound at the first dispatch: this
+# file also loads alone, by path, with no package around it
+_flight = None
+
+
+def _ring():
+    global _flight
+    from pathway_tpu.internals import flight
+
+    _flight = flight
+    return flight
+
 
 # -- peak-rate tables --------------------------------------------------------
 # per-device-kind peak dense FLOP/s (bf16 MXU) and HBM bandwidth. Used as
@@ -561,23 +578,19 @@ def snapshot_staging_bytes(capacity: int, dim: int) -> float:
 
 
 class _Dispatch:
-    """One in-flight dispatch record (``PLANE.begin`` ... ``end``)."""
+    """One in-flight dispatch (``PLANE.begin`` ... ``end``): always its
+    ring span (internals/flight.py), and the timed record's fields when
+    the plane was armed at ``begin``."""
 
     __slots__ = (
-        "site", "seq", "node", "t_commit", "t0", "t_ret", "t_done",
-        "depth",
+        "site", "span", "armed", "seq", "node", "t_commit", "t0", "t_ret",
+        "t_done", "depth",
     )
 
-    def __init__(self, site: str, seq: int, node, t_commit, t0: int,
-                 depth: int):
+    def __init__(self, site: str, span):
         self.site = site
-        self.seq = seq
-        self.node = node
-        self.t_commit = t_commit
-        self.t0 = t0
-        self.t_ret = t0
-        self.t_done = t0
-        self.depth = depth
+        self.span = span
+        self.armed = False
 
 
 class DevicePlane:
@@ -587,8 +600,8 @@ class DevicePlane:
     trace rings, the plane is process-global: under the emulated-rank
     CI lane several thread-ranks share it and rank 0's recorder claims
     the records — approximate there, exact on real multi-rank meshes).
-    ``on`` is the ONE attribute dispatch sites check when the plane is
-    off.
+    ``on`` is what ``begin`` checks: off, a dispatch is its ring span
+    and nothing more.
     """
 
     # memory_stats() walks the allocator — sample at most this often
@@ -641,26 +654,31 @@ class DevicePlane:
         return getattr(self._node_ctx, "v", None)
 
     # -- dispatch records --------------------------------------------------
-    def begin(self, site: str) -> _Dispatch | None:
-        """Open a dispatch record (None when the plane is off — sites
-        guard on ``PLANE.on`` first, so the off path is one attribute
-        check and no call at all)."""
+    def begin(self, site: str, *, span: str | None = None,
+              **args) -> _Dispatch:
+        """Open a dispatch: always a ring span named ``span`` (the site's
+        name unless given) with ``args``; when the plane is armed, also
+        the timed record that ``end`` blocks on and feeds to the flight
+        recorder and the device metrics. One hook a site."""
+        d = _Dispatch(site, (_flight or _ring()).span(span or site, **args))
+        d.span.__enter__()
         if not self.on:
-            return None
+            return d
         with self._lock:
             self._seq += 1
-            seq = self._seq
+            d.seq = self._seq
             self._inflight += 1
-            depth = self._inflight
+            d.depth = self._inflight
         ctx = self._current_node()
-        nid, t_commit = ctx if ctx is not None else (None, None)
-        return _Dispatch(site, seq, nid, t_commit,
-                         _time.perf_counter_ns(), depth)
+        d.node, d.t_commit = ctx if ctx is not None else (None, None)
+        d.t0 = d.t_ret = d.t_done = _time.perf_counter_ns()
+        d.armed = True
+        return d
 
     def enqueued(self, d: _Dispatch | None) -> None:
         """Mark the enqueue boundary explicitly (optional — ``end``
         stamps it from its ``t_ret`` argument path otherwise)."""
-        if d is not None:
+        if d is not None and d.armed:
             d.t_ret = _time.perf_counter_ns()
 
     def end(
@@ -695,6 +713,11 @@ class DevicePlane:
         waste is visible instead of inflating the MFU gauge. Defaults
         to ``flops`` — an unpadded site is 100% effective."""
         if d is None:
+            return
+        # the ring span closes where the site's own work ends, armed or
+        # not: what follows (the block, the cost lookup) is the plane's
+        d.span.__exit__(None, None, None)
+        if not d.armed:
             return
         if d.t_ret == d.t0:
             d.t_ret = _time.perf_counter_ns()

@@ -7,7 +7,14 @@ this is the equivalent attribution layer for the batch-per-timestamp
 engine: always compiled, armed by the ``PATHWAY_TRACE=out.json`` knob,
 near-zero overhead when disarmed (one attribute check on the step path).
 
-What gets recorded, per rank:
+Beneath the recorder sits the **span ring** (below: ``span``,
+``note_span``, ``spans_between``): process-wide, always recording,
+bounded, on ``time.monotonic_ns()``. It needs no knob and writes no
+file; the armed recorder adopts its spans into the export, a running
+``jax.profiler`` session gets them as ``pw.<name>`` host events, and a
+request that took over a second is logged with every span under it.
+
+What the recorder records, per rank:
 
 * **per-node spans** from the runtime's step loop (engine/runtime.py
   ``_step_node``): node id + Plan Doctor provenance, commit timestamp,
@@ -41,8 +48,13 @@ back onto the plan's NBDecision verdicts (analysis/profile.py).
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
+import logging
 import os
+import sys
+import threading
 import time as _time
 from bisect import bisect_right
 from typing import Any
@@ -58,7 +70,10 @@ NATIVE_TAGS = {
     7: "arrow_export",  # columnar egress: capture collect + Arrow export
 }
 
-TRACE_SCHEMA_VERSION = 1
+# 2: the span ring's layers (cat gateway / engine / encoder / index / knn,
+# ...) beside node / step / device
+TRACE_SCHEMA_VERSION = 2
+RING_LAYERS_OVERLAPPING = ("gateway",)
 
 
 def trace_path() -> str | None:
@@ -84,6 +99,308 @@ def max_events() -> int:
 
 def partial_path(path: str, rank: int) -> str:
     return f"{path}.r{rank}"
+
+
+# -- the span ring -------------------------------------------------------------
+# One process-wide, always-recording, bounded ring of spans beneath the
+# recorder: every layer boundary between an HTTP request (or a connector
+# commit) and the device call opens one. A span is per CALL (request,
+# window, commit, step, batch, scan), never per row or token. Three sinks:
+# the ring (always), the device trace's host plane (a TraceAnnotation
+# "pw.<name>" while a jax.profiler session is running) and the Perfetto
+# export (an armed FlightRecorder adopts the ring's spans at dump).
+#
+# Clock: time.monotonic_ns(), the clock a benchmark harness stamps its
+# own intervals with. The recorder's perf_counter_ns is the same
+# CLOCK_MONOTONIC on Linux; measured once here, converted at export
+# where it is not.
+
+RING_SPANS = 262_144
+# an engine.node span shorter than this is counted into its step's args,
+# not recorded: the ring holds the nodes that matter
+NODE_SPAN_NS = 100_000
+# span record layout: ONE flat tuple a span, the args' names and values
+# following the seven fields (``args_of(record)`` reads them). A record is
+# the only container a span leaves behind, and it holds only numbers and
+# strings: the collector counts one allocation and drops the record from
+# its lists at its first pass. (A record holding a dict, or a tuple of
+# pairs, is 3–6 allocations that stay: 4x the young collections of a
+# serve run, and a full collection of 363 ms inside every window;
+# PERF.md section 6.)
+S_ID, S_NAME, S_T0, S_T1, S_THREAD, S_PARENT, S_TRACE, S_ARGS = range(8)
+
+
+def args_of(record: tuple) -> dict:
+    """A record's args. The record of a span whose args someone took with
+    ``open_args`` holds the dict itself, every other their names and
+    values in turn."""
+    tail = record[S_ARGS:]
+    if len(tail) == 1:
+        return tail[0]
+    return dict(zip(tail[::2], tail[1::2]))
+
+
+def _flat(head: list, args: dict) -> tuple:
+    for pair in args.items():
+        head.extend(pair)
+    return tuple(head)
+
+
+def _clock_offset_ns() -> int:
+    """monotonic_ns - perf_counter_ns, 0 when they are one clock (the
+    tighter of a few bracketed reads differs by under 1 ms)."""
+    best = None
+    for _ in range(5):
+        a = _time.monotonic_ns()
+        p = _time.perf_counter_ns()
+        b = _time.monotonic_ns()
+        d = (a + b) // 2 - p
+        if best is None or abs(d) < abs(best):
+            best = d
+    return 0 if abs(best) < 1_000_000 else best
+
+
+MONO_MINUS_PERF_NS = _clock_offset_ns()
+
+_ids = itertools.count(1)
+_tls = threading.local()
+_thread_names: dict[int, str] = {}
+_log = logging.getLogger("pathway_tpu.flight")
+
+
+class SpanRing:
+    """Keeps the newest ``cap`` spans; ``dropped`` counts the evicted."""
+
+    def __init__(self, cap: int = RING_SPANS):
+        self.cap = int(cap)
+        self.spans: "collections.deque[tuple]" = collections.deque(
+            maxlen=self.cap
+        )
+        self.dropped = 0
+
+    def append(self, rec: tuple) -> None:
+        if len(self.spans) >= self.cap:
+            self.dropped += 1  # the deque evicts its head on this append
+        self.spans.append(rec)
+
+    def between(self, lo_ns: int, hi_ns: int) -> list[tuple]:
+        """The spans that overlap [lo_ns, hi_ns], oldest first."""
+        return [
+            s for s in list(self.spans)
+            if s[S_T1] >= lo_ns and s[S_T0] <= hi_ns
+        ]
+
+
+RING = SpanRing()
+
+
+def spans_between(lo_ns: int, hi_ns: int) -> list[tuple]:
+    return RING.between(lo_ns, hi_ns)
+
+
+def thread_name(ident: int) -> str:
+    return _thread_names.get(ident, str(ident))
+
+
+def _thread() -> int:
+    ident = threading.get_ident()
+    if ident not in _thread_names:
+        _thread_names[ident] = threading.current_thread().name
+    return ident
+
+
+def context() -> tuple:
+    """(span id, trace_id) of the innermost span open on this thread, to
+    hand to work whose span closes on another thread (``parent=``,
+    ``trace_id=``); (None, None) outside any span."""
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return (None, None)
+    top = stack[-1]
+    return (top.id, top.trace_id)
+
+
+def open_args() -> dict | None:
+    """The args of the innermost span open on this thread, for whoever
+    learns only after the span has closed what it produced (the engine,
+    of a commit's timestamp) and writes it there: that span's record
+    keeps the dict itself."""
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return None
+    stack[-1].shared = True
+    return stack[-1].args
+
+
+def new_id() -> int:
+    return next(_ids)
+
+
+_ANNOTATION = None
+
+
+def _annotation():
+    """jax.profiler.TraceAnnotation once jax is loaded; this module is
+    never the reason it loads (the relational plane runs without
+    jaxlib)."""
+    global _ANNOTATION
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    profiler = getattr(jax, "profiler", None)
+    ann = getattr(profiler, "TraceAnnotation", None)
+    if ann is not None and hasattr(ann, "is_enabled"):
+        _ANNOTATION = ann
+    return _ANNOTATION
+
+
+class span:
+    """``with flight.span("encoder.tokenize", texts=n) as s:`` -- records
+    ``(id, name, t0_ns, t1_ns, thread, parent, trace_id, *args)`` into the
+    ring when it closes. Parent: the enclosing span on this thread
+    unless given. ``trace_id``: inherited from the parent unless given.
+    ``s.args`` may be filled while the span is open."""
+
+    __slots__ = (
+        "name", "args", "trace_id", "parent", "id", "t0", "kids", "shared",
+        "_ann",
+    )
+
+    def __init__(self, name: str, *, trace_id=None, parent=None, **args):
+        self.name = name
+        self.args = args
+        self.trace_id = trace_id
+        self.parent = parent
+        self.shared = False
+
+    def __enter__(self):
+        self.open()
+        return self
+
+    def __exit__(self, *exc):
+        self.record(self.close())
+        return False
+
+    # the three steps of ``with``, for the caller that decides only at
+    # the end whether the span is worth a record (the runtime, of a node)
+
+    def open(self, annotate: bool = True) -> None:
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        if stack:
+            top = stack[-1]
+            top.kids += 1
+            if self.parent is None:
+                self.parent = top.id
+            if self.trace_id is None:
+                self.trace_id = top.trace_id
+        self.id = next(_ids)
+        self.kids = 0
+        stack.append(self)
+        # sink 2: the device trace's clock, while a profiler session
+        # is collecting host events
+        self._ann = None
+        if annotate:
+            ann = _ANNOTATION or _annotation()
+            if ann is not None and ann.is_enabled():
+                self._ann = ann("pw." + self.name)
+                self._ann.__enter__()
+        self.t0 = _time.monotonic_ns()
+
+    def close(self) -> int:
+        """Ends the span without recording it; returns its end stamp."""
+        t1 = _time.monotonic_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = _tls.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:  # closed out of order (a generator, an error)
+            stack.remove(self)
+        return t1
+
+    def record(self, t1: int) -> None:
+        head = [self.id, self.name, self.t0, t1, _thread(), self.parent,
+                self.trace_id]
+        if self.shared:
+            head.append(self.args)
+            RING.append(tuple(head))
+        else:
+            RING.append(_flat(head, self.args))
+
+
+def note_span(name: str, t0_ns: int, t1_ns: int, *, trace_id=None,
+              parent=None, span_id=None, **args) -> tuple:
+    """A span from stamps already taken, possibly on other threads (the
+    gateway's per-request legs). Ring only: no annotation can be entered
+    after the fact."""
+    rec = _flat(
+        [span_id if span_id is not None else next(_ids), name,
+         int(t0_ns), int(t1_ns), _thread(), parent, trace_id],
+        args,
+    )
+    RING.append(rec)
+    return rec
+
+
+# -- slow-request report -----------------------------------------------------
+
+SLOW_REQUEST_NS = 1_000_000_000
+SLOW_REPORT_EVERY_NS = 10_000_000_000
+SLOW_REPORT_SPANS = 200
+_last_slow_report_ns = None
+
+
+def span_dict(s: tuple, lo_ns: int | None = None) -> dict:
+    """One ring span as a JSON-able object; times in ms, the start
+    relative to ``lo_ns`` when given."""
+    t0 = s[S_T0] if lo_ns is None else s[S_T0] - lo_ns
+    return {
+        "id": s[S_ID], "name": s[S_NAME], "t0_ms": t0 / 1e6,
+        "dur_ms": (s[S_T1] - s[S_T0]) / 1e6,
+        "thread": thread_name(s[S_THREAD]), "parent": s[S_PARENT],
+        "trace_id": None if s[S_TRACE] is None else str(s[S_TRACE]),
+        "args": {k: (v if isinstance(v, (int, float, str, bool)) or v is None
+                     else str(v))
+                 for k, v in args_of(s).items()},
+    }
+
+
+def report_slow_request(rec: tuple) -> bool:
+    """One WARNING line for a ``gateway.request`` that took over
+    ``SLOW_REQUEST_NS``: a JSON object with the span and every ring span
+    that overlaps it (at most ``SLOW_REPORT_SPANS``, the longest kept),
+    at most once in ``SLOW_REPORT_EVERY_NS``. Requests only: a bulk
+    commit's step of seconds is normal. Returns whether it reported."""
+    global _last_slow_report_ns
+    if rec[S_T1] - rec[S_T0] <= SLOW_REQUEST_NS:
+        return False
+    now = _time.monotonic_ns()
+    last = _last_slow_report_ns
+    if last is not None and now - last < SLOW_REPORT_EVERY_NS:
+        return False
+    _last_slow_report_ns = now
+    lo, hi = rec[S_T0], rec[S_T1]
+    under = [s for s in RING.between(lo, hi) if s[S_ID] != rec[S_ID]]
+    total = len(under)
+    if total > SLOW_REPORT_SPANS:
+        under = sorted(
+            under, key=lambda s: s[S_T0] - s[S_T1]
+        )[:SLOW_REPORT_SPANS]
+    under.sort(key=lambda s: s[S_T0])
+    _log.warning(
+        "slow request %s",
+        json.dumps(
+            {
+                "slow_request": span_dict(rec),
+                "overlapping": total,
+                "spans": [span_dict(s, lo) for s in under],
+            },
+            separators=(",", ":"),
+        ),
+    )
+    return True
 
 
 class FlightRecorder:
@@ -591,6 +908,31 @@ class FlightRecorder:
                     "tid": tid, "ts": self._us(t0),
                     "dur": _dur_us(t0, t1),
                     "args": {"rows": rows},
+                }
+            )
+        # sink 3 of the span ring: what it recorded since this recorder
+        # was made, one track per recording thread (tid 500+). cat is
+        # the span's layer (the name up to its first dot). ``gateway``
+        # spans are stamps of concurrent requests and overlap on their
+        # track like ``device`` ones; every other layer nests.
+        ring_tids: dict[int, int] = {}
+        lo = self.mono_anchor_ns + MONO_MINUS_PERF_NS
+        adopted = RING.between(lo, lo + (1 << 62))[-self.max_events:]
+        for s in adopted:
+            tid = ring_tids.setdefault(s[S_THREAD], 500 + len(ring_tids))
+            tid_named(tid, f"spans {thread_name(s[S_THREAD])}")
+            d = span_dict(s)
+            t0 = max(s[S_T0], lo) - MONO_MINUS_PERF_NS
+            out.append(
+                {
+                    "name": s[S_NAME], "cat": s[S_NAME].split(".", 1)[0],
+                    "ph": "X", "pid": pid, "tid": tid,
+                    "ts": self._us(t0),
+                    "dur": _dur_us(t0, s[S_T1] - MONO_MINUS_PERF_NS),
+                    "args": {
+                        "id": d["id"], "parent": d["parent"],
+                        "trace_id": d["trace_id"], **d["args"],
+                    },
                 }
             )
         return out

@@ -145,6 +145,12 @@ class ServeMetrics:
     occupancy: _Histogram = field(
         default_factory=lambda: _Histogram(SERVE_OCCUPANCY_BUCKETS)
     )
+    # admission to dispatch start, per request (ms): the batch window's
+    # wait plus the worker's pickup — the share of a reply's latency the
+    # gateway itself adds (io/http/_server.py observes it at dispatch)
+    window_wait: _Histogram = field(
+        default_factory=lambda: _Histogram(SERVE_LATENCY_BUCKETS_MS)
+    )
 
     def on_request(self) -> None:
         self.requests += 1
@@ -1027,6 +1033,7 @@ class ProberStats:
             for metric, attr in (
                 ("serve_request_latency_ms", "latency"),
                 ("serve_batch_occupancy", "occupancy"),
+                ("serve_window_wait_ms", "window_wait"),
             ):
                 lines.append(f"# TYPE {metric} histogram")
                 for sm in self.serve:

@@ -69,6 +69,7 @@ import zlib
 from typing import Any, Callable
 
 from pathway_tpu.internals import faults as _faults
+from pathway_tpu.internals import flight as _flight
 
 # uncommitted-row backlog above which a stateful subject's rows are
 # journaled without a scan state (degrading recovery to at-least-once)
@@ -264,7 +265,8 @@ def run_connector_thread(conn, out_queue: "queue.Queue") -> None:
 def _stamp(conn) -> None:
     """Event-time lag watermark, connector half: stamp ingest time once
     per forwarded queue entry (perf_counter_ns, the engine's trace
-    timebase). The runtime pops stamps FIFO as it drains entries and
+    timebase), paired with the args of the ring span open on this
+    thread. The runtime pops stamps FIFO as it drains entries and
     keys commit→emit freshness off them (engine/runtime.py
     ``_note_ingest``/``note_output_emit``); appends are GIL-atomic, so
     the subject thread needs no lock."""
@@ -273,7 +275,9 @@ def _stamp(conn) -> None:
         import collections
 
         q = conn._ingest_ns = collections.deque()
-    q.append(_time.perf_counter_ns())
+    # with the args of the span open on this thread (the gateway's
+    # window commit), which the runtime fills with the commit timestamp
+    q.append((_time.perf_counter_ns(), _flight.open_args()))
 
 
 def _run_supervised(conn, out_queue: "queue.Queue") -> None:

@@ -50,6 +50,7 @@ from typing import Any, Sequence
 from pathway_tpu.internals import dtype as dt
 from pathway_tpu.internals import faults as _faults
 from pathway_tpu.internals import memory as _memory
+from pathway_tpu.internals import flight as _flight
 from pathway_tpu.internals.device import PLANE as _DEVICE, device_site
 from pathway_tpu.internals.api import Json, Pointer, ref_scalar
 from pathway_tpu.internals.monitoring import ServeMetrics
@@ -301,22 +302,26 @@ class _PendingRequest:
 
     __slots__ = (
         "key", "values", "future", "admitted_at", "evicted",
-        # Server-Timing stamps (PATHWAY_SERVE_TIMING=1; ISSUE 15
-        # satellite): window-close, dispatch-start and response-resolve
-        # perf_counter readings, so each response can decompose its own
-        # latency into queue/window/dispatch/egress without a trace file
-        "t_closed", "t_dispatch0", "t_resolved",
+        # where the request was at each hand-over, on the span ring's
+        # clock (monotonic_ns): window-close, dispatch-start and
+        # response-resolve. They split every reply into queue / pickup /
+        # dispatch / egress: for the ring (``gateway.*`` spans), for
+        # ``ServeMetrics.window_wait`` and, under PATHWAY_SERVE_TIMING=1,
+        # for the Server-Timing header
+        "t_closed", "t_dispatch0", "t_resolved", "window", "span_id",
     )
 
     def __init__(self, key, values, future):
         self.key = key
         self.values = values
         self.future = future
-        self.admitted_at = _time.perf_counter()
+        self.admitted_at = _time.monotonic_ns()
         self.evicted = False
         self.t_closed = None
         self.t_dispatch0 = None
         self.t_resolved = None
+        self.window = None
+        self.span_id = _flight.new_id()
 
 
 class RestServerSubject(ConnectorSubject):
@@ -432,6 +437,7 @@ class RestServerSubject(ConnectorSubject):
         # collecting window (event-loop thread only) + closed-window queue
         # drained by the dispatch workers
         self._window: list[_PendingRequest] = []
+        self._window_seq = 0  # closed windows, counted (event loop)
         self._window_timer = None
         self._windows_q: "_queue.Queue" = _queue.Queue()
         self._commit_lock = threading.Lock()
@@ -599,6 +605,43 @@ class RestServerSubject(ConnectorSubject):
 
     # -- request path (webserver event loop) ------------------------------
     async def _handle(self, request):
+        """One request, as a ``gateway.request`` span of the always-on
+        ring (internals/flight.py) from here to the reply handed to the
+        web server; one that took over a second is reported with every
+        span that ran under it."""
+        t0 = _time.monotonic_ns()
+        status = 500
+        try:
+            response = await self._answer(request)
+            status = response.status
+            return response
+        except asyncio.CancelledError:
+            status = 499  # the client went away
+            raise
+        finally:
+            t1 = _time.monotonic_ns()
+            p = request.get("pw_pending")  # admitted, if it got that far
+            legs = {}
+            if p is not None and None not in (
+                p.t_closed, p.t_dispatch0, p.t_resolved
+            ):
+                legs = {
+                    "window": p.window,
+                    "admit_ms": (p.admitted_at - t0) / 1e6,
+                    "queue_ms": (p.t_closed - p.admitted_at) / 1e6,
+                    "pickup_ms": (p.t_dispatch0 - p.t_closed) / 1e6,
+                    "dispatch_ms": (p.t_resolved - p.t_dispatch0) / 1e6,
+                    "egress_ms": (t1 - p.t_resolved) / 1e6,
+                }
+            rec = _flight.note_span(
+                "gateway.request", t0, t1,
+                trace_id=None if p is None else int(p.key),
+                span_id=None if p is None else p.span_id,
+                route=self.route, status=status, **legs,
+            )
+            _flight.report_slow_request(rec)
+
+    async def _answer(self, request):
         from aiohttp import web
 
         cols = self.schema.column_names()
@@ -753,11 +796,10 @@ class RestServerSubject(ConnectorSubject):
                 key = ref_scalar("rest", self.route, self._seq)
         future: asyncio.Future = asyncio.get_event_loop().create_future()
         self._tasks[key] = future
-        pending = _PendingRequest(key, values, future)
-        if self._server_timing:
-            # the response fan-in only sees the future — hang the
-            # pending off it so the resolve stamp lands per request
-            future._pw_pending = pending
+        pending = request["pw_pending"] = _PendingRequest(key, values, future)
+        # the response fan-in only sees the future — hang the pending
+        # off it so the resolve stamp lands per request
+        future._pw_pending = pending
         self._inflight += 1
         self._join_window(pending)
         try:
@@ -779,7 +821,7 @@ class RestServerSubject(ConnectorSubject):
             self._inflight -= 1
             self._tasks.pop(key, None)
         metrics.on_latency_ms(
-            (_time.perf_counter() - pending.admitted_at) * 1000.0
+            (_time.monotonic_ns() - pending.admitted_at) / 1e6
         )
         if self._server_timing:
             return web.json_response(
@@ -835,10 +877,15 @@ class RestServerSubject(ConnectorSubject):
             self._window_timer.cancel()
             self._window_timer = None
         self._window = []
-        if self._server_timing:
-            now = _time.perf_counter()
-            for p in window:
-                p.t_closed = now
+        self._window_seq += 1
+        now = _time.monotonic_ns()
+        for p in window:
+            p.t_closed = now
+            p.window = self._window_seq
+            _flight.note_span(
+                "gateway.queue", p.admitted_at, now, trace_id=int(p.key),
+                parent=p.span_id, window=self._window_seq,
+            )
         self._windows_q.put(window)
 
     # -- dispatch workers (threads) ---------------------------------------
@@ -885,18 +932,30 @@ class RestServerSubject(ConnectorSubject):
             # not yet committed (the all-parked-window invariant: this
             # window must commit NOTHING at epoch+1 unless replayed)
             _faults.fault_point("serve.dispatch", phase="window")
-            # device plane (ISSUE 15): the gateway's fused window
-            # dispatch as a timed record — one commit = one downstream
-            # device dispatch. Host-only here (the JAX launch happens in
-            # the engine's step, where the index site records its own
-            # device-bounded span), so no output to block on: the record
-            # carries the window's wall span and the dispatch-queue
-            # depth, and its device time is honestly zero.
-            dev = _DEVICE.begin("serve.window") if _DEVICE.on else None
-            if self._server_timing:
-                now = _time.perf_counter()
-                for p in live:
-                    p.t_dispatch0 = now
+            # the gateway's fused window dispatch: one hook, always its
+            # ``gateway.commit`` span on the ring and, when the device
+            # plane is armed (ISSUE 15), a timed ``serve.window`` record —
+            # one commit = one downstream device dispatch. Host-only here
+            # (the JAX launch happens in the engine's step, where the
+            # index site records its own span), so no output to block
+            # on. The span carries the keys it holds; the runtime adds
+            # the commit timestamp it produced (``t``), which is the
+            # ``trace_id`` of the step that answers them.
+            now = _time.monotonic_ns()
+            window_wait = self.serve_metrics.window_wait
+            for p in live:
+                p.t_dispatch0 = now
+                window_wait.observe((now - p.admitted_at) / 1e6)
+            seq = live[0].window if live else None
+            if live:
+                _flight.note_span(
+                    "gateway.pickup", live[0].t_closed, now, window=seq
+                )
+            dev = _DEVICE.begin(
+                "serve.window", span="gateway.commit", window=seq,
+                live=len(live), removals=len(removals),
+                keys=[int(p.key) for p in live],
+            )
             try:
                 for p in live:
                     if self.delete_completed_queries:
@@ -909,10 +968,9 @@ class RestServerSubject(ConnectorSubject):
                     self._remove(key, values)
                 self.commit()
             except BaseException:
-                if dev is not None:
-                    # close the record on the failure path too — an
-                    # abandoned record would leak dispatch-queue depth
-                    _DEVICE.end(dev, None, block=False)
+                # close the record on the failure path too — an
+                # abandoned record would leak dispatch-queue depth
+                _DEVICE.end(dev, None, block=False)
                 if removals:
                     # the swapped-out retractions must not vanish with
                     # the failed dispatch — re-queue them for the next
@@ -924,8 +982,7 @@ class RestServerSubject(ConnectorSubject):
             # delivered — the frontend must replay (the rollback cut
             # discards this commit) without double-answering anyone
             _faults.fault_point("serve.dispatch", phase="committed")
-            if dev is not None:
-                _DEVICE.end(dev, None, block=False)
+            _DEVICE.end(dev, None, block=False)
             if live:
                 self.serve_metrics.on_window(len(live))
 
@@ -941,16 +998,18 @@ class RestServerSubject(ConnectorSubject):
         # exactly the scenario it exists for
         self._breaker_record(True)
         loop = self.webserver._loop
-        t_resolved = _time.perf_counter() if self._server_timing else None
+        t_resolved = _time.monotonic_ns()
+        # the step that delivered this batch, for the span that closes
+        # on the event loop
+        parent, trace_id = _flight.context()
         futures = []
         for key, result in resolved:
             future = self._tasks.get(key)
             if future is not None:
                 futures.append((future, result))
-                if t_resolved is not None:
-                    p = getattr(future, "_pw_pending", None)
-                    if p is not None:
-                        p.t_resolved = t_resolved
+                p = getattr(future, "_pw_pending", None)
+                if p is not None:
+                    p.t_resolved = t_resolved
             if self.delete_completed_queries:
                 values = self._live.pop(key, None)
                 if values is not None:
@@ -970,6 +1029,10 @@ class RestServerSubject(ConnectorSubject):
                 for future, result in futures:
                     if not future.done():
                         future.set_result(result)
+                _flight.note_span(
+                    "gateway.resolve", t_resolved, _time.monotonic_ns(),
+                    parent=parent, trace_id=trace_id, resolved=len(futures),
+                )
 
             loop.call_soon_threadsafe(_set)
         if self.delete_completed_queries and self._removals:
@@ -1007,7 +1070,7 @@ def _server_timing_header(p: _PendingRequest) -> str:
 
     Missing stamps (a replayed/brownout path) collapse to 0 rather than
     lying with negative durations."""
-    now = _time.perf_counter()
+    now = _time.monotonic_ns()
     t_admit = p.admitted_at
     t_closed = p.t_closed if p.t_closed is not None else t_admit
     t_d0 = p.t_dispatch0 if p.t_dispatch0 is not None else t_closed
@@ -1019,7 +1082,7 @@ def _server_timing_header(p: _PendingRequest) -> str:
         ("egress", now - t_res),
     )
     return ", ".join(
-        f"{name};dur={max(0.0, s) * 1000.0:.2f}" for name, s in legs
+        f"{name};dur={max(0, ns) / 1e6:.2f}" for name, ns in legs
     )
 
 

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from pathway_tpu.internals import flight as _flight
 from pathway_tpu.internals.device import (
     PLANE as _DEVICE,
     batch_bucket,
@@ -321,19 +322,26 @@ class SentenceEncoder:
         texts = list(texts)
         if not texts:
             return np.zeros((0, self.config.hidden), np.float32)
-        ids, mask = self.tokenizer(texts)
-        out = np.empty((len(texts), self.config.hidden), np.float32)
-        for start in range(0, len(texts), self.batch_size):
-            sl = slice(start, min(start + self.batch_size, len(texts)))
-            out[sl] = self._encode_batch(ids[sl], mask[sl])
+        with _flight.span("encoder.encode", texts=len(texts)):
+            ids, mask = self._tokenize(texts)
+            out = np.empty((len(texts), self.config.hidden), np.float32)
+            for start in range(0, len(texts), self.batch_size):
+                sl = slice(start, min(start + self.batch_size, len(texts)))
+                out[sl] = self._encode_batch(ids[sl], mask[sl])
         return out
+
+    def _tokenize(self, texts: list):
+        with _flight.span("encoder.tokenize", texts=len(texts)) as sp:
+            ids, mask = self.tokenizer(texts)
+            sp.args["tokens"] = int(mask.sum())
+        return ids, mask
 
     def encode_device(self, texts: Sequence[str]):
         """Encode one batch and return the (device-resident, async-dispatched)
         jax array of shape [n, hidden]. Chaining this into device-side
         consumers (e.g. KnnShard.add) avoids the host round-trip and lets
         host tokenization of the next batch overlap device compute."""
-        ids, mask = self.tokenizer(list(texts))
+        ids, mask = self._tokenize(list(texts))
         return self.encode_tokens_device(ids, mask)
 
     def encode_tokens_device(self, ids: np.ndarray, mask: np.ndarray):
@@ -341,42 +349,54 @@ class SentenceEncoder:
         shared padding+forward core. Lets a tokenize-ahead thread overlap
         host tokenization of batch N+1 with device compute / transfers of
         batch N — the ingest-throughput lever."""
-        ids_p, mask_p, n = pad_batch(
-            ids, mask, self.config.max_len, self.batch_size
-        )
-        # compact transfer when the mask is a contiguous prefix (wordpiece
-        # and HF padders both produce this) and ids fit uint16
-        lengths = mask_p.sum(axis=1, dtype=np.int32)
-        contiguous = bool(
-            (mask_p.cumsum(axis=1)[np.arange(len(lengths)), lengths - 1]
-             == lengths).all()
-        ) if mask_p.shape[1] else True
-        # device plane (ISSUE 15): one timed dispatch record per forward
-        # — FLOPs/bytes from the compiled executable's cost_analysis()
-        # (cached per (geometry, shape bucket); the analytical model is
-        # the fallback), transfer bytes from the actual wire arrays.
-        # One attribute check when off; an armed run blocks on the
-        # embeddings, trading the tokenize-ahead overlap for attribution.
-        dev = _DEVICE.begin("encoder.forward") if _DEVICE.on else None
-        compact = contiguous and self.config.vocab_size <= 65536
-        nb_, Lb = ids_p.shape
+        with _flight.span(
+            "encoder.pad", rows=int(ids.shape[0]), longest=int(ids.shape[1])
+        ) as sp:
+            ids_p, mask_p, n = pad_batch(
+                ids, mask, self.config.max_len, self.batch_size
+            )
+            # compact transfer when the mask is a contiguous prefix
+            # (wordpiece and HF padders both produce this) and ids fit
+            # uint16
+            lengths = mask_p.sum(axis=1, dtype=np.int32)
+            contiguous = bool(
+                (mask_p.cumsum(axis=1)[np.arange(len(lengths)), lengths - 1]
+                 == lengths).all()
+            ) if mask_p.shape[1] else True
+            compact = contiguous and self.config.vocab_size <= 65536
+            nb_, Lb = ids_p.shape
+            sp.args["padded"] = nb_ * Lb
         bucket = encoder_bucket(nb_, Lb, compact)
         fn = self._compiled.get(bucket)
-        if fn is None:
+        first = fn is None
+        if first:
             # first sighting of this shape bucket: jit will lower+compile
             # a fresh executable on the call below — count it (ISSUE 16)
             fn = self._compiled[bucket] = (
                 self._forward_compact if compact else self._forward
             )
             _DEVICE.note_recompile("encoder.forward")
-        if compact:
-            args = (
-                self.params,
-                jnp.asarray(ids_p.astype(np.uint16)),
-                jnp.asarray(lengths),
-            )
-        else:
-            args = (self.params, jnp.asarray(ids_p), jnp.asarray(mask_p))
+        with _flight.span("encoder.h2d") as sp:
+            if compact:
+                args = (
+                    self.params,
+                    jnp.asarray(ids_p.astype(np.uint16)),
+                    jnp.asarray(lengths),
+                )
+            else:
+                args = (self.params, jnp.asarray(ids_p), jnp.asarray(mask_p))
+            sp.args["bytes"] = nbytes_of(args[1], args[2])
+        real_tokens = int(np.sum(lengths[:n], dtype=np.int64))
+        # the dispatch: always its ring span; armed (ISSUE 15), also one
+        # timed record — FLOPs/bytes from the compiled executable's
+        # cost_analysis() (cached per (geometry, shape bucket); the
+        # analytical model is the fallback), transfer bytes from the
+        # wire arrays, a block on the embeddings (an armed run trades
+        # the tokenize-ahead overlap for attribution)
+        dev = _DEVICE.begin(
+            "encoder.forward", bucket=f"{nb_}x{Lb}", real_tokens=real_tokens,
+            padded_tokens=nb_ * Lb, first=first,
+        )
         try:
             emb = fn(*args)
         except BaseException:
@@ -384,30 +404,42 @@ class SentenceEncoder:
             # leaks dispatch-queue depth)
             _DEVICE.end(dev, None, block=False)
             raise
-        if dev is not None:
-            cfg = self.config
-            key = (
-                "encoder", cfg.hidden, cfg.layers, cfg.mlp,
-                cfg.vocab_size, nb_, Lb, compact,
-            )
-            # cost_fn runs after end() stamps the wall span: the first
-            # call per shape bucket pays an AOT lower+compile that must
-            # not read as host-assembly time in the dispatch record.
-            # Effective share: real tokens over padded tokens — the
-            # bucket-padding waste the effective-MFU gauge exposes.
-            eff_tokens = float(np.sum(lengths[:n], dtype=np.int64))
-            _DEVICE.end(
-                dev, emb,
-                transfer_bytes=nbytes_of(args[1], args[2], emb),
-                cost_fn=lambda: compiled_cost(
-                    key, fn, args, forward_cost_model(cfg, nb_, Lb)
-                ),
-                effective_share=eff_tokens / float(nb_ * Lb),
-            )
+        cfg = self.config
+        key = (
+            "encoder", cfg.hidden, cfg.layers, cfg.mlp,
+            cfg.vocab_size, nb_, Lb, compact,
+        )
+        # cost_fn runs after end() stamps the wall span: the first
+        # call per shape bucket pays an AOT lower+compile that must
+        # not read as host-assembly time in the dispatch record.
+        # Effective share: real tokens over padded tokens — the
+        # bucket-padding waste the effective-MFU gauge exposes.
+        _DEVICE.end(
+            dev, emb,
+            transfer_bytes=nbytes_of(args[1], args[2], emb),
+            cost_fn=lambda: compiled_cost(
+                key, fn, args, forward_cost_model(cfg, nb_, Lb)
+            ),
+            effective_share=real_tokens / float(nb_ * Lb),
+        )
         return emb[:n]
 
     def _encode_batch(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
-        return np.asarray(self.encode_tokens_device(ids, mask), np.float32)
+        emb = self.encode_tokens_device(ids, mask)
+        with _flight.span("encoder.wait"):
+            # np.asarray below queues the copy behind the forward and
+            # blocks on both; queued here first, it still does, and the
+            # wait between only tells the device's time from the copy's.
+            # (A wait BEFORE the copy is queued costs a host round trip
+            # a batch: 0.4 ms, 13% on the serve tail; PERF.md section 6.)
+            queue_copy = getattr(emb, "copy_to_host_async", None)
+            if queue_copy is not None:
+                queue_copy()
+            jax.block_until_ready(emb)
+        with _flight.span("encoder.d2h") as sp:
+            out = np.asarray(emb, np.float32)
+            sp.args["bytes"] = int(out.nbytes)
+        return out
 
     def __call__(self, texts: Sequence[str]) -> np.ndarray:
         return self.encode(texts)

@@ -203,7 +203,7 @@ class IngestPipeline:
         self.rows_ingested += n
         self.real_tokens += int(eff_tokens)
         self.padded_tokens += nb * Lb
-        dev = _DEVICE.begin(self.site) if _DEVICE.on else None
+        dev = _DEVICE.begin(self.site, rows=n, padded_tokens=nb * Lb)
         try:
             with index.lock:
                 slots = index._assign_slots(keys)
@@ -235,21 +235,20 @@ class IngestPipeline:
         except BaseException:
             _DEVICE.end(dev, None, block=False)
             raise
-        if dev is not None:
-            cfg = self.encoder.config
-            d = index.dimension
-            # forward dominates; the scatter write adds the sq-norm
-            # reduction + row traffic (same model as KnnShard.add)
-            flops, acc = forward_cost_model(cfg, nb, Lb)
-            flops += 4.0 * nb * d
-            acc += 8.0 * nb * d + 8.0 * nb
-            # end() blocks OUTSIDE the lock (update-while-serving)
-            _DEVICE.end(
-                dev, (emb, out_vectors),
-                flops=flops, bytes_accessed=acc,
-                transfer_bytes=nbytes_of(ids_dev, lengths_dev) + 4 * nb,
-                effective_share=eff_tokens / float(nb * Lb),
-            )
+        cfg = self.encoder.config
+        d = index.dimension
+        # forward dominates; the scatter write adds the sq-norm
+        # reduction + row traffic (same model as KnnShard.add)
+        flops, acc = forward_cost_model(cfg, nb, Lb)
+        flops += 4.0 * nb * d
+        acc += 8.0 * nb * d + 8.0 * nb
+        # armed, end() blocks: OUTSIDE the lock (update-while-serving)
+        _DEVICE.end(
+            dev, (emb, out_vectors),
+            flops=flops, bytes_accessed=acc,
+            transfer_bytes=nbytes_of(ids_dev, lengths_dev) + 4 * nb,
+            effective_share=eff_tokens / float(nb * Lb),
+        )
         return emb[:n]
 
     # -- public API --------------------------------------------------------

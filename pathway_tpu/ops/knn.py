@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from pathway_tpu.internals import device as _devsup
+from pathway_tpu.internals import flight as _flight
 from pathway_tpu.internals.device import (
     PLANE as _DEVICE,
     device_site,
@@ -269,13 +270,16 @@ class KnnShard:
         if len(keys) != vecs.shape[0]:
             raise ValueError("keys/vectors length mismatch")
         with self.lock:
-            slots = self._assign_slots(keys)
-            slots_arr = jnp.asarray(slots)
+            with _flight.span("knn.assign_slots", rows=len(keys)):
+                slots = self._assign_slots(keys)
             bucket = knn_write_bucket(len(slots), self.capacity)
             if bucket not in self._seen_buckets:
                 self._seen_buckets.add(bucket)
                 _DEVICE.note_recompile("knn.write")
-            dev = _DEVICE.begin("knn.write") if _DEVICE.on else None
+            h2d = (0 if isinstance(vecs, jax.Array) else nbytes_of(vecs)) \
+                + 4 * len(slots)
+            dev = _DEVICE.begin("knn.write", rows=len(slots), h2d_bytes=h2d)
+            slots_arr = jnp.asarray(slots)
 
             def _launch():
                 return _write_slots(
@@ -296,20 +300,19 @@ class KnnShard:
                 _DEVICE.end(dev, None, block=False)
                 raise
             out_vectors = self.vectors
-        if dev is not None:
-            # end() OUTSIDE the lock, like the search side — its
-            # block_until_ready must not serialize update-while-serving
-            # (a racing writer may have re-donated out_vectors by now;
-            # blocking on an invalidated array is absorbed by end()).
-            # Scatter writes: touch the written rows + norms; FLOPs are
-            # the optional normalize + sq-norm reduction.
-            flops, acc = write_cost_model(len(slots), self.dimension)
-            _DEVICE.end(
-                dev, out_vectors,
-                flops=flops,
-                bytes_accessed=acc,
-                transfer_bytes=nbytes_of(vecs) + 4 * len(slots),
-            )
+        # end() OUTSIDE the lock, like the search side — armed, its
+        # block_until_ready must not serialize update-while-serving
+        # (a racing writer may have re-donated out_vectors by now;
+        # blocking on an invalidated array is absorbed by end()).
+        # Scatter writes: touch the written rows + norms; FLOPs are
+        # the optional normalize + sq-norm reduction.
+        flops, acc = write_cost_model(len(slots), self.dimension)
+        _DEVICE.end(
+            dev, out_vectors,
+            flops=flops,
+            bytes_accessed=acc,
+            transfer_bytes=nbytes_of(vecs) + 4 * len(slots),
+        )
 
     def remove(self, keys: Sequence[Any]) -> None:
         with self.lock:
@@ -408,7 +411,8 @@ class KnnShard:
         # come from the SAME function the retrace audit enumerates with
         bucket = knn_search_bucket(n, self.capacity, k, self.chunk)
         padded_n, _, k_eff = bucket
-        if bucket not in self._seen_buckets:
+        first = bucket not in self._seen_buckets
+        if first:
             self._seen_buckets.add(bucket)
             _DEVICE.note_recompile("knn.search")
         if padded_n != n:
@@ -419,12 +423,16 @@ class KnnShard:
                 else np.pad(queries, pad)
             )
         fn = _search_fn(k_eff, self.metric.value, self.chunk, self.precision)
-        # device plane (ISSUE 15): one timed dispatch record per scan —
-        # wall span, block_until_ready-bounded device time, the scan's
-        # cost model and host->device transfer bytes. One attribute
-        # check when the plane is off; end() blocks OUTSIDE the lock so
-        # attribution never serializes writers.
-        dev = _DEVICE.begin("knn.search") if _DEVICE.on else None
+        # the dispatch: always its ring span (prepare, pad, lock,
+        # enqueue); armed (ISSUE 15), also one timed record per scan —
+        # block_until_ready-bounded device time, the scan's cost model
+        # and host->device transfer bytes. end() runs OUTSIDE the lock
+        # so attribution never serializes writers.
+        dev = _DEVICE.begin(
+            "knn.search", queries=padded_n, k=k_eff, first=first,
+            h2d_bytes=0 if isinstance(queries, jax.Array)
+            else nbytes_of(queries),
+        )
         try:
             with self.lock:  # read+launch before the next donating update
                 vals, idx = _devsup.supervised_dispatch(
@@ -441,25 +449,38 @@ class KnnShard:
             # site's rule): an abandoned record leaks queue depth
             _DEVICE.end(dev, None, block=False)
             raise
-        if dev is not None:
-            flops, acc = topk_scan_cost(
-                padded_n, self.capacity, self.dimension, k_eff
-            )
-            # effective FLOPs (ISSUE 16): only real queries against live
-            # rows count as useful work — query padding and the empty
-            # tail of the pow2 capacity buffer are visible padding waste
-            flops_eff, _ = topk_scan_cost(
-                n, live_rows, self.dimension, k_eff
-            )
-            _DEVICE.end(
-                dev, (vals, idx), flops=flops,
-                flops_effective=flops_eff, bytes_accessed=acc,
-                transfer_bytes=nbytes_of(queries, vals, idx),
-            )
-        vals = np.asarray(vals)[:n]
-        idx = np.asarray(idx)[:n]
+        flops, acc = topk_scan_cost(
+            padded_n, self.capacity, self.dimension, k_eff
+        )
+        # effective FLOPs (ISSUE 16): only real queries against live
+        # rows count as useful work — query padding and the empty
+        # tail of the pow2 capacity buffer are visible padding waste
+        flops_eff, _ = topk_scan_cost(n, live_rows, self.dimension, k_eff)
+        _DEVICE.end(
+            dev, (vals, idx), flops=flops,
+            flops_effective=flops_eff, bytes_accessed=acc,
+            transfer_bytes=nbytes_of(queries, vals, idx),
+        )
+        with _flight.span("knn.search.wait"):
+            # both copies queue behind the scan before anything waits
+            # (np.asarray alone queues each only when it is called); the
+            # wait then tells the scan's time from the copies'
+            vals.copy_to_host_async()
+            idx.copy_to_host_async()
+            jax.block_until_ready((vals, idx))
+        with _flight.span("knn.search.d2h") as sp:
+            vals, idx = np.asarray(vals), np.asarray(idx)
+            sp.args["bytes"] = nbytes_of(vals, idx)
+            vals, idx = vals[:n], idx[:n]
+        with _flight.span("knn.search.resolve") as sp:
+            out = self._resolve_hits(vals, idx, k, epoch)
+            sp.args["hits"] = sum(len(h) for h in out)
+        return out
+
+    def _resolve_hits(self, vals, idx, k: int, epoch: int):
+        """Slots back to keys, per query, best first."""
         out: list[list[tuple[Any, float]]] = []
-        for qi in range(n):
+        for qi in range(len(vals)):
             hits = []
             for vv, slot in zip(vals[qi], idx[qi]):
                 if not np.isfinite(vv):

@@ -145,20 +145,14 @@ def pallas_topk_scores(
 ):
     """Fused scored top-k: returns (values [Q, k], indices [Q, k]).
 
-    Host wrapper over the jitted kernel so the device plane (ISSUE 15)
-    can record a timed dispatch per call — one attribute check when
-    tracing is off."""
+    Host wrapper over the jitted kernel: one dispatch hook per call (its
+    ring span always, the device plane's timed record when armed)."""
     q, d = queries.shape
     bucket = pallas_bucket(q, database.shape[0], d, k, block, interpret)
     if bucket not in _SEEN_BUCKETS:
         _SEEN_BUCKETS.add(bucket)
         _DEVICE.note_recompile("pallas.topk")
-    if not _DEVICE.on:
-        return _pallas_topk_scores_jit(
-            queries, database, add_mask, k=k, block=block,
-            interpret=interpret,
-        )
-    dev = _DEVICE.begin("pallas.topk")
+    dev = _DEVICE.begin("pallas.topk", queries=q, k=k)
     try:
         out = _pallas_topk_scores_jit(
             queries, database, add_mask, k=k, block=block,

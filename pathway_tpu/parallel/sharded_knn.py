@@ -451,7 +451,7 @@ class ShardedKnnIndex:
         vecs = self._prepare(vecs)
         if len(keys) != vecs.shape[0]:
             raise ValueError("keys/vectors length mismatch")
-        dev = _DEVICE.begin("knn.sharded_write") if _DEVICE.on else None
+        dev = _DEVICE.begin("knn.sharded_write", rows=len(keys))
         try:
             with self.lock:
                 slots = self._assign_slots(keys)
@@ -477,14 +477,13 @@ class ShardedKnnIndex:
         except BaseException:
             _DEVICE.end(dev, None, block=False)
             raise
-        if dev is not None:
-            flops, acc = write_cost_model(len(keys), self.dimension)
-            _DEVICE.end(
-                dev, out_vectors,
-                flops=flops,
-                bytes_accessed=acc,
-                transfer_bytes=nbytes_of(vecs) + 4 * len(keys),
-            )
+        flops, acc = write_cost_model(len(keys), self.dimension)
+        _DEVICE.end(
+            dev, out_vectors,
+            flops=flops,
+            bytes_accessed=acc,
+            transfer_bytes=nbytes_of(vecs) + 4 * len(keys),
+        )
 
     # batch-adapter alias (engine/external_index.py batched delta path)
     add_batch = add
@@ -608,7 +607,7 @@ class ShardedKnnIndex:
             self.mesh, self.axis, k_eff, self.metric.value,
             self.chunk, self.precision, _merge_mode(self.n_shards),
         )
-        dev = _DEVICE.begin("knn.sharded_search") if _DEVICE.on else None
+        dev = _DEVICE.begin("knn.sharded_search", queries=padded_n, k=k_eff)
         try:
             with self.lock:  # read+launch before the next donating write
                 q_dev = jax.device_put(jnp.asarray(queries), self._repl)
@@ -623,18 +622,15 @@ class ShardedKnnIndex:
         except BaseException:
             _DEVICE.end(dev, None, block=False)
             raise
-        if dev is not None:
-            flops, acc = topk_scan_cost(
-                padded_n, self.capacity, self.dimension, k_eff
-            )
-            flops_eff, _ = topk_scan_cost(
-                n, live_rows, self.dimension, k_eff
-            )
-            _DEVICE.end(
-                dev, (vals, idx), flops=flops,
-                flops_effective=flops_eff, bytes_accessed=acc,
-                transfer_bytes=nbytes_of(queries, vals, idx),
-            )
+        flops, acc = topk_scan_cost(
+            padded_n, self.capacity, self.dimension, k_eff
+        )
+        flops_eff, _ = topk_scan_cost(n, live_rows, self.dimension, k_eff)
+        _DEVICE.end(
+            dev, (vals, idx), flops=flops,
+            flops_effective=flops_eff, bytes_accessed=acc,
+            transfer_bytes=nbytes_of(queries, vals, idx),
+        )
         vals = np.asarray(vals)[:n]
         idx = np.asarray(idx)[:n]
         out: list[list[tuple[Any, float]]] = []

@@ -20,6 +20,7 @@ from typing import Any
 
 import numpy as np
 
+from pathway_tpu.internals import flight as _flight
 from pathway_tpu.internals.expression import ColumnExpression, ColumnReference
 from pathway_tpu.stdlib.indexing._filters import compile_filter
 from pathway_tpu.stdlib.indexing.retrievers import InnerIndex, InnerIndexFactory
@@ -222,30 +223,37 @@ class _KnnAdapter:
         from the wrapped shard (knn.write/search or the sharded pair)."""
         return tuple(getattr(self.shard, "device_sites", ()) or ())
 
+    # one ring span a call (internals/flight.py), inside the method: the
+    # object stays the one ``_KnnAdapter`` callers hold and patch
+
     def add(self, key, data, filter_data) -> None:
-        vec = np.asarray(data, dtype=np.float32)
-        self.shard.add([key], vec[None, :] if vec.ndim == 1 else vec)
-        self.meta[key] = filter_data
+        with _flight.span("index.add", rows=1):
+            vec = np.asarray(data, dtype=np.float32)
+            self.shard.add([key], vec[None, :] if vec.ndim == 1 else vec)
+            self.meta[key] = filter_data
 
     def add_batch(self, rows) -> None:
         """One slot-write dispatch per consolidated delta batch instead
         of one per row (ISSUE 16: ann_recall's 121.7s per-doc build)."""
-        keys = [k for k, _, _ in rows]
-        vecs = np.stack(
-            [np.asarray(d, np.float32).reshape(-1) for _, d, _ in rows]
-        )
-        self.shard.add(keys, vecs)
-        for key, _, fdata in rows:
-            self.meta[key] = fdata
+        with _flight.span("index.add_batch", rows=len(rows)):
+            keys = [k for k, _, _ in rows]
+            vecs = np.stack(
+                [np.asarray(d, np.float32).reshape(-1) for _, d, _ in rows]
+            )
+            self.shard.add(keys, vecs)
+            for key, _, fdata in rows:
+                self.meta[key] = fdata
 
     def remove(self, key) -> None:
-        self.shard.remove([key])
-        self.meta.pop(key, None)
+        with _flight.span("index.remove", rows=1):
+            self.shard.remove([key])
+            self.meta.pop(key, None)
 
     def remove_batch(self, keys) -> None:
-        self.shard.remove(list(keys))
-        for key in keys:
-            self.meta.pop(key, None)
+        with _flight.span("index.remove_batch", rows=len(keys)):
+            self.shard.remove(list(keys))
+            for key in keys:
+                self.meta.pop(key, None)
 
     # -- operator-snapshot hooks -------------------------------------------
     def snapshot_state(self):
@@ -269,6 +277,13 @@ class _KnnAdapter:
         self.meta = dict(state["meta"])
 
     def search(self, queries):
+        with _flight.span(
+            "index.search", queries=len(queries),
+            k=max((q[1] for q in queries), default=0),
+        ):
+            return self._search(queries)
+
+    def _search(self, queries):
         out = []
         for qdata, limit, filt in queries:
             vec = np.asarray(qdata, dtype=np.float32)[None, :]

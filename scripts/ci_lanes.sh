@@ -170,9 +170,8 @@ echo "=== lane 14: device-trace smoke (embed+KNN device plane) ==="
 # (dispatch-id'd spans correlated to their enclosing node spans), and
 # `analysis --profile` must exit 0 naming the top dispatch site with
 # its roofline verdict (compute-bound / bandwidth-bound / host-bound).
-# The traced-vs-untraced overhead bar (<= 3%, interleaved pairs) is
-# re-measured with `--bench`; BENCH_full.json records the artifact
-# (device_trace_overhead) via `--update-artifact`.
+# The armed-vs-disarmed overhead bar (<= 3%, interleaved pairs) is
+# re-measured with `--bench`.
 env -u PATHWAY_LANE_PROCESSES python scripts/device_trace_smoke.py
 
 echo "=== lane 15: sharded-index smoke (pod-sharded HBM KNN + fused ingest) ==="

@@ -16,10 +16,11 @@ server on, then asserts the device observability chain end to end:
    top dispatch site with its roofline verdict
    (compute-bound / bandwidth-bound / host-bound).
 
-``--update-artifact`` additionally measures the device plane's
-traced-vs-untraced overhead on the embed+KNN hot loop as INTERLEAVED
-pairs (same methodology as the PR 8 relational lanes) and records it
-into BENCH_full.json (``device_trace_overhead``, bar: <= 3%).
+``--bench`` additionally measures the armed device plane's overhead on
+the embed+KNN hot loop as INTERLEAVED armed/disarmed pairs (same
+methodology as the PR 8 relational lanes; bar: <= 3%). A CPU count, not
+a device number. What the always-on span ring costs on the chip is
+measured by ``scripts/span_clock_check.py`` and ``PERF.md`` section 3.
 
 Exit 0 = green; any assertion prints the reason and exits 1.
 """
@@ -226,7 +227,7 @@ def run_smoke() -> None:
     )
 
 
-def measure_overhead(update_artifact: bool) -> None:
+def measure_overhead() -> None:
     """Interleaved traced-vs-untraced pairs on the embed+KNN hot loop
     (in-process; the device plane armed with a live recorder so the
     full note path is paid)."""
@@ -280,44 +281,14 @@ def measure_overhead(update_artifact: bool) -> None:
     )
     if overhead_pct > 3.0:
         fail(f"device-plane overhead {overhead_pct:.2f}% > 3%")
-    if update_artifact:
-        path = os.path.join(REPO, "BENCH_full.json")
-        art = json.load(open(path))
-        entry = {
-            "metric": "device_trace_overhead",
-            "value": round(on_med, 6),
-            "unit": "s_per_pass_traced",
-            "untraced_value": round(off_med, 6),
-            "overhead_pct": round(overhead_pct, 3),
-            "overhead_ok": overhead_pct <= 3.0,
-            "interleaved_pairs": pairs,
-            "method": (
-                "embed(tiny encoder, 256 docs)+knn add/search pass; "
-                "median of interleaved traced/untraced pair ratios; "
-                "device plane armed with recorder+stats; CPU backend"
-            ),
-        }
-        art = [
-            e for e in art
-            if not (
-                isinstance(e, dict)
-                and e.get("metric") == "device_trace_overhead"
-            )
-        ] + [entry]
-        with open(path, "w") as f:
-            json.dump(art, f, indent=1)
-            f.write("\n")
-        print("device_trace_smoke: BENCH_full.json device_trace_overhead "
-              "updated")
 
 
 def main() -> int:
-    update = "--update-artifact" in sys.argv
     bench_only = "--bench-only" in sys.argv
     if not bench_only:
         run_smoke()
-    if update or bench_only or "--bench" in sys.argv:
-        measure_overhead(update)
+    if bench_only or "--bench" in sys.argv:
+        measure_overhead()
     print("device_trace_smoke: PASS")
     return 0
 

@@ -57,11 +57,32 @@ def _knn_round_trip(n=4, d=8, q=2):
 
 # -- off-path discipline --------------------------------------------------
 
-def test_plane_off_is_noop():
+def test_plane_off_is_noop(monkeypatch):
+    """Plane off, ``begin`` still opens the site's span on the always-on
+    ring, but the record's ``end`` neither blocks on its outputs nor
+    feeds ProberStats: the guard lives in ``end``, not at the sites."""
+    import jax
+
+    from pathway_tpu.internals import flight
+
     assert PLANE.on is False
-    assert PLANE.begin("knn.search") is None
-    PLANE.end(None)  # closing a None record is free and legal
+    blocked = []
+    real_block = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", blocked.append)
     stats = ProberStats()
+    PLANE.stats = stats  # what an armed plane would feed
+    lo = time.monotonic_ns()
+    d = PLANE.begin("knn.search", queries=1)
+    assert d is not None and d.armed is False
+    PLANE.end(d, np.zeros(3), flops=1.0, transfer_bytes=12)
+    PLANE.end(None)  # closing a None record is free and legal
+    assert blocked == []
+    assert stats.device_sites == {}
+    spans = flight.spans_between(lo, time.monotonic_ns())
+    assert [s[flight.S_NAME] for s in spans] == ["knn.search"]
+    assert flight.args_of(spans[0]) == {"queries": 1}
+    # the real sites, unguarded: same answers, still nothing recorded
+    monkeypatch.setattr(jax, "block_until_ready", real_block)
     hits = _knn_round_trip()
     assert len(hits) == 2 and hits[0]
     assert stats.device_sites == {}
