@@ -65,41 +65,22 @@ class SlidesDocumentStore(DocumentStore):
         from pathway_tpu.internals.api import Json
         from pathway_tpu.stdlib.indexing._filters import compile_filter
 
-        parsed_docs = self._graph["parsed_docs"]
-
-        @pw.udf(deterministic=True)
-        def meta_of(data: Json) -> Json:
-            try:
-                return Json(dict(data.value.get("metadata") or {}))
-            except AttributeError:
-                return Json({})
-
-        metas = parsed_docs.select(meta=meta_of(pw.this.data))
-        all_metas = metas.reduce(
-            metadatas=pw.reducers.tuple(pw.this.meta)
-        )
-        queries = self.merge_filters(parse_docs_queries)
         excluded = tuple(self.excluded_response_metadata)
 
         @pw.udf(deterministic=True)
         def format_inputs(metadatas, metadata_filter: str | None) -> Json:
-            metadatas = list(metadatas or ())
             pred = compile_filter(metadata_filter)
             out = []
             for m in metadatas:
-                value = m.value if hasattr(m, "value") else m
+                value = Json.parse(m).value
                 if pred is not None and not pred(value):
                     continue
-                cleaned = {
-                    k: v for k, v in dict(value).items() if k not in excluded
-                }
-                out.append(cleaned)
+                out.append(
+                    {k: v for k, v in value.items() if k not in excluded}
+                )
             return Json(out)
 
-        joined = queries.join_left(all_metas, id=queries.id).select(
-            metadatas=all_metas.metadatas,
-            metadata_filter=queries.metadata_filter,
-        )
-        return joined.select(
+        per_query = self._metadatas_as_of_now(parse_docs_queries)
+        return per_query.select(
             result=format_inputs(pw.this.metadatas, pw.this.metadata_filter)
         )

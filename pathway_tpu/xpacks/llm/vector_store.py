@@ -6,7 +6,15 @@ Pipeline (reference :209 _build_graph): concat sources -> async parse UDF
 with embedder; query ops retrieve/statistics/inputs; REST serving via
 rest_connector. The index here is the TPU brute-force document index
 (fused MXU matmul+top-k, optionally mesh-sharded) instead of host usearch
-HNSW (:266)."""
+HNSW (:266).
+
+The graph holds no value as long as the corpus: a commit of documents
+costs what it holds. ``stats`` is one global group of a count and two
+maxima, joined to each statistics question. ``inputs_query`` joins each
+question, as of now, against the parsed documents' metadata and groups the
+matches per question: the whole list is assembled only when a question
+arrives, and is answered once, as of that question's commit, like
+``retrieve_query``."""
 
 from __future__ import annotations
 
@@ -144,23 +152,14 @@ class VectorStoreServer:
             except Exception:
                 return 0
 
-        @pw.udf(deterministic=True)
-        def meta_str(data, field: str) -> str:
-            try:
-                return str(data.value["metadata"].get(field, ""))
-            except Exception:
-                return ""
-
         enriched = parsed_docs.with_columns(
             modified=meta_int(pw.this.data, "modified_at"),
             indexed=meta_int(pw.this.data, "seen_at"),
-            path=meta_str(pw.this.data, "path"),
         )
         stats = enriched.reduce(
             count=pw.reducers.count(),
             last_modified=pw.reducers.max(pw.this.modified),
             last_indexed=pw.reducers.max(pw.this.indexed),
-            paths=pw.reducers.tuple(pw.this.path),
         )
         return dict(
             docs=docs,
@@ -296,30 +295,58 @@ class VectorStoreServer:
             )
         )
 
-    def inputs_query(self, input_queries):
-        """reference: :365."""
-        parsed_docs = self._graph["parsed_docs"]
-        all_metas = parsed_docs.reduce(
-            metadatas=pw.reducers.tuple(pw.this.data)
+    def _metadatas_as_of_now(self, queries):
+        """One row a question, under the question's id: its merged
+        ``metadata_filter`` and ``metadatas``, the metadata (as JSON text)
+        of every parsed document present as of the question's own commit,
+        that commit's insertions, updates and deletions applied first, in
+        the documents' row-key order. Nothing here is kept per corpus
+        between questions but the join's arrangement of the documents: a
+        commit of documents costs what it holds."""
+        # a join's select reads `id` as the joined row's own id, so each
+        # side brings its id along as a column. The documents bring their
+        # metadata alone, as JSON text: the join keeps no document text,
+        # and a str keeps its hash where a Json serialises itself for
+        # every hash the join and the group take of a row
+        docs = self._graph["parsed_docs"].select(
+            doc_id=pw.this.id,
+            metadata=apply_with_type(
+                lambda d: Json(d.value["metadata"]).to_json_string(),
+                dt.STR,
+                pw.this.data,
+            ),
         )
-        queries = self.merge_filters(input_queries)
+        queries = self.merge_filters(queries).with_columns(query_id=pw.this.id)
+        # a left join: a question asked of an empty corpus still gets its
+        # (padded) row, hence its answer; skip_nones drops the padding
+        docs_now = queries.asof_now_join(docs, how=pw.JoinMode.LEFT).select(
+            queries.query_id, queries.metadata_filter, docs.doc_id,
+            docs.metadata,
+        )
+        return docs_now.groupby(
+            id=pw.this.query_id, sort_by=pw.this.doc_id
+        ).reduce(
+            metadata_filter=pw.reducers.any(pw.this.metadata_filter),
+            metadatas=pw.reducers.tuple(pw.this.metadata, skip_nones=True),
+        )
 
+    def inputs_query(self, input_queries):
+        """reference: :365. Each question is answered once, as of its
+        arrival, like ``retrieve_query``: a query row left standing in a
+        table is not answered again when documents change later."""
         from pathway_tpu.stdlib.indexing._filters import compile_filter
 
         @pw.udf(deterministic=True)
         def format_inputs(metadatas, metadata_filter) -> Json:
-            metadatas = metadatas or ()
-            metas = [
-                (m.value.get("metadata", {}) if isinstance(m, Json) else {})
-                for m in metadatas
-            ]
+            metas = [Json.parse(m).value for m in metadatas]
             if metadata_filter:
                 pred = compile_filter(metadata_filter)
                 metas = [m for m in metas if pred(m)]
             return Json(metas)
 
-        return queries.join_left(all_metas, id=queries.id).select(
-            result=format_inputs(all_metas.metadatas, queries.metadata_filter)
+        per_query = self._metadatas_as_of_now(input_queries)
+        return per_query.select(
+            result=format_inputs(pw.this.metadatas, pw.this.metadata_filter)
         )
 
     # -- serving ------------------------------------------------------------

@@ -2,6 +2,7 @@
 over a live webserver with mock models (reference Tier-4 webserver tests)."""
 
 import json
+import socket
 import threading
 import time
 import urllib.request
@@ -45,7 +46,16 @@ def test_rag_server_end_to_end():
         return f"pong {name}"
 
     threading.Thread(target=pw.run, daemon=True).start()
-    time.sleep(1.5)
+    # the server listens after 0.8-1.1 s on an idle machine, later beside
+    # other test workers: wait for the port, not for a fixed time
+    deadline = time.monotonic() + 30
+    while True:
+        try:
+            socket.create_connection(("127.0.0.1", 8941), timeout=1).close()
+            break
+        except OSError:
+            assert time.monotonic() < deadline, "server did not come up"
+            time.sleep(0.05)
 
     client = RAGClient(host="127.0.0.1", port=8941)
     out = client.answer("what is pathway")
