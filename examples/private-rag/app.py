@@ -4,8 +4,9 @@ service where EVERY model runs locally — embedder, reranker and LLM never
 leave the machine, so documents and questions stay private.
 
 The default app.yaml wires deterministic offline mocks so the template
-boots anywhere; production deployments swap the `llm` entry for a local
-HF pipeline (pw.xpacks.llm.llms.HFPipelineChat) or a LiteLLM entry
+boots anywhere; production deployments swap the `llm` entry for the
+on-device decoder (pw.xpacks.llm.llms.TPUChat; see examples/on-chip-rag), a
+local HF pipeline (pw.xpacks.llm.llms.HFPipelineChat) or a LiteLLM entry
 pointed at a local server (e.g. ollama/mistral at localhost:11434), and
 the embedder for pw.xpacks.llm.embedders.SentenceTransformerEmbedder —
 no code changes, only YAML.

@@ -462,12 +462,15 @@ def analyze_device_plan(
     world: int = 1,
     config: Any = None,
     mutant: str | None = None,
+    answer: Any = None,
 ) -> DevicePlanReport:
     """Run the five static checks over every registered device chain at
     the declared ``world``/workload. ``mutant`` seeds one of the four
     defect classes (tests + the CI lane's exit-2 contract); None
-    analyzes the shipped chains. Zero execution: chains are lowered
-    with ShapeDtypeStructs — nothing is dispatched."""
+    analyzes the shipped chains. ``answer``: the ``DecoderConfig`` of an
+    answer model resident beside the index; its held parameters and its
+    state cache count into the HBM budget. Zero execution: chains are
+    lowered with ShapeDtypeStructs — nothing is dispatched."""
     import jax
     import jax.numpy as jnp
 
@@ -746,7 +749,16 @@ def analyze_device_plan(
     )
     params_b = encoder_param_bytes(cfg)
     snap_b = dev.snapshot_staging_bytes(per_chip_cap, d_model)
-    footprint = index_b + freelist_b + staging_b + params_b + snap_b
+    answer_params_b = answer_cache_b = 0.0
+    if answer is not None:
+        from pathway_tpu.models import decoder
+
+        answer_params_b = decoder.param_bytes(answer)
+        answer_cache_b = decoder.cache_bytes(answer)
+    footprint = (
+        index_b + freelist_b + staging_b + params_b + snap_b
+        + answer_params_b + answer_cache_b
+    )
     budget = float(dev.device_hbm_bytes())
     hbm = {
         "world": world,
@@ -756,6 +768,8 @@ def analyze_device_plan(
         "ingest_staging_bytes": staging_b,
         "encoder_param_bytes": params_b,
         "snapshot_staging_bytes": snap_b,
+        "answer_param_bytes": answer_params_b,
+        "answer_cache_bytes": answer_cache_b,
         "footprint_bytes": footprint,
         "budget_bytes": budget,
         "share": footprint / budget if budget else 0.0,

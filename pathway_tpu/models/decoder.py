@@ -1,0 +1,885 @@
+"""Causal hybrid decoder: the answer model of the RAG plane.
+
+The model family is ``granitemoehybrid`` (IBM Granite 4.0-H): a stack of
+blocks, each ``x += r * mixer(norm(x)); x += r * (moe(norm(x)) +
+shared(norm(x)))``, whose mixer is a Mamba-2 state-space layer or NoPE
+grouped-query attention by ``layer_types``, with routed experts beside
+an always-on shared MLP, and four scalar multipliers (embedding,
+residual, attention, logits). Everything a deployment divides over chips
+is told to this module, never assumed: which layers (``layer_types``),
+which experts (``experts_held``) and which rows of the tied vocabulary
+(``vocab_held``) live here. The router stays as wide as published and
+picks ``experts_per_token`` of all experts; this chip computes its own
+experts' part and leaves out what the absent ones would add (on one chip
+the layer runs without its exchange).
+
+Two jitted programs serve every request:
+
+* ``prefill(params, state, slot, ids, pos, n)``: one fixed chunk of
+  ``prefill_chunk`` tokens of one sequence, carrying the convolution
+  tail, the SSM state and the keys/values from chunk to chunk through
+  the slot, so one executable serves every prompt length (a padded
+  position has step size 0, routes nowhere and writes no key);
+* ``decode(params, state, slots, ids, pos)``: one token for each of a
+  batch of live slots.
+
+The layers are unrolled, each with its own weight arrays: under
+``lax.scan`` over stacked weights every step copied the layer's slice
+before it multiplied by it (137 MB of ``in_proj``, 680 MB of experts, a
+layer a call: a decode step read its weights twice), so the compile time
+grows with depth instead. Matrix operands are bfloat16 with
+float32 accumulation; the residual stream, every norm, softmax, gate and
+the SSM state are float32.
+
+``StateCache`` holds both kinds of per-sequence state side by side, by
+slot: constant-size (convolution tail, SSM state) for the Mamba layers,
+growing (keys/values) for the attention layers. ``AnswerModel`` is the
+host-facing object (prompt ids in, generated ids out) the chat UDF wraps.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import threading
+from typing import Sequence
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from pathway_tpu.internals import device as _devsup
+from pathway_tpu.internals import flight as _flight
+from pathway_tpu.internals.device import (
+    PLANE as _DEVICE,
+    device_site,
+    nbytes_of,
+    place_compile_cache,
+)
+
+place_compile_cache()
+
+MAMBA, ATTENTION = "mamba", "attention"
+DECODE_BUCKETS = (1, 2, 4, 8)
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    """Published sizes, the share of them held here, and the serving
+    sizes (chunk, positions, slots)."""
+
+    hidden: int = 4096
+    layer_types: tuple = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
+    vocab_size: int = 100352
+    vocab_held: tuple = (0, 100352)      # (first row, rows) of the tied table
+    heads: int = 32
+    kv_heads: int = 8
+    attention_multiplier: float = 1.0 / 128
+    mamba_heads: int = 128
+    mamba_head_dim: int = 64
+    mamba_state: int = 128
+    mamba_groups: int = 1
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
+    experts: int = 72
+    experts_per_token: int = 10
+    experts_held: tuple = (0, 72)        # (first expert, experts) held here
+    expert_width: int = 768
+    shared_width: int = 1536
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    logits_scaling: float = 16.0
+    rms_eps: float = 1e-5
+    prefill_chunk: int = 512
+    max_positions: int = 4096
+    slots: int = 8
+
+    def __post_init__(self):
+        if self.mamba_groups != 1:
+            raise ValueError("only mamba_n_groups == 1 is written down here")
+        if self.prefill_chunk % self.mamba_chunk:
+            raise ValueError("prefill_chunk must be whole Mamba chunks")
+        if self.max_positions % self.prefill_chunk:
+            raise ValueError("max_positions must be whole prefill chunks")
+        if self.heads % self.kv_heads:
+            raise ValueError("heads must be a multiple of kv_heads")
+        if not 1 <= self.slots <= DECODE_BUCKETS[-1]:
+            raise ValueError(f"slots must be 1..{DECODE_BUCKETS[-1]} (the decode buckets)")
+        first, held = self.experts_held
+        if first < 0 or held < 1 or first + held > self.experts:
+            raise ValueError(f"experts_held {self.experts_held} outside 0..{self.experts}")
+        first, rows = self.vocab_held
+        if first < 0 or rows < 1 or first + rows > self.vocab_size:
+            raise ValueError(f"vocab_held {self.vocab_held} outside 0..{self.vocab_size}")
+
+    # derived widths
+    @property
+    def head_dim(self) -> int:
+        return self.hidden // self.heads
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_width(self) -> int:
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state
+
+    @property
+    def in_proj_width(self) -> int:
+        return self.mamba_inner + self.conv_width + self.mamba_heads
+
+    @classmethod
+    def from_hf(cls, hf: dict, *, layers: int | None = None,
+                experts_held: tuple | None = None,
+                vocab_held: tuple | None = None, **serving) -> "DecoderConfig":
+        """From a ``granitemoehybrid`` ``config.json``. ``layers`` keeps
+        the first that many of ``layer_types``; ``experts_held`` and
+        ``vocab_held`` give this chip's share (default: everything)."""
+        types = tuple(hf["layer_types"])[: layers or hf["num_hidden_layers"]]
+        if hf.get("position_embedding_type", "nope") != "nope":
+            raise ValueError("only position_embedding_type 'nope' is written down here")
+        if hf["mamba_expand"] * hf["hidden_size"] != hf["mamba_n_heads"] * hf["mamba_d_head"]:
+            raise ValueError("mamba_expand x hidden_size != mamba_n_heads x mamba_d_head")
+        return cls(
+            hidden=hf["hidden_size"], layer_types=types,
+            vocab_size=hf["vocab_size"],
+            vocab_held=tuple(vocab_held or (0, hf["vocab_size"])),
+            heads=hf["num_attention_heads"], kv_heads=hf["num_key_value_heads"],
+            attention_multiplier=hf["attention_multiplier"],
+            mamba_heads=hf["mamba_n_heads"], mamba_head_dim=hf["mamba_d_head"],
+            mamba_state=hf["mamba_d_state"], mamba_groups=hf["mamba_n_groups"],
+            mamba_conv=hf["mamba_d_conv"], mamba_chunk=hf["mamba_chunk_size"],
+            experts=hf["num_local_experts"],
+            experts_per_token=hf["num_experts_per_tok"],
+            experts_held=tuple(experts_held or (0, hf["num_local_experts"])),
+            expert_width=hf["intermediate_size"],
+            shared_width=hf["shared_intermediate_size"],
+            embedding_multiplier=hf["embedding_multiplier"],
+            residual_multiplier=hf["residual_multiplier"],
+            logits_scaling=hf["logits_scaling"], rms_eps=hf["rms_norm_eps"],
+            **serving,
+        )
+
+    @classmethod
+    def tiny(cls) -> "DecoderConfig":
+        """Test geometry: every mechanism, toy widths (m m A m, 8 experts
+        top-3, one share of 4, a 64-row slice of 128 rows)."""
+        return cls(
+            hidden=32, layer_types=(MAMBA, MAMBA, ATTENTION, MAMBA),
+            vocab_size=128, vocab_held=(0, 64), heads=4, kv_heads=2,
+            attention_multiplier=0.125, mamba_heads=8, mamba_head_dim=8,
+            mamba_state=16, mamba_conv=4, mamba_chunk=8, experts=8,
+            experts_per_token=3, experts_held=(0, 4), expert_width=16,
+            shared_width=32, prefill_chunk=16, max_positions=64, slots=4,
+        )
+
+
+# -- parameters ------------------------------------------------------------------
+
+
+def layer_shapes(cfg: DecoderConfig, kind: str) -> dict[str, tuple]:
+    """Leaf name -> shape of one layer of ``kind``."""
+    h, held = cfg.hidden, cfg.experts_held[1]
+    block = {
+        "norm1": (h,), "norm2": (h,),
+        "router": (h, cfg.experts),
+        "shared_in": (h, 2 * cfg.shared_width),
+        "shared_out": (cfg.shared_width, h),
+        "experts_in": (held, h, 2 * cfg.expert_width),
+        "experts_out": (held, cfg.expert_width, h),
+    }
+    if kind == MAMBA:
+        block.update({
+            "in_proj": (h, cfg.in_proj_width),
+            "conv_w": (cfg.mamba_conv, cfg.conv_width),
+            "conv_b": (cfg.conv_width,),
+            "dt_bias": (cfg.mamba_heads,), "A_log": (cfg.mamba_heads,),
+            "D": (cfg.mamba_heads,),
+            "mixer_norm": (cfg.mamba_inner,),
+            "out_proj": (cfg.mamba_inner, h),
+        })
+    else:
+        qd, kvd = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+        block.update({"wq": (h, qd), "wk": (h, kvd), "wv": (h, kvd), "wo": (qd, h)})
+    return block
+
+
+_F32_LEAVES = frozenset(
+    ("norm1", "norm2", "mixer_norm", "conv_w", "conv_b", "dt_bias", "A_log", "D")
+)
+
+
+def init_layer(cfg: DecoderConfig, kind: str, key) -> dict:
+    """One layer's weights from its key: matrices N(0, 0.02) in bfloat16;
+    norm scales 1 + N(0, 0.02); Mamba's own conventions for the rest
+    (``A_log`` = log U(1, 16), ``dt_bias`` = softplus^-1 of a step size
+    log-uniform in [1e-3, 1e-1], ``D`` = 1, convolution U(-1/2, 1/2) with
+    bias, which is Conv1d's default at fan-in 4)."""
+    out = {}
+    for i, (name, shape) in enumerate(sorted(layer_shapes(cfg, kind).items())):
+        k = jax.random.fold_in(key, i)
+        if name in ("norm1", "norm2", "mixer_norm"):
+            leaf = 1.0 + 0.02 * jax.random.normal(k, shape, jnp.float32)
+        elif name == "A_log":
+            leaf = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0))
+        elif name == "dt_bias":
+            dt = jnp.exp(jax.random.uniform(k, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+            leaf = dt + jnp.log(-jnp.expm1(-dt))
+        elif name == "D":
+            leaf = jnp.ones(shape, jnp.float32)
+        elif name in ("conv_w", "conv_b"):
+            leaf = jax.random.uniform(k, shape, jnp.float32, -0.5, 0.5)
+        else:
+            leaf = (0.02 * jax.random.normal(k, shape, jnp.float32)).astype(jnp.bfloat16)
+        out[name] = leaf
+    return out
+
+
+def init_params(cfg: DecoderConfig, seed: int = 0) -> dict:
+    """Random parameters, each layer from its own key (a reference can
+    then make one layer at a time): ``{"embed", "final_norm", "layers":
+    [one tree a layer]}``."""
+    root = jax.random.PRNGKey(seed)
+    make = {
+        kind: jax.jit(functools.partial(init_layer, cfg, kind))
+        for kind in (MAMBA, ATTENTION)
+    }
+    k_embed = jax.random.fold_in(root, 1_000_000)
+    rows = cfg.vocab_held[1]
+    return {
+        "embed": (0.02 * jax.random.normal(k_embed, (rows, cfg.hidden), jnp.float32)
+                  ).astype(jnp.bfloat16),
+        "final_norm": jnp.ones((cfg.hidden,), jnp.float32),
+        "layers": [
+            make[kind](jax.random.fold_in(root, i)) for i, kind in enumerate(cfg.layer_types)
+        ],
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def param_bytes(cfg: DecoderConfig) -> float:
+    """HBM bytes of the held parameters (bfloat16 matrices, float32 vectors)."""
+    total = 2.0 * cfg.vocab_held[1] * cfg.hidden + 4.0 * cfg.hidden
+    for kind in cfg.layer_types:
+        for name, shape in layer_shapes(cfg, kind).items():
+            total += (4.0 if name in _F32_LEAVES else 2.0) * float(np.prod(shape))
+    return total
+
+
+def cache_bytes(cfg: DecoderConfig) -> float:
+    """HBM bytes of the state cache: ``slots`` + 1 (the scratch slot padded
+    decode rows write to) of convolution tail (bfloat16) and SSM state
+    (float32) a Mamba layer, keys and values (bfloat16) an attention layer."""
+    mamba = sum(k == MAMBA for k in cfg.layer_types)
+    attn = len(cfg.layer_types) - mamba
+    slot = mamba * (
+        2.0 * (cfg.mamba_conv - 1) * cfg.conv_width
+        + 4.0 * cfg.mamba_heads * cfg.mamba_head_dim * cfg.mamba_state
+    ) + attn * 2 * 2.0 * cfg.max_positions * cfg.kv_heads * cfg.head_dim
+    return (cfg.slots + 1) * slot
+
+
+# -- layers ------------------------------------------------------------------------
+
+_f32 = jnp.float32
+_bf16 = jnp.bfloat16
+
+
+def _mm(a, w):
+    """bfloat16 operands, float32 result."""
+    return jnp.dot(a.astype(_bf16), w, preferred_element_type=_f32)
+
+
+def rms_norm(x, scale, eps: float):
+    x = x.astype(_f32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * scale
+
+
+def glu(a, width: int):
+    return jax.nn.silu(a[..., :width]) * a[..., width:]
+
+
+def route(cfg: DecoderConfig, p: dict, u, live):
+    """Router over ALL experts: (selected ids [T, k], gates [T, k] float32,
+    held [T, k]: the selection is one of this chip's experts and the row
+    is live)."""
+    logits = _mm(u, p["router"])
+    top, sel = jax.lax.top_k(logits, cfg.experts_per_token)
+    gates = jax.nn.softmax(top, axis=-1)
+    first, n_held = cfg.experts_held
+    held = (sel >= first) & (sel < first + n_held) & live[:, None]
+    return sel, gates, held
+
+
+def routed_experts(cfg: DecoderConfig, p: dict, u, sel, gates, held):
+    """This chip's experts' part of the routed sum, as grouped products
+    over ragged token groups: the (token, selection) pairs sorted by
+    expert, one ``ragged_dot`` in and one out, unsorted and weighted.
+    Returns (sum [T, hidden] float32, tokens per held expert [E_held])."""
+    T, k = sel.shape
+    first, n_held = cfg.experts_held
+    flat = jnp.where(held, sel - first, n_held).reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.sum(
+        flat[:, None] == jnp.arange(n_held, dtype=flat.dtype)[None, :], axis=0,
+        dtype=jnp.int32,
+    )
+    rows = u.astype(_bf16)[order // k]
+    a = jax.lax.ragged_dot(rows, p["experts_in"], sizes, preferred_element_type=_f32)
+    mid = glu(a, cfg.expert_width).astype(_bf16)
+    y = jax.lax.ragged_dot(mid, p["experts_out"], sizes, preferred_element_type=_f32)
+    # rows past the last group belong to no expert held here
+    y = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None], y, 0.0)
+    back = jnp.argsort(order)
+    y = y[back].reshape(T, k, -1)
+    weight = jnp.where(held, gates, 0.0)
+    return jnp.einsum("tk,tkh->th", weight, y), sizes
+
+
+def shared_mlp(cfg: DecoderConfig, p: dict, u):
+    return _mm(glu(_mm(u, p["shared_in"]), cfg.shared_width), p["shared_out"])
+
+
+def moe_block(cfg: DecoderConfig, p: dict, x, live):
+    """x + r * (routed(u) + shared(u)), u = norm2(x); and what the layer
+    counted: selections [T, k], tokens per held expert, selections of
+    live rows that fell on absent experts."""
+    u = rms_norm(x, p["norm2"], cfg.rms_eps)
+    sel, gates, held = route(cfg, p, u, live)
+    routed, sizes = routed_experts(cfg, p, u, sel, gates, held)
+    x = x + cfg.residual_multiplier * (routed + shared_mlp(cfg, p, u))
+    absent = jnp.sum(live[:, None] & ~held, dtype=jnp.int32)
+    return x, {"sel": sel.astype(jnp.int32), "counts": sizes, "absent": absent}
+
+
+def _split_proj(cfg: DecoderConfig, proj):
+    """[z | xBC | dt] of the input projection; xBC is rounded to the
+    activation dtype here, so the convolution sees the same values
+    whether they come from this chunk or from the carried tail."""
+    di, cw = cfg.mamba_inner, cfg.conv_width
+    return proj[..., :di], proj[..., di:di + cw].astype(_bf16), proj[..., di + cw:]
+
+
+def _split_xbc(cfg: DecoderConfig, xbc):
+    di, n = cfg.mamba_inner, cfg.mamba_state
+    lead = xbc.shape[:-1]
+    x = xbc[..., :di].reshape(*lead, cfg.mamba_heads, cfg.mamba_head_dim)
+    return x, xbc[..., di:di + n], xbc[..., di + n:]
+
+
+def _gated_out(cfg: DecoderConfig, p: dict, y, z):
+    y = rms_norm(y * jax.nn.silu(z), p["mixer_norm"], cfg.rms_eps)
+    return _mm(y, p["out_proj"])
+
+
+def mamba_prefill(cfg: DecoderConfig, p: dict, u, tail, ssm, n):
+    """Mamba-2 mixer over one chunk of one sequence, the recurrence
+    computed chunk-wise (``mamba_chunk`` positions at a time). ``u``
+    [T, hidden]; ``tail`` [conv-1, conv_width] bfloat16, the last inputs
+    of the convolution; ``ssm`` [heads, head_dim, state] float32; ``n``
+    real positions. Returns (out [T, hidden], tail, ssm)."""
+    T, H, Q = u.shape[0], cfg.mamba_heads, cfg.mamba_chunk
+    K = cfg.mamba_conv
+    z, xbc, dt = _split_proj(cfg, _mm(u, p["in_proj"]))
+    ext = jnp.concatenate([tail, xbc], axis=0).astype(_f32)      # [K-1+T, C]
+    conv = p["conv_b"] + sum(p["conv_w"][j] * ext[j:j + T] for j in range(K))
+    new_tail = jax.lax.dynamic_slice_in_dim(ext, n, K - 1, axis=0).astype(_bf16)
+    x, B, C = _split_xbc(cfg, jax.nn.silu(conv))
+    real = jnp.arange(T) < n
+    dt = jnp.where(real[:, None], jax.nn.softplus(dt + p["dt_bias"]), 0.0)
+    A = -jnp.exp(p["A_log"])
+    nc = T // Q
+    a = (dt * A).reshape(nc, Q, H)
+    cum = jnp.cumsum(a, axis=1)                                     # [c, Q, H]
+    dtc, xc = dt.reshape(nc, Q, H), x.reshape(nc, Q, H, -1)
+    Bc, Cc = B.reshape(nc, Q, -1), C.reshape(nc, Q, -1)
+    # inside a chunk: position i reads j <= i through exp(sum of a over (j, i])
+    lower = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None]
+    seg = jnp.where(lower, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
+    w = jnp.exp(seg) * jnp.einsum("cin,cjn->cij", Cc, Bc)[..., None] * dtc[:, None]
+    y = jnp.einsum("cijh,cjhp->cihp", w, xc)
+    # what each chunk leaves behind, and what it receives
+    to_end = jnp.exp(cum[:, -1:, :] - cum) * dtc                    # [c, Q, H]
+    left = jnp.einsum("cjh,cjhp,cjn->chpn", to_end, xc, Bc)
+    chunk_decay = jnp.exp(cum[:, -1, :])                            # [c, H]
+    received = []
+    for c in range(nc):
+        received.append(ssm)
+        ssm = chunk_decay[c][:, None, None] * ssm + left[c]
+    received = jnp.stack(received)                                  # [c, H, P, N]
+    y = y + jnp.einsum("cin,chpn->cihp", Cc, received) * jnp.exp(cum)[..., None]
+    y = (y + p["D"][:, None] * xc).reshape(T, -1)
+    return _gated_out(cfg, p, y, z), new_tail, ssm
+
+
+def mamba_decode(cfg: DecoderConfig, p: dict, u, tail, ssm):
+    """One recurrence step for a batch: ``u`` [B, hidden], ``tail``
+    [B, conv-1, conv_width], ``ssm`` [B, heads, head_dim, state]."""
+    z, xbc, dt = _split_proj(cfg, _mm(u, p["in_proj"]))
+    ext = jnp.concatenate([tail, xbc[:, None]], axis=1)             # [B, K, C]
+    conv = p["conv_b"] + jnp.einsum("kc,bkc->bc", p["conv_w"], ext.astype(_f32))
+    x, B, C = _split_xbc(cfg, jax.nn.silu(conv))
+    dt = jax.nn.softplus(dt + p["dt_bias"])                         # [B, H]
+    decay = jnp.exp(dt * -jnp.exp(p["A_log"]))
+    ssm = (
+        decay[:, :, None, None] * ssm
+        + (dt[:, :, None] * x)[..., None] * B[:, None, None, :]
+    )
+    y = jnp.sum(ssm * C[:, None, None, :], axis=-1) + p["D"][:, None] * x
+    return _gated_out(cfg, p, y.reshape(y.shape[0], -1), z), ext[:, 1:], ssm
+
+
+def _attend(cfg: DecoderConfig, q, k, v, visible):
+    """q [.., T, heads, d] against k, v [.., P, kv_heads, d]; ``visible``
+    [.., T, P]. Scores scaled by ``attention_multiplier``, softmax in
+    float32, no positional encoding."""
+    g = cfg.heads // cfg.kv_heads
+    q = q.reshape(*q.shape[:-2], cfg.kv_heads, g, cfg.head_dim)
+    s = jnp.einsum("...tkgd,...pkd->...kgtp", q, k, preferred_element_type=_f32)
+    s = jnp.where(visible[..., None, None, :, :], s * cfg.attention_multiplier,
+                  jnp.finfo(_f32).min)
+    w = jax.nn.softmax(s, axis=-1).astype(_bf16)
+    out = jnp.einsum("...kgtp,...pkd->...tkgd", w, v, preferred_element_type=_f32)
+    return out.reshape(*out.shape[:-3], cfg.heads * cfg.head_dim)
+
+
+def _qkv(cfg: DecoderConfig, p: dict, u):
+    lead = u.shape[:-1]
+    q = _mm(u, p["wq"]).astype(_bf16).reshape(*lead, cfg.heads, cfg.head_dim)
+    k = _mm(u, p["wk"]).astype(_bf16).reshape(*lead, cfg.kv_heads, cfg.head_dim)
+    v = _mm(u, p["wv"]).astype(_bf16).reshape(*lead, cfg.kv_heads, cfg.head_dim)
+    return q, k, v
+
+
+def attention_prefill(cfg: DecoderConfig, p: dict, u, keys, values, pos, n):
+    """One chunk at positions ``pos``..: its keys/values go into the
+    slot's slab (``keys``, ``values`` [positions, kv_heads, d]; a padded
+    position writes nothing), its queries read the slab causally."""
+    T, P = u.shape[0], keys.shape[0]
+    q, k, v = _qkv(cfg, p, u)
+    real = (jnp.arange(T) < n)[:, None, None]
+    old_k = jax.lax.dynamic_slice_in_dim(keys, pos, T, axis=0)
+    old_v = jax.lax.dynamic_slice_in_dim(values, pos, T, axis=0)
+    keys = jax.lax.dynamic_update_slice_in_dim(keys, jnp.where(real, k, old_k), pos, 0)
+    values = jax.lax.dynamic_update_slice_in_dim(values, jnp.where(real, v, old_v), pos, 0)
+    visible = jnp.arange(P)[None, :] <= (pos + jnp.arange(T))[:, None]
+    return _mm(_attend(cfg, q, keys, values, visible), p["wo"]), keys, values
+
+
+def attention_decode(cfg: DecoderConfig, p: dict, u, keys, values, pos):
+    """One token a sequence: ``keys``, ``values`` [B, positions, kv, d],
+    ``pos`` [B] the token's position."""
+    q, k, v = _qkv(cfg, p, u)
+    rows = jnp.arange(u.shape[0])
+    keys = keys.at[rows, pos].set(k)
+    values = values.at[rows, pos].set(v)
+    visible = (jnp.arange(keys.shape[1])[None, :] <= pos[:, None])[:, None, :]
+    out = _attend(cfg, q[:, None], keys, values, visible)[:, 0]
+    return _mm(out, p["wo"]), keys, values
+
+
+def _logits(cfg: DecoderConfig, params: dict, x):
+    """Final norm, tied head over the held rows, ``logits_scaling``."""
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    return jnp.dot(x.astype(_bf16), params["embed"].T,
+                   preferred_element_type=_f32) / cfg.logits_scaling
+
+
+def _embed(cfg: DecoderConfig, params: dict, ids):
+    return cfg.embedding_multiplier * params["embed"][ids].astype(_f32)
+
+
+# -- the two programs ---------------------------------------------------------------
+
+
+def _stacked(counted: list) -> dict:
+    """Per-layer counts -> one tree with a leading layer axis."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *counted)
+
+
+def prefill_chunk(cfg: DecoderConfig, params: dict, state: list, slot, ids, pos, n):
+    """One chunk of one sequence through every layer. ``state``: the
+    cache's arrays, a dict a layer, ``[slots + 1, ...]`` each. Returns
+    (state, greedy next id, logits [held rows] at the chunk's last real
+    position, counts: ``sel`` [layers, T, k], ``counts`` [layers, held
+    experts], ``absent`` [layers])."""
+    x = _embed(cfg, params, ids)
+    live = jnp.arange(ids.shape[0]) < n
+    r = cfg.residual_multiplier
+    new_state, counted = [], []
+    for kind, p, s in zip(cfg.layer_types, params["layers"], state):
+        u = rms_norm(x, p["norm1"], cfg.rms_eps)
+        mine = {nm: jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False)
+                for nm, a in s.items()}
+        if kind == MAMBA:
+            out, tail, ssm = mamba_prefill(cfg, p, u, mine["tail"], mine["ssm"], n)
+            mine = {"tail": tail, "ssm": ssm}
+        else:
+            out, keys, values = attention_prefill(
+                cfg, p, u, mine["keys"], mine["values"], pos, n)
+            mine = {"keys": keys, "values": values}
+        x, counts = moe_block(cfg, p, x + r * out, live)
+        new_state.append({nm: jax.lax.dynamic_update_index_in_dim(s[nm], new, slot, 0)
+                          for nm, new in mine.items()})
+        counted.append(counts)
+    last = jax.lax.dynamic_index_in_dim(x, n - 1, 0, keepdims=False)
+    logits = _logits(cfg, params, last)
+    return new_state, jnp.argmax(logits).astype(jnp.int32), logits, _stacked(counted)
+
+
+def decode_step(cfg: DecoderConfig, params: dict, state: list, slots, ids, pos, live):
+    """One token for each row: ``slots``, ``ids``, ``pos``, ``live`` [B]
+    (a padded row is not live: it reads and writes the scratch slot and
+    routes nowhere). Returns (state, next ids [B] (greedy), logits
+    [B, held rows], counts as ``prefill_chunk`` with T = B)."""
+    x = _embed(cfg, params, ids)
+    r = cfg.residual_multiplier
+    new_state, counted = [], []
+    for kind, p, s in zip(cfg.layer_types, params["layers"], state):
+        u = rms_norm(x, p["norm1"], cfg.rms_eps)
+        if kind == MAMBA:
+            out, tail, ssm = mamba_decode(cfg, p, u, s["tail"][slots], s["ssm"][slots])
+            mine = {"tail": tail, "ssm": ssm}
+        else:
+            out, keys, values = attention_decode(
+                cfg, p, u, s["keys"][slots], s["values"][slots], pos)
+            mine = {"keys": keys, "values": values}
+        x, counts = moe_block(cfg, p, x + r * out, live)
+        new_state.append({nm: s[nm].at[slots].set(new) for nm, new in mine.items()})
+        counted.append(counts)
+    logits = _logits(cfg, params, x)
+    return (new_state, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits,
+            _stacked(counted))
+
+
+def empty_state(cfg: DecoderConfig) -> list:
+    """Zeroed cache arrays: a dict a layer, each leaf ``[slots + 1, ...]``
+    (the last slot is the scratch slot)."""
+    S = cfg.slots + 1
+    out = []
+    for kind in cfg.layer_types:
+        if kind == MAMBA:
+            out.append({
+                "tail": jnp.zeros((S, cfg.mamba_conv - 1, cfg.conv_width), _bf16),
+                "ssm": jnp.zeros(
+                    (S, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), _f32),
+            })
+        else:
+            shape = (S, cfg.max_positions, cfg.kv_heads, cfg.head_dim)
+            out.append({"keys": jnp.zeros(shape, _bf16), "values": jnp.zeros(shape, _bf16)})
+    return out
+
+
+def _zero_slot(state: list, slot):
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_update_index_in_dim(
+            a, jnp.zeros(a.shape[1:], a.dtype), slot, 0),
+        state,
+    )
+
+
+# -- cost models (device sites) -------------------------------------------------------
+
+
+def _layer_matrix_params(cfg: DecoderConfig, kind: str, experts: float) -> float:
+    """Matrix parameters one token multiplies through in one layer, with
+    ``experts`` routed experts a token."""
+    shapes = layer_shapes(cfg, kind)
+    dense = sum(
+        float(np.prod(s)) for name, s in shapes.items()
+        if len(s) == 2 and name != "conv_w"
+    )
+    per_expert = float(np.prod(shapes["experts_in"][1:]) + np.prod(shapes["experts_out"][1:]))
+    return dense + experts * per_expert
+
+
+@functools.lru_cache(maxsize=None)
+def flops_per_token(cfg: DecoderConfig) -> float:
+    """Forward FLOPs of one token through the held share: two a matrix
+    parameter, the routed experts at this chip's expected share of the
+    ``experts_per_token`` selections; the head is not included."""
+    share = cfg.experts_per_token * cfg.experts_held[1] / cfg.experts
+    return 2.0 * sum(_layer_matrix_params(cfg, k, share) for k in cfg.layer_types)
+
+
+def prefill_cost_model(cfg: DecoderConfig) -> tuple[float, float]:
+    """(flops, bytes) of one prefill chunk: the chunk's tokens through
+    every matrix, one read of the held parameters."""
+    return cfg.prefill_chunk * flops_per_token(cfg), param_bytes(cfg)
+
+
+def decode_cost_model(cfg: DecoderConfig, batch: int) -> tuple[float, float]:
+    """(flops, bytes) of one decode step: bandwidth-bound, one read of
+    the held parameters at the most."""
+    head = 2.0 * cfg.vocab_held[1] * cfg.hidden
+    return batch * (flops_per_token(cfg) + head), param_bytes(cfg)
+
+
+device_site(
+    "answer.prefill",
+    cost_model=prefill_cost_model,
+    dtypes=("int32", "bfloat16", "float32"),
+    where="pathway_tpu/models/decoder.py:AnswerModel._prefill_prompt",
+    donates=("state",),
+    description="one fixed chunk of one prompt through the hybrid decoder, "
+                "state carried through the cache slot",
+)
+
+device_site(
+    "answer.decode",
+    cost_model=decode_cost_model,
+    dtypes=("int32", "bfloat16", "float32"),
+    where="pathway_tpu/models/decoder.py:AnswerModel._generate",
+    donates=("state",),
+    description="one token for each live slot (batch buckets 1, 2, 4, 8)",
+)
+
+
+# -- the state cache -----------------------------------------------------------------
+
+
+class StateCache:
+    """Per-sequence state of both kinds, by slot: convolution tail and
+    SSM state for each Mamba layer (constant in length), keys/values for
+    each attention layer (growing, up to ``max_positions``). ``acquire``
+    zeroes a slot and hands it out; with every slot out it waits.
+    ``release`` gives it back. ``state`` is the device arrays (the jitted
+    programs donate and return them); ``scratch`` is the extra slot the
+    padded rows of a decode batch use."""
+
+    def __init__(self, cfg: DecoderConfig):
+        self.cfg = cfg
+        self.state = empty_state(cfg)
+        self.scratch = cfg.slots
+        self._free = list(range(cfg.slots))
+        self._cond = threading.Condition()
+        self._zero = jax.jit(_zero_slot, donate_argnums=0)
+
+    @property
+    def in_use(self) -> int:
+        return self.cfg.slots - len(self._free)
+
+    def acquire(self) -> int:
+        with _flight.span("cache.acquire") as sp:
+            with self._cond:
+                while not self._free:
+                    self._cond.wait()
+                slot = self._free.pop(0)
+                self.state = self._zero(self.state, np.int32(slot))
+                sp.args["slot"] = slot
+                sp.args["in_use"] = self.in_use
+        return slot
+
+    def release(self, slot: int) -> None:
+        with _flight.span("cache.release", slot=slot) as sp:
+            with self._cond:
+                if slot in self._free or not 0 <= slot < self.cfg.slots:
+                    raise ValueError(f"slot {slot} is not out")
+                self._free.append(slot)
+                sp.args["in_use"] = self.in_use
+                self._cond.notify()
+
+
+# -- the host-facing model -------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class Generation:
+    """What one prompt produced. ``logits`` [new tokens, held rows]
+    float32 and the expert selections (``prompt_routes`` [layers, prompt
+    tokens, k], ``decode_routes`` [layers, new tokens - 1, k]) and ``ssm``
+    (the slot's final SSM state, a device array a Mamba layer) only
+    for the rows ``generate`` was asked to keep."""
+
+    prompt: np.ndarray
+    tokens: np.ndarray
+    logits: np.ndarray | None = None
+    prompt_routes: np.ndarray | None = None
+    decode_routes: np.ndarray | None = None
+    ssm: list | None = None
+
+
+class Counters:
+    """What the model counted since it was made; plain numbers a reader
+    may copy at any time."""
+
+    def __init__(self, cfg: DecoderConfig):
+        self.expert_tokens = np.zeros((len(cfg.layer_types), cfg.experts_held[1]), np.int64)
+        self.held_selections = 0
+        self.absent_selections = 0
+        self.prefill_real = 0
+        self.prefill_padded = 0
+        self.prompts = 0
+        self.decode_steps: dict[int, int] = {}      # live rows -> steps
+        self.decode_experts_touched = 0             # sum over steps and layers
+
+    def add_routing(self, counts: np.ndarray, absent: np.ndarray) -> None:
+        self.expert_tokens += counts
+        self.held_selections += int(counts.sum())
+        self.absent_selections += int(absent.sum())
+
+
+class AnswerModel:
+    """Prompt ids in, greedy continuations out: ``generate`` prefills a
+    call's prompts one after another in fixed chunks, then decodes them
+    together in lock-step, one dispatch a token, and waits once."""
+
+    device_sites = ("answer.prefill", "answer.decode")
+
+    def __init__(self, cfg: DecoderConfig, params: dict | None = None, *, seed: int = 0):
+        self.cfg = cfg
+        self.params = params if params is not None else init_params(cfg, seed)
+        self.cache = StateCache(cfg)
+        self.counters = Counters(cfg)
+        self._lock = threading.Lock()
+
+        def answer_prefill(params, state, slot, ids, pos, n):
+            return prefill_chunk(cfg, params, state, slot, ids, pos, n)
+
+        def answer_decode(params, state, slots, ids, pos, live):
+            return decode_step(cfg, params, state, slots, ids, pos, live)
+
+        self._prefill = jax.jit(answer_prefill, donate_argnums=1)
+        self._decode = jax.jit(answer_decode, donate_argnums=1)
+        self._seen: set = set()
+
+    def _dispatch(self, site: str, fn, bucket, *args, **span_args):
+        """One supervised device call that takes and returns the cache's
+        arrays; its ring span, and the armed plane's record."""
+        first = (site, bucket) not in self._seen
+        if first:
+            self._seen.add((site, bucket))
+            _DEVICE.note_recompile(site)
+        dev = _DEVICE.begin(site, first=first, **span_args)
+        try:
+            state, *out = _devsup.supervised_dispatch(
+                site, lambda: fn(self.params, self.cache.state, *args))
+        except BaseException:
+            _DEVICE.end(dev, None, block=False)
+            raise
+        self.cache.state = state
+        flops, nbytes = (
+            prefill_cost_model(self.cfg) if site == "answer.prefill"
+            else decode_cost_model(self.cfg, bucket)
+        )
+        _DEVICE.end(dev, out[0], flops=flops, bytes_accessed=nbytes,
+                    transfer_bytes=nbytes_of(*args))
+        return out
+
+    def _prefill_prompt(self, slot: int, ids: np.ndarray):
+        """Every chunk of one prompt; (first generated id, logits at the
+        prompt's last position, [(real positions, counts) a chunk])."""
+        T = self.cfg.prefill_chunk
+        out, counted = None, []
+        for at in range(0, len(ids), T):
+            n = min(T, len(ids) - at)
+            chunk = np.zeros(T, np.int32)
+            chunk[:n] = ids[at:at + n]
+            *out, counts = self._dispatch(
+                "answer.prefill", self._prefill, T,
+                np.int32(slot), chunk, np.int32(at), np.int32(n),
+                chunk=at // T, real=n, padded=T - n,
+            )
+            counted.append((n, counts))
+            self.counters.prefill_real += n
+            self.counters.prefill_padded += T - n
+        return out[0], out[1], counted
+
+    def generate(self, prompts: Sequence[Sequence[int]], max_new_tokens: int,
+                 keep: Sequence[int] = ()) -> list[Generation]:
+        """Greedy continuations of ``prompts`` (token ids of the held
+        vocabulary rows), ``max_new_tokens`` each. At most ``slots``
+        prompts a call. The rows named in ``keep`` also return their
+        logits and expert selections."""
+        cfg = self.cfg
+        if not 1 <= len(prompts) <= cfg.slots:
+            raise ValueError(f"a call takes 1..{cfg.slots} prompts, got {len(prompts)}")
+        if max_new_tokens < 1:
+            raise ValueError("max_new_tokens must be at least 1")
+        room = cfg.max_positions - max_new_tokens
+        prompts = [np.asarray(p, np.int32)[:room] for p in prompts]
+        if any(len(p) == 0 for p in prompts):
+            raise ValueError("an empty prompt")
+        rows_held = cfg.vocab_held[1]
+        if any(int(p.max()) >= rows_held or int(p.min()) < 0 for p in prompts):
+            raise ValueError(f"a prompt id outside the held vocabulary rows 0..{rows_held}")
+        with self._lock, _flight.span(
+            "answer.generate", rows=len(prompts),
+            prompt_tokens=int(sum(len(p) for p in prompts)),
+            new_tokens=max_new_tokens * len(prompts),
+        ):
+            slots = [self.cache.acquire() for _ in prompts]
+            try:
+                return self._generate(prompts, slots, max_new_tokens, sorted(set(keep)))
+            finally:
+                for slot in slots:
+                    self.cache.release(slot)
+
+    def _generate(self, prompts, slots, max_new_tokens, keep):
+        rows = len(prompts)
+        prefilled = [self._prefill_prompt(slot, ids) for slot, ids in zip(slots, prompts)]
+        self.counters.prompts += rows
+        # lock-step decode: the ids stay on the device from step to step
+        bucket = next(b for b in DECODE_BUCKETS if b >= rows)
+        pad = bucket - rows
+        slot_ids = np.asarray(slots + [self.cache.scratch] * pad, np.int32)
+        live = np.asarray([True] * rows + [False] * pad)
+        lengths = np.asarray([len(p) for p in prompts] + [0] * pad, np.int32)
+        ids = jnp.stack([p[0] for p in prefilled] + [jnp.int32(0)] * pad)
+        routing = ("counts", "absent")
+        fetch = {
+            "tokens": [ids],
+            # every dispatch's routing counts: the prefill chunks', then the steps'
+            "counts": [{k: c[k] for k in routing} for p in prefilled for _, c in p[2]],
+            "kept": {r: {"logits": [prefilled[r][1]],
+                         "prompt_routes": [c["sel"] for _, c in prefilled[r][2]],
+                         "decode_routes": []} for r in keep},
+        }
+        chunks = len(fetch["counts"])
+        with _flight.span("answer.decode", batch=rows, bucket=bucket,
+                          steps=max_new_tokens - 1):
+            for step in range(max_new_tokens - 1):
+                ids, logits, counts = self._dispatch(
+                    "answer.decode", self._decode, bucket,
+                    slot_ids, ids, lengths + step, live,
+                    span="answer.decode.step", batch=rows,
+                )
+                fetch["tokens"].append(ids)
+                fetch["counts"].append({k: counts[k] for k in routing})
+                for r in keep:
+                    fetch["kept"][r]["logits"].append(logits[r])
+                    fetch["kept"][r]["decode_routes"].append(counts["sel"][:, r])
+        self.counters.decode_steps[rows] = (
+            self.counters.decode_steps.get(rows, 0) + max_new_tokens - 1
+        )
+        final_ssm = {
+            r: [s["ssm"][slots[r]] for s in self.cache.state if "ssm" in s]
+            for r in keep
+        }
+        with _flight.span("answer.wait"):
+            # every copy queues behind the last step before anything waits
+            leaves = jax.tree_util.tree_leaves(fetch)
+            for leaf in leaves:
+                leaf.copy_to_host_async()
+            jax.block_until_ready(leaves)
+        with _flight.span("answer.d2h") as sp:
+            got = jax.device_get(fetch)
+            sp.args["bytes"] = int(sum(x.nbytes for x in jax.tree_util.tree_leaves(got)))
+        for c in got["counts"]:
+            self.counters.add_routing(c["counts"], c["absent"])
+        for c in got["counts"][chunks:]:
+            self.counters.decode_experts_touched += int((c["counts"] > 0).sum())
+        tokens = np.stack(got["tokens"], axis=1)                    # [bucket, new]
+        out = [Generation(prompt=prompts[r], tokens=tokens[r]) for r in range(rows)]
+        for r, kept in got["kept"].items():
+            gen = out[r]
+            gen.ssm = final_ssm[r]
+            gen.logits = np.stack(kept["logits"])
+            gen.prompt_routes = np.concatenate(
+                [sel[:, :n] for sel, (n, _) in zip(kept["prompt_routes"], prefilled[r][2])],
+                axis=1)
+            gen.decode_routes = np.stack(kept["decode_routes"], axis=1) \
+                if kept["decode_routes"] else gen.prompt_routes[:, :0]
+        return out

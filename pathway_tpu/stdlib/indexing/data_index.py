@@ -98,6 +98,16 @@ class DataIndex:
             return self._collapsed(matches, query_table, as_of_now)
         return self._flat(matches, query_table, as_of_now)
 
+    @staticmethod
+    def _join_queries(query_table: Table, as_of_now: bool):
+        """How a query meets its matches. As of now, the matches are
+        forgotten at the next timestamp while the query lives until it is
+        answered and deleted: an ordinary left join would then revise the
+        answer to "no matches" in between (a second row through everything
+        downstream: a second prompt for a chat). The as-of-now join answers
+        once, at the query's own timestamp, and replays that on deletion."""
+        return query_table.asof_now_join if as_of_now else query_table.join
+
     def _flat(self, matches: Table, query_table: Table, as_of_now: bool):
         data_table = self.data_table
         joined = matches.join(
@@ -110,7 +120,7 @@ class DataIndex:
         if as_of_now:
             joined = joined._forget_immediately()
         # one OUTPUT row per match: ids derive from the (query, match) pair
-        return query_table.join(
+        return self._join_queries(query_table, as_of_now)(
             joined,
             query_table.id == joined[_QUERY_ID],
             how="left",
@@ -159,7 +169,7 @@ class DataIndex:
             grouped["_pw_pairs"],
         )
         shaped = grouped.select(**cols)
-        return query_table.join(
+        return self._join_queries(query_table, as_of_now)(
             shaped,
             query_table.id == shaped.id,
             how="left",

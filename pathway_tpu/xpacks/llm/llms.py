@@ -1,8 +1,10 @@
 """LLM chat wrappers (reference: python/pathway/xpacks/llm/llms.py:27-707).
 
 Remote chats are async UDFs (capacity/retry/cache); HFPipelineChat runs a
-local transformers pipeline (CPU/offline). `prompt_chat_single_qa` mirrors
-the reference helper (:686).
+local transformers pipeline (CPU/offline); TPUChat runs the in-repo hybrid
+decoder (models/decoder.py) on the default JAX device, a logical-time batch
+of prompts a call. `prompt_chat_single_qa` mirrors the reference helper
+(:686).
 """
 
 from __future__ import annotations
@@ -148,6 +150,74 @@ class HFPipelineChat(BaseChat):
 
     def crop_to_max_tokens(self, text):  # reference parity helper
         return text
+
+
+class TPUChat(BaseChat):
+    """The answer model on the chip: a batched UDF like
+    ``SentenceTransformerEmbedder``. A call's prompts (the rows of one
+    engine step, at most the cache's slots a call) are tokenised, prefilled one
+    after another in fixed chunks, decoded together in lock-step for
+    ``max_new_tokens`` (greedy; there is no stop token) and detokenised.
+
+    ``model``: an :class:`pathway_tpu.models.decoder.AnswerModel`.
+    Declared non-deterministic like the remote chats, so the engine keeps
+    each answer beside its row and the retraction that
+    ``delete_completed_queries`` commits replays it: a question is
+    generated for once."""
+
+    def __init__(self, model, *, max_new_tokens: int = 32):
+        from pathway_tpu.models.tokenizer import get_tokenizer
+
+        self.model = model
+        cfg = model.cfg
+        self.max_new_tokens = int(max_new_tokens)
+        self.max_prompt_tokens = cfg.max_positions - self.max_new_tokens
+        self.tokenizer = get_tokenizer(
+            None, vocab_size=cfg.vocab_held[1], max_length=self.max_prompt_tokens + 1)
+        vocab = getattr(self.tokenizer, "vocab", None)
+        self._pieces = (
+            [piece for piece, _ in sorted(vocab.items(), key=lambda kv: kv[1])]
+            if vocab else None
+        )
+
+        def chat_batch(messages_list: list, **_kw) -> list:
+            from pathway_tpu.internals import flight
+
+            texts = [
+                "\n".join(str(m.get("content", "")) for m in _normalize_messages(msgs))
+                for msgs in messages_list
+            ]
+            with flight.span("answer.tokenize", texts=len(texts)) as sp:
+                prompts = self.tokenize(texts)
+                sp.args["tokens"] = sum(len(p) for p in prompts)
+            made = self.model.generate(prompts, self.max_new_tokens)
+            return [self.detokenize(g.tokens) for g in made]
+
+        super().__init__(
+            chat_batch,
+            return_type=str,
+            deterministic=False,
+            max_batch_size=cfg.slots,
+        )
+
+    def tokenize(self, texts: list) -> list:
+        """[CLS] and the pieces of each text (no [SEP]: the model
+        continues the text), cut to what the cache leaves room for."""
+        ids, mask = self.tokenizer(
+            [t or "" for t in texts], max_length=self.max_prompt_tokens + 1)
+        return [row[: int(n) - 1] for row, n in zip(ids, mask.sum(axis=1))]
+
+    def detokenize(self, ids) -> str:
+        if self._pieces is None:
+            return " ".join(f"<{int(i)}>" for i in ids)
+        words: list[str] = []
+        for i in ids:
+            piece = self._pieces[int(i)] if int(i) < len(self._pieces) else f"<{int(i)}>"
+            if piece.startswith("##") and words:
+                words[-1] += piece[2:]
+            else:
+                words.append(piece)
+        return " ".join(words)
 
 
 class CohereChat(BaseChat):

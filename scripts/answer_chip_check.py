@@ -1,0 +1,115 @@
+#!/usr/bin/env python3
+"""The answer model at its published widths on the chip, outside the
+server: one chip's share of granite-4.0-h-small made from a seed, a few
+prompts prefilled in chunks and decoded through the cache, held to
+benchmark/reference_decoder.py's one full forward; times a chunk and a
+decode step; prints the device's memory peak.
+
+    chiprun -- python scripts/answer_chip_check.py [--seed N] [--control 1]
+
+Refuses any backend but ``tpu``."""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [os.path.join(ROOT, "benchmark"), ROOT]
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+
+import numpy as np  # noqa: E402
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--seed", type=int, default=2147484001)
+    parser.add_argument("--lengths", default="700,1400,3100")
+    parser.add_argument("--control", type=int, default=0)
+    args = parser.parse_args()
+    import jax
+
+    if jax.default_backend() != "tpu":
+        print("answer_chip_check: no TPU -- refusing", file=sys.stderr)
+        return 2
+    import loader
+    import reference_decoder as ref
+    from pathway_tpu.models import decoder as dec
+
+    cell = loader.Cell(loader.load(), "granite-4.0-h-small.answer-steady")
+    config = cell.config
+    a = ref.arch_of(config)
+    cfg = dec.DecoderConfig.from_hf(
+        {**config, **{k: config["published"][k] for k in config["reduced"]}},
+        layers=config["num_hidden_layers"], experts_held=tuple(config["held"]["experts"]),
+        vocab_held=tuple(config["held"]["vocab_rows"]), **config["serving"])
+    t0 = time.monotonic()
+
+    class Ctx:
+        darch, seed = a, args.seed
+
+    params = cell.pipeline.decoder_params(Ctx)
+    jax.block_until_ready(params)
+    model = dec.AnswerModel(cfg, params)
+    print(json.dumps({"weights_s": round(time.monotonic() - t0, 2),
+                      "param_bytes": dec.param_bytes(cfg), "cache_bytes": dec.cache_bytes(cfg)}),
+          flush=True)
+    rng = np.random.default_rng(args.seed & 0xFFFFFFFF)
+    lengths = [int(n) for n in args.lengths.split(",")]
+    prompts = [rng.integers(1000, 26000, size=n).astype(np.int32) for n in lengths]
+    new = 32
+    for label in ("cold", "warm", "warm2"):
+        t = time.monotonic()
+        made = model.generate(prompts, new, keep=range(len(prompts)))
+        print(json.dumps({"generate": label, "seconds": round(time.monotonic() - t, 3)}), flush=True)
+    # one prompt alone, timed: its chunks and its 31 steps
+    for n in lengths:
+        t = time.monotonic()
+        model.generate([prompts[lengths.index(n)]], new)
+        print(json.dumps({"alone_tokens": n, "seconds": round(time.monotonic() - t, 4)}), flush=True)
+    t = time.monotonic()
+    model.generate([prompts[0][:500]] * 8, new)
+    print(json.dumps({"eight_of_500": round(time.monotonic() - t, 4)}), flush=True)
+    stats = jax.devices()[0].memory_stats() or {}
+    print(json.dumps({"memory_peak_bytes": stats.get("peak_bytes_in_use"),
+                      "bytes_in_use": stats.get("bytes_in_use")}), flush=True)
+    states = [[np.asarray(layer) for layer in g.ssm] for g in made]
+    for leaf in jax.tree_util.tree_leaves((model.params, model.cache.state)):
+        leaf.delete()
+    seqs = [np.concatenate([g.prompt, g.tokens[:-1]]) for g in made]
+    routes = [np.concatenate([g.prompt_routes, g.decode_routes], axis=1) for g in made]
+    for precision in ("f32",) + (("fp8",) if args.control else ()):
+        t = time.monotonic()
+        out = ref.forward(a, args.seed, seqs, last=new, routes=routes if precision == "f32" else None,
+                          router_tol=0.05, precision=precision)
+        secs = round(time.monotonic() - t, 2)
+        if precision == "fp8":
+            base = ref.forward(a, args.seed, seqs, last=new, routes=[o["routes"] for o in out],
+                               router_tol=0.05)
+        for i, (g, o) in enumerate(zip(made, out)):
+            want = (o if precision == "f32" else base[i])["logits"].astype(np.float64)
+            got = g.logits if precision == "f32" else o["logits"]
+            ref_states = (o if precision == "f32" else base[i])["states"]
+            got_states = states[i] if precision == "f32" else o["states"]
+            spread = want.max(-1) - want.min(-1)
+            gap = (np.abs(got - want).max(-1) / spread).max()
+            tok = g.tokens if precision == "f32" else got.argmax(-1)
+            behind = ((want.max(-1) - want[np.arange(new), tok]) / spread).max()
+            state_gap = max(float(np.abs(x - y).max() / np.abs(y).max())
+                            for x, y in zip(got_states, ref_states))
+            rgap = (o if precision == "f32" else base[i])
+            print(json.dumps({
+                "precision": precision, "tokens": len(seqs[i]), "reference_s": secs,
+                "logit_gap": float(gap), "token_gap": float(behind), "state_gap": state_gap,
+                "router_gap": rgap["router_gap"], "wrong_routes": rgap["wrong_routes"],
+                "logit_spread": float(spread.mean()),
+                "agree": int((tok == want.argmax(-1)).sum()),
+            }), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    code = main()
+    sys.stdout.flush()
+    os._exit(code)

@@ -37,6 +37,7 @@ from pathway_tpu.internals.device import (  # noqa: E402
 from pathway_tpu.internals.monitoring import ProberStats  # noqa: E402
 
 ALL_SITES = {
+    "answer.decode", "answer.prefill",
     "encoder.forward", "ingest.fused", "knn.search", "knn.sharded_search",
     "knn.sharded_write", "knn.write", "pallas.topk", "serve.window",
 }
@@ -360,6 +361,7 @@ def test_registry_covers_every_dispatch_site():
     # dispatch modules populates the registry (analyze_device_plan pulls
     # most in; pallas + the serving gateway register on import here)
     import pathway_tpu.io.http._server  # noqa: F401
+    import pathway_tpu.models.decoder  # noqa: F401
     import pathway_tpu.models.encoder  # noqa: F401
     import pathway_tpu.ops.ingest  # noqa: F401
     import pathway_tpu.ops.knn  # noqa: F401

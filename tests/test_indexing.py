@@ -3,6 +3,7 @@ python/pathway/tests/test_knn.py + external_index/ tests — static tables,
 deterministic embedder, compare against oracle)."""
 
 import numpy as np
+import pytest
 
 import pathway_tpu as pw
 from pathway_tpu.internals.graph_runner import GraphRunner
@@ -226,3 +227,70 @@ def test_index_as_of_now_streaming():
     assert len(additions) == 1
     retractions = [e for e in events if not e[2]]
     assert not retractions
+
+
+@pytest.mark.parametrize("collapse_rows", [True, False])
+def test_data_index_as_of_now_answers_each_query_once(collapse_rows):
+    """A ``DataIndex`` query as of now gives one answer per query and never
+    revises it: the matches are forgotten at the next timestamp while the
+    query row lives on, and a plain left join of the two would then revise
+    every answer to "no matches" (a second row through everything
+    downstream of ``/v1/retrieve``, a second prompt for a chat)."""
+    import threading
+    import time
+
+    class Docs(pw.io.python.ConnectorSubject):
+        def __init__(self, gate):
+            super().__init__()
+            self.gate = gate
+
+        def run(self):
+            self.next(name="d1", vec="1.0,0.0")
+            self.next(name="d2", vec="0.0,1.0")
+            self.commit()
+            self.gate.wait(timeout=5)
+            self.next(name="d3", vec="0.6,0.8")     # a later timestamp
+            self.commit()
+
+    class Queries(pw.io.python.ConnectorSubject):
+        def __init__(self, gate):
+            super().__init__()
+            self.gate = gate
+
+        def run(self):
+            time.sleep(0.3)
+            self.next(qid="q1", qvec="0.0,1.0")
+            self.next(qid="q2", qvec="1.0,0.1")
+            self.commit()
+            time.sleep(0.3)
+            self.gate.set()
+
+    class DS(pw.Schema):
+        name: str = pw.column_definition(primary_key=True)
+        vec: str
+
+    class QS(pw.Schema):
+        qid: str = pw.column_definition(primary_key=True)
+        qvec: str
+
+    gate = threading.Event()
+    docs = pw.io.python.read(Docs(gate), schema=DS, autocommit_duration_ms=None)
+    queries = pw.io.python.read(Queries(gate), schema=QS, autocommit_duration_ms=None)
+    parse = pw.udf(
+        lambda s: tuple(float(x) for x in s.split(",")), return_type=tuple, deterministic=True)
+    docs = docs.select(pw.this.name, vec=parse(pw.this.vec))
+    queries = queries.select(pw.this.qid, qvec=parse(pw.this.qvec))
+
+    index = DataIndex(docs, BruteForceKnn(data_column=docs.vec, dimensions=2, metric="cos"))
+    res = index.query_as_of_now(
+        queries.qvec, number_of_matches=1, collapse_rows=collapse_rows)
+    events = []
+    pw.io.subscribe(
+        res,
+        on_change=lambda key, row, time, is_addition: events.append(
+            (row["qid"], row["name"], is_addition)),
+    )
+    pw.run()
+    first = ("d2",) if collapse_rows else "d2"
+    second = ("d1",) if collapse_rows else "d1"
+    assert sorted(events, key=repr) == [("q1", first, True), ("q2", second, True)]
