@@ -178,7 +178,7 @@ def _ingest_window(enc, docs, batch_size, index, window_s, key_base0):
             raise RuntimeError(
                 "tokenize-ahead thread stalled"
             ) from (tok_err[0] if tok_err else None)
-        embs = enc.encode_tokens_device(ids, mask)
+        embs = enc.encode_tokens_device(ids, mask)[:n]
         # keys cycle within half the index capacity: later windows upsert
         # (slot reuse, same device work) instead of growing the index —
         # a growth reshape would recompile INSIDE a timed window and

@@ -709,12 +709,11 @@ def analyze_device_plan(
         "encoder.forward", enc_where, diags,
     )
     enc_buckets = {
-        dev.encoder_bucket(
-            dev.batch_bucket(n, 8, spec.batch_cap),
-            dev.seq_bucket(L, cfg.max_len),
-            cfg.vocab_size <= 65536,
-        )
+        dev.encoder_bucket(rows, width, cfg.vocab_size <= 65536)
         for n, L in spec.ingest_batches
+        for rows, width in dev.encoder_call_shapes(
+            n, L, spec.batch_cap, cfg.max_len
+        )
     }
     _retrace_audit(spec, "encoder.forward", enc_buckets, enc_where, diags,
                    predictions)

@@ -429,11 +429,15 @@ def batch_bucket(n: int, floor: int, cap: int) -> int:
 
 
 def seq_bucket(L: int, cap: int) -> int:
-    """Multiple-of-32 sequence bucket (floor 16), capped — the encoder's
-    sequence padding."""
-    if L <= 16:
-        return 16
-    return min(((L + 31) // 32) * 32, cap)
+    """The encoder's sequence padding: the narrowest width of the ladder
+    32, 64, 128, ... (doubling, capped) that holds ``L`` tokens. One
+    ladder for every dispatch: a call's only one, a group of a call that
+    was cut (``encoder_group_shapes``) and the fused ingest chain alike,
+    so a process meets one executable a rung and no more."""
+    width = min(32, cap)
+    while width < min(L, cap):
+        width = min(width * 2, cap)
+    return width
 
 
 def pow2_capacity(n: int, floor: int = 128) -> int:
@@ -503,6 +507,76 @@ def ingest_bucket(nb: int, Lb: int, capacity: int, ids_dtype: str) -> tuple:
 def encoder_bucket(nb: int, Lb: int, compact: bool) -> tuple:
     """Compiled-shape key of one ``encoder.forward`` dispatch."""
     return (nb, Lb, bool(compact))
+
+
+# A group of a length-sorted ``SentenceEncoder.encode`` call holds this
+# share of the full ``batch_size x max_len`` slab: 4,096 tokens at
+# 256 x 512. Picked on the chip (PERF.md section 6, PR 28).
+ENCODER_GROUP_SHARE = 32
+# ...and no more rows than this. The narrow members (64 x 32, 64 x 64 at
+# the published sizes) are then shapes a process that answers questions
+# warms anyway, so its documents bring three executables of their own
+# and not four (PERF.md section 6, PR 29).
+ENCODER_GROUP_ROWS = 64
+
+
+def encoder_group_shapes(batch_cap: int, max_len: int) -> tuple:
+    """Every ``(rows, width)`` a ``SentenceEncoder.encode`` call dispatches
+    when its rows do not fit one dispatch, narrowest first: the widths of
+    ``seq_bucket``'s ladder up to ``max_len``, each with the pow2 row
+    count that fills the group's token budget (8 at least; at most
+    ``ENCODER_GROUP_ROWS`` and ``batch_cap``). A closed set, fixed by the
+    two arguments alone: a group short of rows, a call's last, is padded
+    up to its member, so a call's lengths choose among the members and
+    never add one."""
+    budget = batch_cap * max_len // ENCODER_GROUP_SHARE
+    most = min(batch_cap, ENCODER_GROUP_ROWS)
+    shapes, width = [], seq_bucket(1, max_len)
+    while True:
+        rows = min(8, batch_cap)
+        while rows * 2 * width <= budget and rows * 2 <= most:
+            rows *= 2
+        shapes.append((rows, width))
+        if width >= max_len:
+            return tuple(shapes)
+        width = seq_bucket(width + 1, max_len)
+
+
+def encoder_call_groups(extents, batch_cap: int, max_len: int) -> list:
+    """The dispatches of one ``encode`` call as ``(first, stop, rows,
+    width)`` over its rows ordered longest first (``extents``: each row's
+    token count in that order). The longest row not yet placed opens a
+    group at the member of :func:`encoder_group_shapes` of its width and
+    takes that member's rows. A call that one member holds whole is one
+    dispatch at that width and the pow2 bucket of its own row count:
+    ``pad_batch``'s shape, what it was before calls were cut."""
+    rows_at = {width: rows for rows, width in encoder_group_shapes(batch_cap, max_len)}
+    n = len(extents)
+    groups, at = [], 0
+    while at < n:
+        width = seq_bucket(int(extents[at]), max_len)
+        rows = rows_at[width]
+        if at == 0 and n <= rows:
+            return [(0, n, batch_bucket(n, 8, batch_cap), width)]
+        groups.append((at, min(at + rows, n), rows, width))
+        at += rows
+    return groups
+
+
+def encoder_call_shapes(
+    n: int, longest: int, batch_cap: int, max_len: int
+) -> set:
+    """Every ``(rows, width)`` an ``encode`` call of ``n`` rows, the
+    longest of ``longest`` tokens, can dispatch: what the retrace audit
+    lists for a declared call, whose other rows' lengths it is not told."""
+    groups = encoder_call_groups([longest] * n, batch_cap, max_len)
+    shapes = {(rows, width) for _, _, rows, width in groups}
+    if len(groups) > 1:  # a shorter row may open any narrower member
+        shapes.update(
+            s for s in encoder_group_shapes(batch_cap, max_len)
+            if s[1] <= groups[0][3]
+        )
+    return shapes
 
 
 # -- static HBM budget (ISSUE 20) --------------------------------------------

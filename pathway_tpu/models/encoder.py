@@ -7,8 +7,16 @@ that matter on TPU:
 
 * whole logical-time batches are encoded in one jitted call (the ≥10k docs/s
   lever, SURVEY §7 stage 4) instead of one string per UDF call;
-* sequence lengths are bucketed to powers of two and batches padded to a
-  bounded shape set, so XLA compiles a handful of executables, once;
+* every dispatch is padded onto one small ladder of shapes: widths double
+  from 32 to ``max_len`` (``seq_bucket``: 32, 64, 128, 256, 512), rows are
+  a power of two from 8. A call that one rung holds is one dispatch; a
+  longer one is ordered by length and cut into the members
+  ``encoder_group_shapes`` enumerates (64 x 32, 64 x 64, 32 x 128,
+  16 x 256, 8 x 512 at the published sizes: 4,096 tokens a dispatch), so
+  a heavy-tailed call is not one slab as wide as its longest document,
+  XLA compiles one executable a shape, once, and none is keyed by a
+  call's real row count (padded rows come back and are dropped on the
+  host);
 * activations in bfloat16 (MXU native), accumulation and outputs f32;
 * mean-pool + L2-normalize pooling, bge-style.
 
@@ -35,6 +43,7 @@ from pathway_tpu.internals.device import (
     compiled_cost,
     device_site,
     encoder_bucket,
+    encoder_call_groups,
     nbytes_of,
     place_compile_cache,
     seq_bucket,
@@ -90,6 +99,15 @@ class _Block(nn.Module):
         return x
 
 
+# One traced body for the twelve layers: under ``nn.jit`` a block is
+# traced and lowered once a shape and called ``layers`` times (XLA
+# inlines the calls; parameters and results are what they were). A
+# process pays tracing for every shape it meets, from a warm compile
+# cache too: 0.7-1.1 s a shape unrolled, 0.15 s so (PERF.md section 6,
+# PR 29).
+_TracedOnceBlock = nn.jit(_Block)
+
+
 class TransformerEncoder(nn.Module):
     """Token ids + mask -> L2-normalized sentence embeddings [n, hidden]."""
 
@@ -112,7 +130,7 @@ class TransformerEncoder(nn.Module):
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_embed")(tok + pos + typ)
         attn_mask = nn.make_attention_mask(mask, mask, dtype=cfg.dtype)
         for i in range(cfg.layers):
-            x = _Block(cfg, name=f"block_{i}")(x, attn_mask)
+            x = _TracedOnceBlock(cfg, name=f"block_{i}")(x, attn_mask)
         # mean pool over valid tokens, then L2 normalize (bge pooling)
         m = mask[:, :, None].astype(jnp.float32)
         x = x.astype(jnp.float32)
@@ -223,13 +241,13 @@ device_site(
     dtypes=("uint16", "int32", "float32", "bfloat16"),
     where="pathway_tpu/models/encoder.py:SentenceEncoder.encode_tokens_device",
     description="jitted sentence-encoder forward "
-                "(pow2 batch x multiple-of-32 seq buckets)",
+                "(pow2 batch x doubling-width seq buckets)",
 )
 
 
 def pad_batch(ids: np.ndarray, mask: np.ndarray, max_len: int, batch_cap: int):
     """Pad (ids, mask) to the bounded (batch, seq) shape set jit relies
-    on: pow2 batch buckets x multiple-of-32 sequence buckets. Returns
+    on: pow2 batch buckets x ``seq_bucket``'s ladder of widths. Returns
     (ids_p, mask_p, n_valid_rows)."""
     n, L = ids.shape
     Lb = _seq_bucket(L, max_len)
@@ -319,15 +337,65 @@ class SentenceEncoder:
         return self.config.hidden
 
     def encode(self, texts: Sequence[str]) -> np.ndarray:
+        """Embeddings in arrival order. The call's rows are ordered by
+        length and cut into the dispatches ``encoder_call_groups`` names
+        (a short call is one, at ``pad_batch``'s shape); every group is
+        queued, with its copy back, before the one wait, so the host lays
+        out group k+1 while the device runs group k."""
         texts = list(texts)
         if not texts:
             return np.zeros((0, self.config.hidden), np.float32)
-        with _flight.span("encoder.encode", texts=len(texts)):
+        with _flight.span("encoder.encode", texts=len(texts)) as call:
             ids, mask = self._tokenize(texts)
+            held = mask != 0
+            # a row's extent: one past its last real token (its length,
+            # for the contiguous masks the tokenizers make)
+            extents = np.where(
+                held.any(axis=1),
+                mask.shape[1] - np.argmax(held[:, ::-1], axis=1), 0,
+            )
+            order = np.argsort(-extents, kind="stable")
+            groups = encoder_call_groups(
+                extents[order], self.batch_size, self.config.max_len
+            )
+            queued = []
+            for first, stop, rows, width in groups:
+                picked = order[first:stop]
+                longest = min(int(extents[picked[0]]), width)
+                if len(groups) == 1:
+                    g_ids, g_mask = ids[picked, :longest], mask[picked, :longest]
+                else:
+                    # at its member's shape already: encode_tokens_device
+                    # has no word for it, and pads what it is given to
+                    # the bucket of its own rows and width
+                    g_ids = np.zeros((rows, width), np.int32)
+                    g_mask = np.zeros((rows, width), np.int32)
+                    g_ids[:len(picked), :longest] = ids[picked, :longest]
+                    g_mask[:len(picked), :longest] = mask[picked, :longest]
+                emb = self.encode_tokens_device(g_ids, g_mask)
+                queue_copy = getattr(emb, "copy_to_host_async", None)
+                if queue_copy is not None:
+                    queue_copy()
+                queued.append((picked, emb))
+            call.args["groups"] = len(groups)
+            call.args["padded"] = sum(rows * width for _, _, rows, width in groups)
+            with _flight.span("encoder.wait"):
+                # np.asarray below blocks on forward and copy alike; the
+                # wait between only tells the device's time from the
+                # copy's. (A wait BEFORE the copies are queued costs a
+                # host round trip a dispatch: 0.4 ms, 13% on the serve
+                # tail; PERF.md section 6.)
+                jax.block_until_ready([emb for _, emb in queued])
             out = np.empty((len(texts), self.config.hidden), np.float32)
-            for start in range(0, len(texts), self.batch_size):
-                sl = slice(start, min(start + self.batch_size, len(texts)))
-                out[sl] = self._encode_batch(ids[sl], mask[sl])
+            with _flight.span("encoder.d2h") as sp:
+                moved = 0
+                for picked, emb in queued:
+                    # padded rows come back with the real ones: a slice
+                    # on the device is an executable per row count
+                    host = np.asarray(emb, np.float32)
+                    moved += int(host.nbytes)
+                    out[picked] = host[:len(picked)]
+                sp.args["bytes"] = moved
         return out
 
     def _tokenize(self, texts: list):
@@ -342,13 +410,15 @@ class SentenceEncoder:
         consumers (e.g. KnnShard.add) avoids the host round-trip and lets
         host tokenization of the next batch overlap device compute."""
         ids, mask = self._tokenize(list(texts))
-        return self.encode_tokens_device(ids, mask)
+        return self.encode_tokens_device(ids, mask)[:ids.shape[0]]
 
     def encode_tokens_device(self, ids: np.ndarray, mask: np.ndarray):
         """Device-encode a pre-tokenized batch (async-dispatched) — the
         shared padding+forward core. Lets a tokenize-ahead thread overlap
         host tokenization of batch N+1 with device compute / transfers of
-        batch N — the ingest-throughput lever."""
+        batch N — the ingest-throughput lever. Returns the bucket's
+        rows, the batch's first: a slice here would be one more
+        executable per (bucket, row count)."""
         with _flight.span(
             "encoder.pad", rows=int(ids.shape[0]), longest=int(ids.shape[1])
         ) as sp:
@@ -422,24 +492,7 @@ class SentenceEncoder:
             ),
             effective_share=real_tokens / float(nb_ * Lb),
         )
-        return emb[:n]
-
-    def _encode_batch(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
-        emb = self.encode_tokens_device(ids, mask)
-        with _flight.span("encoder.wait"):
-            # np.asarray below queues the copy behind the forward and
-            # blocks on both; queued here first, it still does, and the
-            # wait between only tells the device's time from the copy's.
-            # (A wait BEFORE the copy is queued costs a host round trip
-            # a batch: 0.4 ms, 13% on the serve tail; PERF.md section 6.)
-            queue_copy = getattr(emb, "copy_to_host_async", None)
-            if queue_copy is not None:
-                queue_copy()
-            jax.block_until_ready(emb)
-        with _flight.span("encoder.d2h") as sp:
-            out = np.asarray(emb, np.float32)
-            sp.args["bytes"] = int(out.nbytes)
-        return out
+        return emb
 
     def __call__(self, texts: Sequence[str]) -> np.ndarray:
         return self.encode(texts)

@@ -20,8 +20,8 @@ This module is the fix — ROADMAP item 2's dispatch-chain rebuild:
   shows the verdict flip from host-bound to compute/bandwidth-bound and
   the MFU gauge reports honest utilization.
 
-Padding discipline: the encoder's pow2-batch × multiple-of-32-seq
-buckets bound the shape set; padded rows carry slot index == capacity,
+Padding discipline: the encoder's pow2-batch × doubling-width-seq
+buckets (``seq_bucket``'s ladder) bound the shape set; padded rows carry slot index == capacity,
 which the scatter drops (``mode="drop"``) — no masking pass, no second
 dispatch. The chain stores the encoder's L2-normalized embeddings
 directly, which is exactly what the COS-metric shard would have

@@ -523,7 +523,7 @@ def test_device_plan_predicts_fused_ingest_recompiles_exactly():
     batches = [
         [" ".join([word] * 3)] * 4,          # small batch, short seqs
         [" ".join([word] * 3)] * 4,          # same shape: no new bucket
-        [" ".join([word] * 20)] * 4,         # longer seq bucket
+        [" ".join([word] * 40)] * 4,         # the ladder's next rung
         [" ".join([word] * 3)] * 12,         # bigger batch bucket
     ]
     # the declared workload: (rows, raw token length) per batch, read

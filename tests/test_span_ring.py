@@ -43,42 +43,77 @@ def _tiny_encoder(batch_size=8):
 # -- (a) the encoder's spans ---------------------------------------------------
 
 
-def test_encode_yields_six_children_in_order_with_counts():
+ONE_GROUP = ["alpha beta gamma delta", "epsilon zeta", "eta"]
+# 20 rows at batch_size 8: two full groups and one of four rows
+THREE_GROUPS = [" ".join(["word"] * (1 + 3 * i)) for i in range(20)]
+
+
+@pytest.mark.parametrize(
+    "texts, groups", [(ONE_GROUP, 1), (THREE_GROUPS, 3)],
+    ids=["one-group", "three-groups"],
+)
+def test_encode_yields_six_children_in_order_with_counts(texts, groups):
+    """One ``.pad`` / ``.h2d`` / ``.forward`` a dispatched group, every
+    group queued before the one ``.wait`` / ``.d2h`` pass that closes the
+    call; ``groups`` and ``padded`` on ``encoder.encode`` say how it cut."""
+    from pathway_tpu.internals.device import (
+        encoder_call_groups,
+        encoder_group_shapes,
+    )
     from pathway_tpu.models.encoder import pad_batch
 
     enc = _tiny_encoder()
-    texts = ["alpha beta gamma delta", "epsilon zeta", "eta"]
-    enc.encode(texts)  # the bucket's first sighting compiles: not measured
+    enc.encode(texts)  # the buckets' first sighting compiles: not measured
     lo = time.monotonic_ns()
     out = enc.encode(texts)
     spans = [
         s for s in flight.spans_between(lo, time.monotonic_ns())
         if s[S_THREAD] == threading.get_ident()
     ]
+    ids, mask = enc.tokenizer(texts)
+    lengths = np.sort(mask.sum(axis=1))[::-1]
+    plan = encoder_call_groups(lengths, enc.batch_size, enc.config.max_len)
+    assert len(plan) == groups
     (parent,) = _named(spans, "encoder.encode")
-    assert _args(parent) == {"texts": 3}
+    assert _args(parent) == {
+        "texts": len(texts), "groups": groups,
+        "padded": sum(rows * width for _, _, rows, width in plan),
+    }
     kids = sorted(
         (s for s in spans if s[S_PARENT] == parent[S_ID]), key=lambda s: s[S_T0]
     )
-    assert [s[S_NAME] for s in kids] == ENCODER_CHILDREN
+    assert [s[S_NAME] for s in kids] == (
+        ENCODER_CHILDREN[:1] + ENCODER_CHILDREN[1:4] * groups + ENCODER_CHILDREN[4:]
+    )
     at = parent[S_T0]
     for s in kids:  # inside the parent, one after the other
         assert at <= s[S_T0] <= s[S_T1] <= parent[S_T1]
         at = s[S_T1]
-    by = {s[S_NAME]: _args(s) for s in kids}
-    ids, mask = enc.tokenizer(texts)
-    assert by["encoder.tokenize"] == {"texts": 3, "tokens": int(mask.sum())}
-    ids_p, mask_p, n = pad_batch(ids, mask, enc.config.max_len, enc.batch_size)
-    assert by["encoder.pad"] == {
-        "rows": 3, "longest": ids.shape[1], "padded": ids_p.size,
-    }
-    lengths = mask_p.sum(axis=1, dtype=np.int32)
-    assert by["encoder.h2d"]["bytes"] == ids_p.astype(np.uint16).nbytes + lengths.nbytes
-    fwd = by["encoder.forward"]
-    assert fwd["bucket"] == f"{ids_p.shape[0]}x{ids_p.shape[1]}"
-    assert fwd["real_tokens"] == int(mask.sum())
-    assert fwd["padded_tokens"] == ids_p.size and fwd["first"] is False
-    assert by["encoder.d2h"]["bytes"] == out.nbytes
+    assert _args(kids[0]) == {"texts": len(texts), "tokens": int(mask.sum())}
+    real = padded_rows = 0
+    for g, (first, stop, rows, width) in enumerate(plan):
+        pad, h2d, fwd = (_args(s) for s in kids[1 + 3 * g:4 + 3 * g])
+        if groups == 1:  # as the tokenizer made it: rows and width its own
+            ids_p, mask_p, _ = pad_batch(ids, mask, enc.config.max_len, enc.batch_size)
+            assert (rows, width) == ids_p.shape
+            assert pad == {"rows": 3, "longest": ids.shape[1], "padded": ids_p.size}
+            group_lengths = mask_p.sum(axis=1, dtype=np.int32)
+            assert h2d["bytes"] == ids_p.astype(np.uint16).nbytes + group_lengths.nbytes
+        else:  # at its enumerated shape already
+            assert pad == {"rows": rows, "longest": width, "padded": rows * width}
+            assert h2d["bytes"] == rows * width * 2 + rows * 4
+            assert (rows, width) in encoder_group_shapes(
+                enc.batch_size, enc.config.max_len
+            )
+        assert fwd["bucket"] == f"{rows}x{width}"
+        assert fwd["real_tokens"] == int(lengths[first:stop].sum())
+        assert fwd["padded_tokens"] == rows * width and fwd["first"] is False
+        real += fwd["real_tokens"]
+        padded_rows += rows
+    assert real == int(mask.sum())
+    # padded rows come back too: no slice on the device
+    assert _args(kids[-1])["bytes"] == padded_rows * out.shape[1] * 4
+    assert out.shape == (len(texts), enc.config.hidden)
 
 
 # -- (c) the ring is bounded ---------------------------------------------------
