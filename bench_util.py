@@ -1,14 +1,9 @@
-"""Shared measurement policy for the bench harness (bench.py and
-scripts/bench_relational.py): median-of-runs selection with dispersion
-flagging, and the atomic artifact writer. One module so both measurement
-planes always report under the same policy."""
+"""Measurement policy of scripts/bench_relational.py: median-of-runs
+selection with dispersion flagging."""
 
 from __future__ import annotations
 
-import json
-import os
 import statistics
-import tempfile
 
 DISPERSION_FLAG = 0.2
 
@@ -30,27 +25,3 @@ def median_of(runs: list[dict], rates: list[float]) -> dict:
     out["dispersion"] = dispersion(rates)
     out["unsteady"] = dispersion(rates) > DISPERSION_FLAG
     return out
-
-
-def write_artifact_atomic(path: str, artifact: list[dict]) -> None:
-    """Rewrite the artifact via temp-file + rename so a crash mid-write
-    can never truncate previously recorded metrics. A failed write (full
-    disk, permissions) is logged loudly — a silently stale artifact would
-    defeat the self-defending-measurement goal — and the temp file is
-    cleaned up; the previous artifact version stays intact either way."""
-    d = os.path.dirname(os.path.abspath(path))
-    fd, tmp = tempfile.mkstemp(dir=d, prefix=".bench_full_", suffix=".tmp")
-    try:
-        with os.fdopen(fd, "w") as f:
-            json.dump(artifact, f, indent=1)
-        os.replace(tmp, path)
-    except OSError as exc:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "bench artifact write failed (%s stays stale): %s", path, exc
-        )
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass

@@ -5,7 +5,7 @@ Brand-new implementation of the capabilities of Pathway
 declarative Table DSL, unified batch+streaming semantics with retractions,
 IO connectors, temporal operators, vector indexes and an LLM/RAG xpack —
 with the dense hot path (embedders, KNN scoring, rerankers) running on TPU
-via JAX/XLA/Pallas and sharded over device meshes.
+via JAX/XLA and sharded over device meshes.
 
 Use as: ``import pathway_tpu as pw``.
 """

@@ -18,8 +18,8 @@ protocol by driving the SAME transition table
 ``pw.analyze`` calls report its distributed-safety verdicts.
 
 CLI: ``python -m pathway_tpu.analysis program.py [--json]
-[--processes N] [--require-fused]`` and ``--bench`` to annotate
-BENCH_full.json entries with plan verdicts.
+[--processes N] [--require-fused]`` and ``--bench`` for the plan
+verdicts of the canonical bench pipelines.
 
 Attribute access is lazy: engine/nodes.py imports
 ``analysis.eligibility`` at module load, so this package __init__ must

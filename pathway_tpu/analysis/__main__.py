@@ -2,7 +2,7 @@
 
     python -m pathway_tpu.analysis [--json] [--processes N]
         [--require-fused] program.py [prog args...]
-    python -m pathway_tpu.analysis --bench [--json] [--update-artifact]
+    python -m pathway_tpu.analysis --bench [--json]
     python -m pathway_tpu.analysis --mesh [--processes N]
         [--mesh-rounds D] [--mesh-faults F] [--mesh-mutant NAME]
         [--json] [program.py]
@@ -58,9 +58,8 @@ the plan verdict is "fused" — the CI gate for "this pipeline must stay
 on the NativeBatch fused chain".
 
 Bench mode analyzes the canonical bench pipeline shapes
-(analysis/bench.py) and, with ``--update-artifact``, annotates the
-matching BENCH_full.json metric lines in place with ``plan_verdict`` so
-future perf regressions triage as "plan degraded" vs "engine slower".
+(analysis/bench.py) and prints each one's plan verdict, so a perf
+regression triages as "plan degraded" vs "engine slower".
 """
 
 from __future__ import annotations
@@ -432,12 +431,7 @@ def _analyze_critical_path(args) -> int:
 
 
 def _analyze_bench(args) -> int:
-    from pathway_tpu.analysis.bench import (
-        BENCH_DEVICE_METRIC_CHAINS,
-        BENCH_METRIC_PLANS,
-        bench_verdicts,
-        device_chain_verdicts,
-    )
+    from pathway_tpu.analysis.bench import bench_verdicts
 
     verdicts = bench_verdicts()
     if args.json:
@@ -445,41 +439,6 @@ def _analyze_bench(args) -> int:
     else:
         for name, verdict in sorted(verdicts.items()):
             print(f"{name:<24} {verdict}")
-    if args.update_artifact:
-        repo = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-        path = os.path.join(repo, "BENCH_full.json")
-        try:
-            with open(path) as f:
-                artifact = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            print(f"no artifact at {path}", file=sys.stderr)
-            return 1
-        chain_verdicts = device_chain_verdicts()
-        n = nd = 0
-        for entry in artifact:
-            if not isinstance(entry, dict):
-                continue
-            plan = BENCH_METRIC_PLANS.get(entry.get("metric"))
-            if plan is not None:
-                name, world = plan
-                entry["plan_verdict"] = verdicts[f"{name}@{world}rank"]
-                n += 1
-            chain = BENCH_DEVICE_METRIC_CHAINS.get(entry.get("metric"))
-            if chain is not None and chain in chain_verdicts:
-                entry["device_plan_verdict"] = (
-                    f"device-{chain_verdicts[chain]}"
-                )
-                nd += 1
-        sys.path.insert(0, repo)
-        from bench_util import write_artifact_atomic
-
-        write_artifact_atomic(path, artifact)
-        print(
-            f"annotated {n} metric line(s) "
-            f"(+{nd} device lane(s)) in {path}"
-        )
     return 0
 
 
@@ -619,8 +578,8 @@ def main(argv=None) -> int:
     parser.add_argument(
         "--device-plan", action="store_true",
         help="Device Doctor: statically lower every registered device "
-             "dispatch chain (fused ingest, KNN scan/write, sharded "
-             "search/write, encoder forward, pallas kernel) with ZERO "
+             "dispatch chain (KNN scan/write, sharded search/write, "
+             "encoder forward) with ZERO "
              "execution and audit donation aliasing, host syncs, "
              "retrace buckets, the per-chip HBM budget, and the "
              "mesh/merge layout; combine with --profile TRACE_JSON to "
@@ -637,11 +596,6 @@ def main(argv=None) -> int:
         help="with --device-plan: analyze a deliberately broken chain "
              "(undonated_write | host_sync | unbounded_buckets | "
              "over_budget) — the doctor must catch it",
-    )
-    parser.add_argument(
-        "--update-artifact", action="store_true",
-        help="with --bench: annotate BENCH_full.json lines with "
-             "plan_verdict",
     )
     parser.add_argument(
         "--profile", default=None, metavar="TRACE_JSON",

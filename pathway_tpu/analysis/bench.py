@@ -1,5 +1,5 @@
 """Canonical bench pipeline builders shared by the plan-doctor CLI
-(``--bench`` verdict annotation), the analyzer-vs-runtime agreement tests
+(``--bench`` verdicts), the analyzer-vs-runtime agreement tests
 and ad-hoc triage. Each builder clears the global ParseGraph, constructs
 the same graph SHAPE as scripts/bench_relational.py (same schemas, same
 operators — sizes are parameters) and returns the pipeline handle; the
@@ -206,54 +206,15 @@ BENCH_PIPELINES: dict[str, Callable[[], BenchPipeline]] = {
     "serving": build_serving,
 }
 
-# BENCH_full.json metric name -> (pipeline, analysis world size)
-BENCH_METRIC_PLANS: dict[str, tuple[str, int]] = {
-    "wordcount_rows_per_s": ("wordcount", 1),
-    "wordcount_2rank_rows_per_s": ("wordcount", 2),
-    "stream_join_rows_per_s": ("stream_join", 1),
-    "transform_rows_per_s": ("transform", 1),
-}
-
-# BENCH_full.json DEVICE metric name -> Device Doctor chain whose static
-# verdict annotates the line (ISSUE 20): the ingest lanes dispatch
-# through ingest.fused, the query/recall lanes through the KNN scan,
-# and the trace-overhead lane through the bare encoder forward
-BENCH_DEVICE_METRIC_CHAINS: dict[str, str] = {
-    "preflight_ingest": "ingest",
-    "embed_ingest_docs_per_s_per_chip": "ingest",
-    "embed_ingest_fused_docs_per_s_per_chip": "ingest",
-    "rag_query_p50_ms": "knn",
-    "rag_under_load_p50_ms": "knn",
-    "rag_qps_vs_clients": "knn",
-    "rag_update_while_serving_p50_ms": "knn",
-    "ann_recall_at_10": "knn",
-    "device_trace_overhead": "encoder",
-}
-
-
-def device_chain_verdicts() -> dict[str, str]:
-    """One Device Doctor run; per-chain verdict keyed by chain name."""
-    from pathway_tpu.analysis.device_plan import analyze_device_plan
-
-    return dict(analyze_device_plan().chains)
-
-
 def bench_verdicts() -> dict[str, str]:
-    """Plan verdict for every (pipeline, world) the bench artifact
-    records, keyed "name@Nrank"."""
+    """Plan verdict of every pipeline in ``BENCH_PIPELINES`` at one rank,
+    and of wordcount at two (the 2-rank lane), keyed "name@Nrank"."""
     from pathway_tpu.analysis.analyzer import analyze
 
-    out: dict[str, str] = {}
-    seen: dict[tuple[str, int], str] = {}
-    for metric, (name, world) in BENCH_METRIC_PLANS.items():
-        key = (name, world)
-        if key not in seen:
-            bp = BENCH_PIPELINES[name]()
-            seen[key] = analyze(bp.out, processes=world).verdict
-        out[f"{name}@{world}rank"] = seen[key]
-    # pipelines not in the artifact mapping still get a verdict line
-    for name, build in BENCH_PIPELINES.items():
-        if not any(n == name for n, _ in BENCH_METRIC_PLANS.values()):
-            bp = build()
-            out[f"{name}@1rank"] = analyze(bp.out, processes=1).verdict
-    return out
+    plans = [(name, 1) for name in BENCH_PIPELINES] + [("wordcount", 2)]
+    return {
+        f"{name}@{world}rank": analyze(
+            BENCH_PIPELINES[name]().out, processes=world
+        ).verdict
+        for name, world in plans
+    }

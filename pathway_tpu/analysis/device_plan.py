@@ -2,14 +2,14 @@
 
 Plan Doctor pass 6: for every registered device site reachable from the
 lowered plan (``internals/device.py`` site registry — encoder forward,
-fused ingest, KNN scan/write, pallas kernel, sharded search/write), the
-chain is lowered with ``jax.eval_shape`` / jaxpr inspection under the
-declared knob/mesh config — **zero execution, no accelerator needed** —
-and five checks emit provenance-carrying diagnostics:
+KNN scan/write, sharded search/write), the chain is lowered with
+``jax.eval_shape`` / jaxpr inspection under the declared knob/mesh
+config — **zero execution, no accelerator needed** — and five checks
+emit provenance-carrying diagnostics:
 
 1. **donation audit** — inputs declared donated must appear in the
    lowered input-output aliasing (``tf.aliasing_output`` on the MLIR
-   main signature); a donatable index/ingest buffer that is NOT donated
+   main signature); a donatable index buffer that is NOT donated
    is blamed with the per-dispatch HBM copy cost it silently pays.
 2. **host-sync audit** — device→host transfers inside the steady chain:
    blocking callbacks in the jaxpr (``pure_callback``/``io_callback``),
@@ -22,18 +22,18 @@ and five checks emit provenance-carrying diagnostics:
    flag unbounded or excessive sets, and predict
    ``device_site_recompiles_total`` per site.
 4. **static HBM budget** — per-chip footprint (index shards +
-   free-lists + double-buffered ingest staging + encoder params +
-   snapshot staging) from shapes/dtypes and the mesh layout, vs
-   ``device_hbm_bytes()`` (``PATHWAY_DEVICE_HBM_BYTES`` override for
-   CPU/CI) — a layout that cannot hold the declared corpus is refused
-   before PR 17's runtime OOM path ever fires.
+   free-lists + encoder params + snapshot staging) from shapes/dtypes
+   and the mesh layout, vs ``device_hbm_bytes()``
+   (``PATHWAY_DEVICE_HBM_BYTES`` override for CPU/CI) — a layout that
+   cannot hold the declared corpus is refused before PR 17's runtime OOM
+   path ever fires.
 5. **mesh-layout check** — shard count vs world vs the pow2 tree-merge
    requirement, and ``out_shardings`` pinned on donated sharded writes.
 
 Like eligibility.py, the predicates the checks gate on are the same
-objects the runtime sites consume: ``make_fused``/``FUSED_DONATE_ARGNUMS``
-(ops/ingest.py), ``_write_slots``/``_search_fn`` (ops/knn.py),
-``make_sharded_write``/``_sharded_search_fn`` (parallel/sharded_knn.py)
+objects the runtime sites consume: ``_write_slots``/``_search_fn``
+(ops/knn.py), ``make_sharded_write``/``_sharded_search_fn``
+(parallel/sharded_knn.py), the encoder's module (models/encoder.py)
 and the shared bucket/cost models in ``internals/device.py``.
 ``join_profile`` joins measured recompiles/MFU from a ``--profile``
 trace onto the static predictions with a predicted-vs-measured drift
@@ -72,9 +72,11 @@ def _max_buckets() -> int:
 @dataclasses.dataclass(frozen=True)
 class WorkloadSpec:
     """The declared steady-state workload the retrace/HBM checks analyze
-    under. ``ingest_batches`` are (rows, token_len) per fused-ingest
-    dispatch; ``write_batches`` are direct index-write row counts;
-    queries arrive in ``query_batches`` sizes asking ``ks`` neighbors.
+    under. ``ingest_batches`` are (rows, longest row's tokens) per
+    ``SentenceEncoder.encode`` call, whose rows are then written to the
+    index in one ``add``; ``write_batches`` are the row counts of index
+    writes that follow them with vectors made elsewhere; queries arrive
+    in ``query_batches`` sizes asking ``ks`` neighbors.
     ``bounded=False`` declares the batch/shape distribution unbounded —
     exactly the retrace-storm defect the audit refuses."""
 
@@ -86,8 +88,13 @@ class WorkloadSpec:
     batch_cap: int = 256          # encoder batch_size (pow2 bucket cap)
     initial_capacity: int = 128
     chunk: int | None = None
-    depth: int = 2                # tokenize-ahead staging depth
     bounded: bool = True
+
+    def write_rows(self) -> tuple:
+        """Row count of every index write, in order."""
+        return tuple(n for n, _ in self.ingest_batches) + tuple(
+            self.write_batches
+        )
 
 
 # -- report ------------------------------------------------------------------
@@ -315,7 +322,7 @@ def _donation_check(
             severity="error",
             node=site,
             message=(
-                "index/ingest buffers are donatable but the lowered "
+                "index buffers are donatable but the lowered "
                 "executable does not alias them in-place"
                 + (f" (flat inputs {missing} lack tf.aliasing_output)"
                    if donate_argnums else
@@ -325,9 +332,10 @@ def _donation_check(
             ),
             hint=(
                 "jit the chain with donate_argnums covering the buffer "
-                "triple (see ops/ingest.py FUSED_DONATE_ARGNUMS / "
-                "ops/knn.py _write_slots) and keep shapes/dtypes of "
-                "donor and output identical so XLA can alias"
+                "triple (see ops/knn.py _write_slots / "
+                "parallel/sharded_knn.py make_sharded_write) and keep "
+                "shapes/dtypes of donor and output identical so XLA "
+                "can alias"
             ),
             where=where,
         ))
@@ -336,30 +344,6 @@ def _donation_check(
 
 
 # -- retrace audit (shared bucket enumeration) -------------------------------
-
-
-def simulate_ingest_buckets(
-    spec: WorkloadSpec, cfg, *, wire_dtype: str | None = None
-) -> set:
-    """The ``ingest.fused`` compiled-shape set the declared workload
-    implies — computed through the SAME bucket functions the pipeline
-    pads with (batch_bucket/seq_bucket/pow2_capacity/ingest_bucket)."""
-    from pathway_tpu.internals.device import (
-        batch_bucket, ingest_bucket, pow2_capacity, seq_bucket,
-    )
-
-    if wire_dtype is None:
-        wire_dtype = "uint16" if cfg.vocab_size <= 65536 else "int32"
-    cap = pow2_capacity(spec.initial_capacity)
-    rows = 0
-    out: set = set()
-    for n, L in spec.ingest_batches:
-        nb = batch_bucket(n, 8, spec.batch_cap)
-        Lb = seq_bucket(L, cfg.max_len)
-        rows += n
-        cap = max(cap, pow2_capacity(rows))
-        out.add(ingest_bucket(nb, Lb, cap, wire_dtype))
-    return out
 
 
 def simulate_knn_buckets(spec: WorkloadSpec) -> tuple[set, set]:
@@ -373,7 +357,7 @@ def simulate_knn_buckets(spec: WorkloadSpec) -> tuple[set, set]:
     cap = pow2_capacity(spec.initial_capacity)
     rows = 0
     wb: set = set()
-    for b in spec.write_batches:
+    for b in spec.write_rows():
         rows += b
         cap = max(cap, pow2_capacity(rows))
         wb.add(knn_write_bucket(b, cap))
@@ -397,7 +381,7 @@ def simulate_sharded_buckets(
     local = pow2_capacity(max(1, spec.initial_capacity // max(world, 1)))
     rows = 0
     wb: set = set()
-    for b in spec.write_batches:
+    for b in spec.write_rows():
         rows += b
         # evenly-routed model: every shard holds ~rows/world
         local = max(local, pow2_capacity(-(-rows // max(world, 1))))
@@ -466,11 +450,15 @@ def analyze_device_plan(
 ) -> DevicePlanReport:
     """Run the five static checks over every registered device chain at
     the declared ``world``/workload. ``mutant`` seeds one of the four
-    defect classes (tests + the CI lane's exit-2 contract); None
-    analyzes the shipped chains. ``answer``: the ``DecoderConfig`` of an
-    answer model resident beside the index; its held parameters and its
-    state cache count into the HBM budget. Zero execution: chains are
-    lowered with ShapeDtypeStructs — nothing is dispatched."""
+    defect classes into the chains the product dispatches (tests + the CI
+    lane's exit-2 contract): ``undonated_write`` into the index write of
+    the declared world (``knn.write`` on one chip, ``knn.sharded_write``
+    across several), ``host_sync`` into ``encoder.forward`` and
+    ``knn.search``; None analyzes the shipped chains. ``answer``: the
+    ``DecoderConfig`` of an answer model resident beside the index; its
+    held parameters and its state cache count into the HBM budget. Zero
+    execution: chains are lowered with ShapeDtypeStructs — nothing is
+    dispatched."""
     import jax
     import jax.numpy as jnp
 
@@ -480,7 +468,6 @@ def analyze_device_plan(
         TransformerEncoder,
         encoder_param_bytes,
     )
-    from pathway_tpu.ops.ingest import FUSED_DONATE_ARGNUMS, make_fused
     from pathway_tpu.ops.knn import _search_fn, _write_slots
 
     if mutant is not None and mutant not in MUTANTS:
@@ -506,6 +493,19 @@ def analyze_device_plan(
             return "degraded"
         return "clean"
 
+    def seeded(fn):
+        """``fn`` with the ``host_sync`` defect: a mid-chain scalar read
+        that forces a device->host sync on every dispatch."""
+        if mutant != "host_sync":
+            return fn
+
+        def synced(*args):
+            out = fn(*args)
+            jax.tree_util.tree_leaves(out)[0].sum().item()
+            return out
+
+        return synced
+
     model = TransformerEncoder(cfg)
     d_model = cfg.hidden
     nb = dev.batch_bucket(
@@ -522,55 +522,25 @@ def analyze_device_plan(
         model.init, rng,
         S((1, 8), jnp.int32), S((1, 8), jnp.int32),
     )["params"]
-    wire_dtype = jnp.uint16 if cfg.vocab_size <= 65536 else jnp.int32
+    undonated = None
+    if mutant == "undonated_write":
+        undonated = "knn.write" if world == 1 else "knn.sharded_write"
 
-    # -- chain: ingest.fused ------------------------------------------------
-    mark = len(diags)
-    fused = make_fused(model)
-    if mutant == "host_sync":
-        inner = fused
-
-        def fused(params, ids, lengths, slots, vectors, valid, sq_norms):
-            emb, vectors, valid, sq_norms = inner(
-                params, ids, lengths, slots, vectors, valid, sq_norms
-            )
-            # the seeded defect: a mid-chain scalar read forces a
-            # device->host sync on every dispatch
-            emb = emb * emb.sum().item()
-            return emb, vectors, valid, sq_norms
-
-    donate = () if mutant == "undonated_write" else FUSED_DONATE_ARGNUMS
-    fused_jit = jax.jit(fused, donate_argnums=donate)
-    fused_avals = (
-        params_avals,
-        S((nb, Lb), wire_dtype),
-        S((nb,), jnp.int32),
-        S((nb,), jnp.int32),
-        S((cap0, d_model), jnp.float32),
-        S((cap0,), jnp.bool_),
-        S((cap0,), jnp.float32),
-    )
-    ingest_where = "pathway_tpu/ops/ingest.py:IngestPipeline._dispatch"
-    traced = _host_sync_check(
-        fused, fused_avals, "ingest.fused", ingest_where, diags
-    )
-    if traced:
-        _donation_check(
-            fused_jit, fused_avals, donate,
-            dev.index_shard_bytes(cap0, d_model),
-            "ingest.fused", ingest_where, diags,
-        )
-    _retrace_audit(
-        spec, "ingest.fused",
-        simulate_ingest_buckets(spec, cfg), ingest_where, diags, predictions,
-    )
-    chains["ingest"] = chain_verdict(mark)
+    def write_chain(site, fn, **jit_kwargs):
+        """``fn`` (the site's jitted write) and its donated argnums; the
+        seeded defect: the same write jitted without its donation."""
+        if site != undonated:
+            return fn, (0, 1, 2)
+        return jax.jit(
+            _write_slots.__wrapped__, static_argnames=("normalize",),
+            **jit_kwargs,
+        ), ()
 
     # -- chain: knn.write / knn.search --------------------------------------
     mark = len(diags)
     knn_where = "pathway_tpu/ops/knn.py:KnnShard"
     wb, sb = simulate_knn_buckets(spec)
-    write_rows = max(spec.write_batches, default=64)
+    write_rows = max(spec.write_rows(), default=64)
     write_avals = (
         S((cap0, d_model), jnp.float32),
         S((cap0,), jnp.bool_),
@@ -579,12 +549,13 @@ def analyze_device_plan(
         S((write_rows, d_model), jnp.float32),
         S((write_rows,), jnp.bool_),
     )
+    write_fn, donate = write_chain("knn.write", _write_slots)
     if _host_sync_check(
         _write_slots.__wrapped__, write_avals, "knn.write",
         knn_where + ".add", diags,
     ):
         _donation_check(
-            _write_slots, write_avals, (0, 1, 2),
+            write_fn, write_avals, donate,
             dev.index_shard_bytes(cap0, d_model),
             "knn.write", knn_where + ".add", diags,
         )
@@ -598,7 +569,8 @@ def analyze_device_plan(
             S((scap,), jnp.float32),
         )
         _host_sync_check(
-            sfn, search_avals, "knn.search", knn_where + ".search", diags
+            seeded(sfn), search_avals, "knn.search", knn_where + ".search",
+            diags,
         )
     _retrace_audit(spec, "knn.write", wb, knn_where + ".add", diags,
                    predictions)
@@ -623,12 +595,15 @@ def analyze_device_plan(
         # device); the declared-world checks below are pure-model
         mesh1 = Mesh(np.array(jax.devices()[:1]), ("dp",))
         wfn, out_shardings = make_sharded_write(mesh1, "dp")
+        wfn, donate = write_chain(
+            "knn.sharded_write", wfn, out_shardings=out_shardings
+        )
         if _host_sync_check(
             _write_slots.__wrapped__, write_avals, "knn.sharded_write",
             sh_where + ".add", diags,
         ):
             _donation_check(
-                wfn, write_avals, (0, 1, 2),
+                wfn, write_avals, donate,
                 dev.index_shard_bytes(cap0, d_model),
                 "knn.sharded_write", sh_where + ".add", diags,
             )
@@ -704,7 +679,7 @@ def analyze_device_plan(
         return model.apply({"params": params}, ids, mask)
 
     _host_sync_check(
-        forward,
+        seeded(forward),
         (params_avals, S((nb, Lb), jnp.int32), S((nb, Lb), jnp.int32)),
         "encoder.forward", enc_where, diags,
     )
@@ -719,20 +694,6 @@ def analyze_device_plan(
                    predictions)
     chains["encoder"] = chain_verdict(mark)
 
-    # -- chain: pallas.topk (retrace model only — the TPU kernel does not
-    # lower off-device; its cost model rides the registry) ------------------
-    mark = len(diags)
-    pallas_buckets = {
-        dev.pallas_bucket(q, cap0, d_model, k, min(1024, cap0))
-        for q in spec.query_batches for k in spec.ks
-    }
-    _retrace_audit(
-        spec, "pallas.topk", pallas_buckets,
-        "pathway_tpu/ops/pallas_knn.py:pallas_topk_scores", diags,
-        predictions,
-    )
-    chains["pallas"] = chain_verdict(mark)
-
     # -- static HBM budget ---------------------------------------------------
     per_chip_rows = -(-spec.corpus_rows // world)
     per_chip_cap = dev.pow2_capacity(per_chip_rows)
@@ -743,9 +704,6 @@ def analyze_device_plan(
         per_chip_cap, d_model, donated=donation_ok
     )
     freelist_b = 8.0 * per_chip_cap  # host slot free-list + freed-epoch
-    staging_b = dev.ingest_staging_bytes(
-        nb, Lb, 2 if cfg.vocab_size <= 65536 else 4, depth=spec.depth
-    )
     params_b = encoder_param_bytes(cfg)
     snap_b = dev.snapshot_staging_bytes(per_chip_cap, d_model)
     answer_params_b = answer_cache_b = 0.0
@@ -755,7 +713,7 @@ def analyze_device_plan(
         answer_params_b = decoder.param_bytes(answer)
         answer_cache_b = decoder.cache_bytes(answer)
     footprint = (
-        index_b + freelist_b + staging_b + params_b + snap_b
+        index_b + freelist_b + params_b + snap_b
         + answer_params_b + answer_cache_b
     )
     budget = float(dev.device_hbm_bytes())
@@ -764,7 +722,6 @@ def analyze_device_plan(
         "per_chip_capacity": per_chip_cap,
         "index_bytes": index_b,
         "freelist_bytes": freelist_b,
-        "ingest_staging_bytes": staging_b,
         "encoder_param_bytes": params_b,
         "snapshot_staging_bytes": snap_b,
         "answer_param_bytes": answer_params_b,
@@ -782,7 +739,7 @@ def analyze_device_plan(
             message=(
                 f"declared corpus of {spec.corpus_rows} rows needs "
                 f"{footprint:.3e} bytes/chip (index {index_b:.3e} + "
-                f"staging {staging_b:.3e} + params {params_b:.3e} + "
+                f"params {params_b:.3e} + "
                 f"snapshot {snap_b:.3e}) but the device budget is "
                 f"{budget:.3e} bytes — this layout OOMs before serving"
             ),
@@ -792,7 +749,7 @@ def analyze_device_plan(
                 "PATHWAY_DEVICE_HBM_BYTES if the budget model is wrong "
                 "for this hardware"
             ),
-            where="pathway_tpu/parallel/sharded_knn.py:ShardedKnnIndex",
+            where=knn_where if world == 1 else sh_where,
         ))
         chains["sharded" if world > 1 else "knn"] = "dirty"
 

@@ -157,18 +157,7 @@ KNOBS: dict[str, Knob] = {
            "compiled shape buckets than this at one dispatch site gets "
            "a retrace-storm warning (compile time and executable memory "
            "scale with every bucket).", lo=1, hi=1_000_000),
-        # -- fused ingest + pod-sharded index (ISSUE 16) -------------------
-        _k("PATHWAY_INGEST_DEPTH", "int", 2,
-           "Tokenize-ahead depth of the fused ingest chain "
-           "(ops/ingest.py): how many tokenized+padded batches the host "
-           "producer may stage ahead of the device. 1 degrades to "
-           "strict alternation; 2 is classic double buffering.",
-           lo=1, hi=64),
-        _k("PATHWAY_INGEST_STAGE_H2D", "bool", True,
-           "Start the next ingest batch's host-to-device token copies "
-           "from the producer thread (double-buffered H2D) so the copy "
-           "overlaps the previous batch's fused dispatch; 0 hands the "
-           "device numpy arrays and pays the transfer on dispatch."),
+        # -- pod-sharded index (ISSUE 16) ----------------------------------
         _k("PATHWAY_INDEX_SHARDS", "int", None,
            "Back vector-index adapters with the pod-sharded HBM index "
            "over an N-device data-parallel mesh (one corpus shard per "
@@ -184,7 +173,7 @@ KNOBS: dict[str, Knob] = {
         # -- device fault domain (ISSUE 17) --------------------------------
         _k("PATHWAY_DEVICE_DISPATCH_TIMEOUT_S", "float", 0.0,
            "Watchdog deadline (seconds) on supervised device dispatch "
-           "sites (KNN write/search, fused ingest): a dispatch that "
+           "sites (KNN write/search): a dispatch that "
            "exceeds it is abandoned and raises WatchdogTimeout (a "
            "permanent fault, routed to epoch abort). 0 disables the "
            "watchdog. Set well under PATHWAY_MESH_OP_TIMEOUT_S so a "
@@ -193,7 +182,7 @@ KNOBS: dict[str, Knob] = {
            lo=0.0, hi=86400.0),
         _k("PATHWAY_DEVICE_RETRIES", "int", 2,
            "Bounded retry budget for transient device dispatch "
-           "failures (supervised_dispatch / the fused-ingest producer): "
+           "failures (supervised_dispatch): "
            "transient errors retry with exponential backoff up to this "
            "many times; OOM flips the serving breaker into brownout; "
            "permanent faults abort the epoch immediately.",

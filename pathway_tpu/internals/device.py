@@ -6,9 +6,9 @@ an ``ExternalIndexNode`` KNN scan or an embedder forward is one opaque
 slab of node self-time, with no way to tell whether a slow node needs a
 kernel (device-bound) or needs the host path fixed (device idle while
 the host assembles batches). This module is the missing plane: engine
-dispatch sites (ops/knn.py, ops/pallas_knn.py, models/encoder.py, the
-serving gateway's fused window dispatch) wrap every device launch in a
-**timed dispatch record** —
+dispatch sites (ops/knn.py, parallel/sharded_knn.py, models/encoder.py,
+models/decoder.py, the serving gateway's fused window dispatch) wrap
+every device launch in a **timed dispatch record** —
 
 * wall span of the whole dispatch (host assembly + enqueue + wait);
 * ``jax.block_until_ready``-bounded device time (enqueue-return to
@@ -431,9 +431,9 @@ def batch_bucket(n: int, floor: int, cap: int) -> int:
 def seq_bucket(L: int, cap: int) -> int:
     """The encoder's sequence padding: the narrowest width of the ladder
     32, 64, 128, ... (doubling, capped) that holds ``L`` tokens. One
-    ladder for every dispatch: a call's only one, a group of a call that
-    was cut (``encoder_group_shapes``) and the fused ingest chain alike,
-    so a process meets one executable a rung and no more."""
+    ladder for every dispatch: a call's only one and a group of a call
+    that was cut (``encoder_group_shapes``) alike, so a process meets
+    one executable a rung and no more."""
     width = min(32, cap)
     while width < min(L, cap):
         width = min(width * 2, cap)
@@ -475,14 +475,6 @@ def knn_write_bucket(nrows: int, capacity: int) -> tuple:
     return (nrows, capacity)
 
 
-def pallas_bucket(
-    q: int, cap: int, d: int, k: int, block: int, interpret: bool = False
-) -> tuple:
-    """Compiled-shape key of one ``pallas.topk`` kernel launch (every
-    field is a static arg or an input dim of the pallas_call)."""
-    return (q, cap, d, k, block, bool(interpret))
-
-
 def sharded_search_bucket(
     n: int, n_shards: int, local_cap: int, k: int, chunk: int | None
 ) -> tuple:
@@ -496,12 +488,6 @@ def sharded_search_bucket(
 def sharded_write_bucket(nrows: int, capacity: int) -> tuple:
     """Compiled-shape key of one ``knn.sharded_write`` dispatch."""
     return (nrows, capacity)
-
-
-def ingest_bucket(nb: int, Lb: int, capacity: int, ids_dtype: str) -> tuple:
-    """Compiled-shape key of one ``ingest.fused`` chain dispatch (batch
-    bucket x seq bucket x index capacity x wire dtype)."""
-    return (nb, Lb, capacity, ids_dtype)
 
 
 def encoder_bucket(nb: int, Lb: int, compact: bool) -> tuple:
@@ -630,16 +616,6 @@ def index_shard_bytes(capacity: int, dim: int, *, donated: bool = True) -> float
     — the doctor's donation audit bills exactly this doubling."""
     steady = 4.0 * capacity * dim + 1.0 * capacity + 4.0 * capacity
     return steady if donated else 2.0 * steady
-
-
-def ingest_staging_bytes(
-    nb: int, Lb: int, ids_itemsize: int = 2, *, depth: int = 2
-) -> float:
-    """H2D staging footprint of the tokenize-ahead ingest loop: ``depth``
-    in-flight batches of (ids [nb, Lb] at the wire itemsize + i32
-    lengths [nb])."""
-    per = float(nb) * float(Lb) * float(ids_itemsize) + 4.0 * nb
-    return float(depth) * per
 
 
 def snapshot_staging_bytes(capacity: int, dim: int) -> float:

@@ -51,16 +51,12 @@ Injection points threaded through the hot paths:
     device.dispatch                 per supervised device dispatch
                                     (internals/device.py
                                     supervised_dispatch — the KNN
-                                    search/write sites and the fused
-                                    ingest chain), with ``site=``
+                                    search/write sites), with ``site=``
                                     context; a retryable raise here
                                     exercises the bounded-backoff retry
                                     classifier, a delay longer than
                                     PATHWAY_DEVICE_DISPATCH_TIMEOUT_S
                                     trips the watchdog
-    device.h2d                      per host->device staging copy
-                                    (ops/ingest.py tokenize-ahead
-                                    producer)
     device.oom                      HBM growth attempts
                                     (KnnShard._grow_to /
                                     ShardedKnnIndex._grow_to_local): a
@@ -163,7 +159,6 @@ POINTS = (
     "sink.finalize",
     "sink.recover",
     "device.dispatch",
-    "device.h2d",
     "device.oom",
     "device.snapshot",
     "device.restore",

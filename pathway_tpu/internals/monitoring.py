@@ -311,7 +311,7 @@ class ProberStats:
     # span; wall - device = host assembly. flops_effective (ISSUE 16) is
     # the real-row share of flops — padding waste is the gap between the
     # two. Bounded cardinality: a handful of static site names
-    # (knn.search, encoder.forward, ingest.fused, serve.window, ...).
+    # (knn.search, knn.write, encoder.forward, serve.window, ...).
     device_sites: dict = field(default_factory=dict)
     # fresh XLA compilations observed at dispatch sites (ISSUE 16): a
     # new shape bucket entering a site's compiled-fn cache. A recompile

@@ -404,14 +404,6 @@ class SentenceEncoder:
             sp.args["tokens"] = int(mask.sum())
         return ids, mask
 
-    def encode_device(self, texts: Sequence[str]):
-        """Encode one batch and return the (device-resident, async-dispatched)
-        jax array of shape [n, hidden]. Chaining this into device-side
-        consumers (e.g. KnnShard.add) avoids the host round-trip and lets
-        host tokenization of the next batch overlap device compute."""
-        ids, mask = self._tokenize(list(texts))
-        return self.encode_tokens_device(ids, mask)[:ids.shape[0]]
-
     def encode_tokens_device(self, ids: np.ndarray, mask: np.ndarray):
         """Device-encode a pre-tokenized batch (async-dispatched) — the
         shared padding+forward core. Lets a tokenize-ahead thread overlap

@@ -3,31 +3,18 @@
 The reference implements its retrieval hot loop in native Rust
 (/root/reference/src/external_integration/brute_force_knn_integration.rs:22-237
 — ndarray matmul + k_smallest on CPU). Here the same role is played by
-XLA/Pallas kernels: padded HBM-resident vector shards, fused
-matmul + top-k scoring on the MXU, and mergeable partial top-k results for
-mesh-sharded indexes (SURVEY §5 long-context mapping).
+XLA: padded HBM-resident vector shards, fused matmul + top-k scoring on
+the MXU, and mergeable partial top-k results for mesh-sharded indexes
+(SURVEY §5 long-context mapping).
 """
 
 from pathway_tpu.ops.topk import masked_topk, merge_topk, tree_merge_topk
 from pathway_tpu.ops.knn import KnnShard, Metric
-from pathway_tpu.ops.query_engine import MicroBatcher, QueryEngine
 
 __all__ = [
     "KnnShard",
     "Metric",
-    "MicroBatcher",
-    "QueryEngine",
     "masked_topk",
     "merge_topk",
     "tree_merge_topk",
 ]
-
-
-def __getattr__(name):
-    # IngestPipeline pulls in the encoder stack (flax) — lazy so the
-    # relational plane keeps importing pathway_tpu.ops for free
-    if name == "IngestPipeline":
-        from pathway_tpu.ops.ingest import IngestPipeline
-
-        return IngestPipeline
-    raise AttributeError(name)

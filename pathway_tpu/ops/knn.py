@@ -240,10 +240,7 @@ class KnnShard:
 
     def _assign_slots(self, keys: Sequence[Any]) -> np.ndarray:
         """Map keys to dense slots (upsert semantics), growing first.
-        Must be called under ``self.lock`` — shared by ``add`` and the
-        fused ingest chain (ops/ingest.py), which maps keys to slots
-        host-side while the encoder forward + slot-write run as one
-        jitted dispatch."""
+        Must be called under ``self.lock``."""
         self._grow_to(len(self.key_to_slot) + len(keys))
         slots = []
         for key in keys:
@@ -255,9 +252,7 @@ class KnnShard:
                 self.key_seq[key] = self._next_seq
                 self._next_seq += 1
             slots.append(slot)
-            # every upserted key is dirty for the next snapshot cut;
-            # this also captures the fused ingest chain, which assigns
-            # slots here before the encoder+write dispatch
+            # every upserted key is dirty for the next snapshot cut
             self._dirty[key] = None
             self._dirty_removed.pop(key, None)
         return np.asarray(slots, dtype=np.int32)

@@ -16,7 +16,7 @@ from pathway_tpu.internals.device import place_compile_cache
 
 place_compile_cache()
 
-# plain float, like pallas_knn: a module-scope jnp.float32() would jit a
+# plain float: a module-scope jnp.float32() would jit a
 # convert_element_type at IMPORT time (slow, and it drags XLA compilation
 # into processes that only need the relational plane — e.g. the ASan CI
 # lane, where jaxlib's C++ exceptions abort under the preloaded runtime);

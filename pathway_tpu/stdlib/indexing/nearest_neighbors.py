@@ -3,8 +3,8 @@ python/pathway/stdlib/indexing/nearest_neighbors.py — BruteForceKnn :170,
 USearchKnn :65 and their factories).
 
 Both front-ends here are backed by the TPU brute-force shard
-(pathway_tpu.ops.KnnShard — padded HBM buffer, fused MXU matmul + top-k;
-Pallas variant in ops/pallas_knn.py). The reference's USearchKnn wraps a
+(pathway_tpu.ops.KnnShard — padded HBM buffer, fused MXU matmul + top-k).
+The reference's USearchKnn wraps a
 host-CPU HNSW (usearch_integration.rs:20); at vector-search scales that fit
 one HBM the fused brute-force scan is both exact and faster on TPU, so
 `UsearchKnn` is an API-compatible alias with HNSW-specific knobs accepted

@@ -24,8 +24,8 @@ N-rank scaling lanes (ISSUE 10): ``--ranks 1,2,4`` runs wordcount and
 stream_join at every requested rank count through the real-fork mesh
 harness and records throughput + ``scaling_efficiency`` (vs the 1-rank
 lane measured in the same session) + ``mesh_skew_seconds`` (cross-rank
-recv-wait spread); ``--ranks 1,2,4 --update-artifact`` splices the
-entries into BENCH_full.json in place.
+recv-wait spread). Every lane prints its metric lines; nothing is
+written to disk.
 """
 
 from __future__ import annotations
@@ -757,8 +757,8 @@ def bench_traced_overhead(
     stream_join re-measured with ``PATHWAY_TRACE`` armed, PAIRED with
     fresh untraced runs from the same session so the overhead number
     compares like with like (same host state, same warmup). The traced
-    entries land in BENCH_full.json alongside the untraced value they
-    were paired against plus ``overhead_pct`` — the bar is <= 3%."""
+    entries carry the untraced value they were paired against plus
+    ``overhead_pct`` — the bar is <= 3%."""
     import statistics
     import tempfile
 
@@ -840,7 +840,7 @@ def child(n_rows: int, distinct: int, batch: int, emit=_print_emit) -> None:
 
 def _run_child_capture(args: list[str], env: dict, emit) -> None:
     """Run a child bench process, re-emitting its JSON lines through the
-    parent's emit so BENCH_full.json holds the full curve. A timeout
+    parent's emit so the output holds the full curve. A timeout
     still salvages whatever lines the child managed to print; a failed
     child becomes a ``bench_child_error`` line, which main() turns into
     a non-zero exit once the remaining lanes have run."""
@@ -911,122 +911,6 @@ def main(
             )
 
 
-_RELATIONAL_METRICS = {
-    "wordcount_rows_per_s",
-    "stream_join_rows_per_s",
-    "transform_rows_per_s",
-    "wordcount_2rank_rows_per_s",
-    "wordcount_traced_rows_per_s",
-    "stream_join_traced_rows_per_s",
-    "bench_child_error",
-}
-
-_TRACED_METRICS = {
-    "wordcount_traced_rows_per_s",
-    "stream_join_traced_rows_per_s",
-}
-
-
-def _scaling_metric_names(ranks: list[int]) -> set[str]:
-    names = {
-        f"{name}_{world}rank_rows_per_s"
-        for name in ("wordcount", "stream_join")
-        for world in ranks
-    }
-    if 2 in ranks:
-        # the fast-wire forced-zlib companion lane (ISSUE 13)
-        names.add("wordcount_2rank_zlib_rows_per_s")
-    return names
-
-
-def main_scaling_artifact(
-    ranks: list[int], n_rows: int, distinct: int, batch: int
-) -> None:
-    """--ranks ... --update-artifact: re-measure ONLY the N-rank scaling
-    lanes and splice their metric lines into BENCH_full.json in place
-    (the single-rank relational entries and everything else untouched;
-    a 2-rank lane replaces the legacy wordcount_2rank entry — same
-    metric name, same harness)."""
-    from bench_util import write_artifact_atomic
-
-    path = os.path.join(REPO, "BENCH_full.json")
-    try:
-        with open(path) as f:
-            artifact = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        artifact = []
-    names = _scaling_metric_names(ranks)
-    kept = [
-        m
-        for m in artifact
-        if not (isinstance(m, dict) and m.get("metric") in names)
-    ]
-    fresh: list[dict] = []
-
-    def emit(metric: dict) -> None:
-        _print_emit(metric)
-        fresh.append(metric)
-        write_artifact_atomic(path, kept + fresh)
-
-    bench_scaling(ranks, n_rows, distinct, batch, emit=emit)
-
-
-def main_traced_artifact(n_rows: int, distinct: int, batch: int) -> None:
-    """--traced-artifact: re-measure ONLY the flight-recorder overhead
-    lanes and splice the two traced metric lines into BENCH_full.json
-    in place (the other relational entries are untouched)."""
-    from bench_util import write_artifact_atomic
-
-    path = os.path.join(REPO, "BENCH_full.json")
-    try:
-        with open(path) as f:
-            artifact = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        artifact = []
-    kept = [
-        m
-        for m in artifact
-        if not (isinstance(m, dict) and m.get("metric") in _TRACED_METRICS)
-    ]
-    fresh: list[dict] = []
-
-    def emit(metric: dict) -> None:
-        _print_emit(metric)
-        fresh.append(metric)
-        write_artifact_atomic(path, kept + fresh)
-
-    bench_traced_overhead(n_rows, distinct, batch, emit=emit)
-
-
-def main_update_artifact(n_rows: int, distinct: int, batch: int) -> None:
-    """Re-measure the relational plane and splice the fresh metric lines
-    into BENCH_full.json in place of the stale relational entries (the
-    serving/ingest entries are untouched — rerunning those needs the
-    accelerator harness). Keeps the artifact current across
-    relational-only rounds without a full bench.py pass."""
-    from bench_util import write_artifact_atomic
-
-    path = os.path.join(REPO, "BENCH_full.json")
-    try:
-        with open(path) as f:
-            artifact = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        artifact = []
-    kept = [
-        m
-        for m in artifact
-        if not (isinstance(m, dict) and m.get("metric") in _RELATIONAL_METRICS)
-    ]
-    fresh: list[dict] = []
-
-    def emit(metric: dict) -> None:
-        _print_emit(metric)
-        fresh.append(metric)
-        write_artifact_atomic(path, kept + fresh)
-
-    main(n_rows, distinct, batch, emit=emit)
-
-
 if __name__ == "__main__":
     args = list(sys.argv[1:])
     ranks = None
@@ -1038,8 +922,8 @@ if __name__ == "__main__":
             ranks = [int(x) for x in args[i + 1].split(",") if x]
         except (IndexError, ValueError):
             sys.exit(
-                "usage: bench_relational.py --ranks N[,M,...] "
-                "[--update-artifact]  (e.g. --ranks 1,2,4)"
+                "usage: bench_relational.py --ranks N[,M,...]  "
+                "(e.g. --ranks 1,2,4)"
             )
         if not ranks:
             sys.exit("--ranks needs at least one rank count")
@@ -1049,15 +933,8 @@ if __name__ == "__main__":
     d = int(argv[1]) if len(argv) > 1 else 5_000
     b = int(argv[2]) if len(argv) > 2 else 2_000
     if ranks is not None:
-        if "--update-artifact" in args:
-            main_scaling_artifact(ranks, n, d, b)
-        else:
-            bench_scaling(ranks, n, d, b)
+        bench_scaling(ranks, n, d, b)
     elif "--child" in args:
         child(n, d, b)
-    elif "--update-artifact" in args:
-        main_update_artifact(n, d, b)
-    elif "--traced-artifact" in args:
-        main_traced_artifact(n, d, b)
     else:
         main(n, d, b)

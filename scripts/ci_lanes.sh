@@ -174,21 +174,21 @@ echo "=== lane 14: device-trace smoke (embed+KNN device plane) ==="
 # re-measured with `--bench`.
 env -u PATHWAY_LANE_PROCESSES python scripts/device_trace_smoke.py
 
-echo "=== lane 15: sharded-index smoke (pod-sharded HBM KNN + fused ingest) ==="
+echo "=== lane 15: sharded-index smoke (pod-sharded HBM KNN + encode/add burst) ==="
 # real-fork embed+KNN pipeline whose index adapter is backed by the
 # pod-sharded index (PATHWAY_INDEX_SHARDS=8 over the emulated 8-device
-# CPU mesh) with a fused tokenize->encode->index ingest burst in the
+# CPU mesh) with a burst of SentenceEncoder.encode + KnnShard.add in the
 # same traced process: LIVE /metrics must show per-site device samples
 # for knn.sharded_search / knn.sharded_write (dispatches + the
 # effective-FLOPs family) with ZERO nb_fallbacks_total, the trace must
-# carry device spans for the sharded sites AND the fused chain, and
-# `analysis --profile` must exit 0 naming ingest.fused with a roofline
-# verdict. Then in-process: capacity scales 4x one chip's slots over 8
-# shards with zero per-shard growth and no empty shard, and sharded-vs-
-# single query p50 is measured (flat-within-20% gates real multi-device
-# backends; the CPU emulation records the ratio, gross gate only).
-# Bit-identical parity is tests/test_sharded_parity.py (lanes 1/2);
-# BENCH_full.json records sharded_knn_scaling via `--update-artifact`.
+# carry device spans for the sharded sites, encoder.forward and
+# knn.write, and `analysis --profile` must exit 0 naming
+# encoder.forward with a roofline verdict. Then in-process: capacity
+# scales 4x one chip's slots over 8 shards with zero per-shard growth
+# and no empty shard, and sharded-vs-single query p50 is measured
+# (flat-within-20% gates real multi-device backends; the CPU emulation
+# records the ratio, gross gate only).
+# Bit-identical parity is tests/test_sharded_parity.py (lanes 1/2).
 env -u PATHWAY_LANE_PROCESSES python scripts/sharded_index_smoke.py
 
 echo "=== lane 16: device fault-domain chaos smoke (snapshot/restore/reshard) ==="
@@ -225,8 +225,8 @@ echo "=== lane 17: backpressure smoke (bounded-memory firehose + pacing) ==="
 env -u PATHWAY_LANE_PROCESSES python scripts/backpressure_smoke.py
 
 echo "=== lane 18: device doctor (static dispatch-plane analysis) ==="
-# zero-execution lowering of every registered device chain (fused
-# ingest, KNN scan/write, sharded search/write, encoder forward):
+# zero-execution lowering of every registered device chain (KNN
+# scan/write, sharded search/write, encoder forward):
 # donation aliasing, host syncs, retrace buckets, HBM budget and mesh
 # layout must all verify device-clean on the shipped chains (exit 0
 # under --require-device-clean), and each seeded defect class must be

@@ -3,18 +3,18 @@
 
 Runs a REAL embed+KNN pipeline whose index adapter is backed by the
 pod-sharded HBM index (``PATHWAY_INDEX_SHARDS=8`` over the emulated
-8-device CPU mesh) while a fused tokenize→encode→index ingest burst
-(ops/ingest.py) runs inside the same traced process, then asserts the
-ISSUE 16 chain end to end:
+8-device CPU mesh) while a burst of ``SentenceEncoder.encode`` +
+``KnnShard.add`` calls runs inside the same traced process, then asserts
+the ISSUE 16 chain end to end:
 
 1. LIVE ``/metrics`` shows per-site device samples for the sharded
    index (``device_site_dispatches_total{site="knn.sharded_search"}``
    and the sharded write site) plus the effective-FLOPs family, with
    ZERO ``nb_fallbacks_total`` — the sharded path must not knock any
    relational operator off its native fast path;
-2. the trace carries device spans for both the sharded index sites and
-   the fused chain, and ``python -m pathway_tpu.analysis --profile``
-   exits 0 NAMING the fused chain (``ingest.fused``) with a roofline
+2. the trace carries device spans for the sharded index sites, the
+   encoder and the one-chip write, and ``python -m pathway_tpu.analysis
+   --profile`` exits 0 NAMING ``encoder.forward`` with a roofline
    verdict;
 3. capacity scales with the mesh: the 8-shard index absorbs 4x a single
    chip's slot budget with zero per-shard growth and every shard
@@ -69,20 +69,18 @@ class DocSchema(pw.Schema):
 class Queries(pw.io.python.ConnectorSubject):
     _deletions_enabled = False
     def run(self):
-        # the fused ingest burst runs on the query connector's thread:
-        # it executes DURING pw.run, so its ingest.fused dispatches land
-        # on the armed device plane (same trace, same /metrics)
-        from pathway_tpu.ops.ingest import IngestPipeline
+        # the ingest burst runs on the query connector's thread: it
+        # executes DURING pw.run, so its encoder.forward / knn.write
+        # dispatches land on the armed device plane (same trace, same
+        # /metrics)
         from pathway_tpu.ops.knn import KnnShard
 
         shard = KnnShard(DIM, "cos", capacity=256)
-        pipe = IngestPipeline(enc, shard)
-        batches = (
-            ([f"burst{{s}}-{{j}}" for j in range(16)],
-             DOCS[s * 16 : s * 16 + 16])
-            for s in range(4)
-        )
-        pipe.run(batches)
+        for s in range(4):
+            shard.add(
+                [f"burst{{s}}-{{j}}" for j in range(16)],
+                enc.encode(DOCS[s * 16 : s * 16 + 16]),
+            )
         assert len(shard) == 64
         for i in range(8):
             self.next_batch([{{"q": f"topic {{i % 13}}"}}])
@@ -222,8 +220,8 @@ def run_smoke() -> None:
         f"{writes:.0f} writes), nb_fallbacks 0"
     )
 
-    # 2. trace has both the sharded sites and the fused chain; --profile
-    #    exits 0 naming ingest.fused with a verdict
+    # 2. trace has the sharded sites, the encoder and the burst's
+    #    write; --profile exits 0 naming encoder.forward with a verdict
     if not os.path.exists(trace):
         fail("trace file missing")
     doc = json.load(open(trace))
@@ -235,7 +233,10 @@ def run_smoke() -> None:
     sites = {
         e["name"] for e in doc["traceEvents"] if e.get("cat") == "device"
     }
-    for want in ("knn.sharded_search", "knn.sharded_write", "ingest.fused"):
+    for want in (
+        "knn.sharded_search", "knn.sharded_write", "encoder.forward",
+        "knn.write",
+    ):
         if want not in sites:
             fail(f"device site {want!r} missing from trace ({sites})")
     from pathway_tpu.analysis.__main__ import main as cli_main
@@ -247,28 +248,29 @@ def run_smoke() -> None:
     dev = report.get("device")
     if not dev or not dev["sites"]:
         fail("--profile report has no device section")
-    fused = next(
-        (s for s in dev["sites"] if s["site"] == "ingest.fused"), None
+    forward = next(
+        (s for s in dev["sites"] if s["site"] == "encoder.forward"), None
     )
-    if fused is None:
-        fail("--profile does not name the fused chain")
-    if fused["verdict"] not in (
+    if forward is None:
+        fail("--profile does not name encoder.forward")
+    if forward["verdict"] not in (
         "compute-bound", "bandwidth-bound", "host-bound"
     ):
-        fail(f"bad fused-chain verdict: {fused['verdict']!r}")
-    if not (0 <= fused["mfu"] <= fused["mfu_padded"]):
+        fail(f"bad encoder.forward verdict: {forward['verdict']!r}")
+    if not (0 <= forward["mfu"] <= forward["mfu_padded"]):
         fail(
-            f"fused-chain MFU accounting broken: "
-            f"{fused['mfu']} / {fused['mfu_padded']}"
+            f"encoder.forward MFU accounting broken: "
+            f"{forward['mfu']} / {forward['mfu_padded']}"
         )
     print(
-        "sharded_index_smoke: --profile names ingest.fused "
-        f"({fused['dispatches']} dispatches, mfu {fused['mfu']:.4f} "
-        f"eff / {fused['mfu_padded']:.4f} padded) -> {fused['verdict']}"
+        "sharded_index_smoke: --profile names encoder.forward "
+        f"({forward['dispatches']} dispatches, mfu {forward['mfu']:.4f} "
+        f"eff / {forward['mfu_padded']:.4f} padded) -> "
+        f"{forward['verdict']}"
     )
 
 
-def measure_scaling(update_artifact: bool) -> None:
+def measure_scaling() -> None:
     """Capacity scaling + latency flatness, in-process on the emulated
     8-device mesh."""
     flags = os.environ.get("XLA_FLAGS", "")
@@ -345,51 +347,11 @@ def measure_scaling(update_artifact: bool) -> None:
     )
     if ratio > bar:
         fail(f"sharded query latency ratio {ratio:.2f} > {bar}")
-    if update_artifact:
-        path = os.path.join(REPO, "BENCH_full.json")
-        art = json.load(open(path))
-        entry = {
-            "metric": "sharded_knn_scaling",
-            "value": round(ratio, 3),
-            "unit": "sharded_over_single_query_p50_ratio",
-            "single_p50_ms": round(t_single * 1e3, 3),
-            "sharded_p50_ms": round(t_shard * 1e3, 3),
-            "shards": 8,
-            "rows": n,
-            "dim": dim,
-            "queries": nq,
-            "capacity_no_growth_rows": n_cap,
-            "shard_fill": fill,
-            "backend": backend,
-            "latency_bar": bar,
-            "method": (
-                "ShardedKnnIndex(8 emulated CPU devices) vs single-chip "
-                "KnnShard, same rows/queries; p50 of 11 reps; "
-                "flat-within-20% bar applies on real multi-device "
-                "backends, CPU emulation gates gross regression only"
-            ),
-        }
-        art = [
-            e for e in art
-            if not (
-                isinstance(e, dict)
-                and e.get("metric") == "sharded_knn_scaling"
-            )
-        ] + [entry]
-        with open(path, "w") as f:
-            json.dump(art, f, indent=1)
-            f.write("\n")
-        print(
-            "sharded_index_smoke: BENCH_full.json sharded_knn_scaling "
-            "updated"
-        )
-
 
 def main() -> int:
-    update = "--update-artifact" in sys.argv
     if "--scaling-only" not in sys.argv:
         run_smoke()
-    measure_scaling(update)
+    measure_scaling()
     print("sharded_index_smoke: PASS")
     return 0
 

@@ -15,8 +15,7 @@ Pins the tentpole contracts:
   second copy to drift): transient errors retry with bounded backoff,
   OOM refuses growth and browns the serving plane out via the listener
   hook, watchdog trips and permanent faults abort;
-* **satellites** — the fused-ingest producer restarts through the same
-  classifier, and index filter-predicate failures are counted and
+* **satellite** — index filter-predicate failures are counted and
   surfaced instead of swallowed.
 """
 
@@ -509,41 +508,8 @@ def test_reshard_rebuckets_without_loss_or_duplication(pm, worlds):
 
 
 # ---------------------------------------------------------------------------
-# satellites: ingest producer restart, filter-error surfacing
+# satellite: filter-error surfacing
 # ---------------------------------------------------------------------------
-
-
-def test_ingest_producer_restarts_through_classifier():
-    from pathway_tpu.models.encoder import EncoderConfig, SentenceEncoder
-    from pathway_tpu.ops.ingest import IngestPipeline
-
-    cfg = EncoderConfig.tiny()
-    enc = SentenceEncoder(cfg)
-    shard = KnnShard(cfg.hidden, "cos")
-    pipe = IngestPipeline(enc, shard)
-    texts = ["alpha beta", "gamma delta", "epsilon zeta", "eta theta"]
-    batches = [(["a", "b"], texts[:2]), (["c", "d"], texts[2:])]
-    # transient staging failures (device.h2d) restart the producer on
-    # the SAME batch with backoff; the run completes with no loss
-    faults.install_plan({"rules": [
-        {"point": "device.h2d", "hits": [1, 3], "action": "raise"},
-    ]})
-    stats = ProberStats()
-    PLANE.arm(None, stats)
-    try:
-        pipe.run(iter(batches))
-    finally:
-        PLANE.disarm()
-    assert len(shard) == 4
-    assert stats.device_dispatch_retries.get("ingest.fused") == 2
-    # a permanent staging failure surfaces raw — no infinite restart
-    faults.clear_plan()
-    faults.install_plan({"rules": [
-        {"point": "device.h2d", "action": "raise", "retryable": False},
-    ]})
-    with pytest.raises(faults.InjectedFault):
-        pipe.run(iter([(["e"], ["iota kappa"])]))
-    assert len(shard) == 4
 
 
 def test_filter_errors_counted_and_first_surfaced():

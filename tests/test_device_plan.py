@@ -3,9 +3,10 @@
 The seeded-defect battery — an un-donated index write, an injected
 mid-chain ``.item()`` host sync, an unbounded-bucket pipeline, and an
 over-budget shard layout — must each be caught STATICALLY with correct
-provenance and a fix hint, while the shipped ingest and sharded-KNN
-chains verify device-clean with zero execution (the armed device plane
-records no dispatch during analysis). Satellite coverage: the site
+provenance and a fix hint on a chain the product dispatches, while the
+shipped encoder, KNN and sharded-KNN chains verify device-clean with
+zero execution (the armed device plane records no dispatch during
+analysis). Satellite coverage: the site
 registry round-trips through the lint pass, the per-shape compiled-cost
 cache is bounded, and every dispatch site ticks
 ``device_site_recompiles_total`` on a fresh shape bucket.
@@ -27,7 +28,6 @@ from pathway_tpu.analysis.device_plan import (  # noqa: E402
     WorkloadSpec,
     analyze_device_plan,
     join_profile,
-    simulate_ingest_buckets,
     simulate_knn_buckets,
 )
 from pathway_tpu.internals.device import (  # noqa: E402
@@ -36,10 +36,12 @@ from pathway_tpu.internals.device import (  # noqa: E402
 )
 from pathway_tpu.internals.monitoring import ProberStats  # noqa: E402
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 ALL_SITES = {
     "answer.decode", "answer.prefill",
-    "encoder.forward", "ingest.fused", "knn.search", "knn.sharded_search",
-    "knn.sharded_write", "knn.write", "pallas.topk", "serve.window",
+    "encoder.forward", "knn.search", "knn.sharded_search",
+    "knn.sharded_write", "knn.write", "serve.window",
 }
 
 
@@ -74,9 +76,7 @@ def test_shipped_chains_analyze_clean_with_zero_execution():
         PLANE.disarm()
     assert report.verdict == "device-clean"
     assert report.device_clean
-    assert set(report.chains) == {
-        "ingest", "knn", "sharded", "encoder", "pallas",
-    }
+    assert set(report.chains) == {"knn", "sharded", "encoder"}
     assert all(v == "clean" for v in report.chains.values())
     assert stats.device_sites == {}, "analysis must not dispatch"
     assert stats.device_recompiles == {}, "analysis must not compile-tick"
@@ -89,8 +89,8 @@ def test_report_shape_and_json_roundtrip():
     assert d["verdict"] == "device-clean"
     # every registered chain site carries a bucket/recompile prediction
     for site in (
-        "ingest.fused", "knn.write", "knn.search", "knn.sharded_write",
-        "knn.sharded_search", "encoder.forward", "pallas.topk",
+        "knn.write", "knn.search", "knn.sharded_write",
+        "knn.sharded_search", "encoder.forward",
     ):
         assert d["predictions"][site]["recompiles"] >= 1
     assert d["hbm"]["footprint_bytes"] > 0
@@ -101,29 +101,63 @@ def test_report_shape_and_json_roundtrip():
 
 # -- seeded defect battery ---------------------------------------------------
 
-def test_mutant_undonated_write_is_caught_with_copy_cost_blame():
-    report = analyze_device_plan(mutant="undonated_write")
-    assert report.verdict == "device-dirty"
-    d = _diag(report, "device.donation")
-    assert d.severity == "error"
-    assert d.node == "ingest.fused"
-    assert "ops/ingest.py" in d.where
-    assert "MB" in d.message          # the per-dispatch HBM copy blame
-    assert "donate_argnums" in d.hint
-    assert report.chains["ingest"] == "dirty"
-    # the other chains keep their own verdicts: the defect is localized
-    assert report.chains["knn"] == "clean"
+# (mutant, declared world, site blamed, its file, its chain, the message's
+# blame, the hint's fix): the defects sit in what the product dispatches
+SEEDED = [
+    ("undonated_write", 1, "knn.write", "pathway_tpu/ops/knn.py", "knn",
+     "MB", "donate_argnums"),
+    ("undonated_write", 4, "knn.sharded_write",
+     "pathway_tpu/parallel/sharded_knn.py", "sharded",
+     "MB", "donate_argnums"),
+    ("host_sync", 1, "encoder.forward", "pathway_tpu/models/encoder.py",
+     "encoder", ".item()", ""),
+    ("host_sync", 1, "knn.search", "pathway_tpu/ops/knn.py", "knn",
+     ".item()", ""),
+]
 
 
-def test_mutant_host_sync_is_caught_with_provenance():
-    report = analyze_device_plan(mutant="host_sync")
+@pytest.mark.parametrize(
+    "mutant, world, site, path, chain, blame, fix", SEEDED,
+    ids=[f"{m}-{s}" for m, _, s, *_ in SEEDED],
+)
+def test_mutant_is_caught_on_a_chain_the_product_dispatches(
+    mutant, world, site, path, chain, blame, fix
+):
+    code = {
+        "undonated_write": "device.donation", "host_sync": "device.host_sync",
+    }[mutant]
+    report = analyze_device_plan(mutant=mutant, world=world)
     assert report.verdict == "device-dirty"
-    d = _diag(report, "device.host_sync")
+    hits = [
+        d for d in report.diagnostics if d.code == code and d.node == site
+    ]
+    assert hits, [(d.code, d.node) for d in report.diagnostics]
+    d = hits[0]
     assert d.severity == "error"
-    assert d.node == "ingest.fused"
-    assert "ops/ingest.py" in d.where
-    assert ".item()" in d.message
-    assert d.hint
+    assert d.where.startswith(path + ":")
+    assert blame in d.message  # the copy's cost, or the read that syncs
+    assert d.hint and fix in d.hint
+    assert report.chains[chain] == "dirty"
+    # the defect is localized: a chain it was not seeded in stays clean
+    seeded_in = {
+        "undonated_write": {chain}, "host_sync": {"encoder", "knn"},
+    }[mutant]
+    for other in set(report.chains) - seeded_in:
+        assert report.chains[other] == "clean", other
+
+
+@pytest.mark.parametrize("mutant", MUTANTS)
+def test_every_error_of_a_mutant_names_a_product_site_and_a_file(mutant):
+    """The CLI's exit-2 contract (ci lane 18): whatever a seeded defect
+    is blamed on is a site the product dispatches, in a file that is
+    there to open."""
+    report = analyze_device_plan(mutant=mutant)
+    errors = [d for d in report.diagnostics if d.severity == "error"]
+    assert errors and report.verdict == "device-dirty"
+    for d in errors:
+        assert d.node in registered_sites(), d
+        where_file, _, where_name = d.where.partition(":")
+        assert where_name and os.path.isfile(os.path.join(REPO, where_file)), d
 
 
 def test_mutant_unbounded_buckets_is_refused():
@@ -233,29 +267,38 @@ def test_shipped_write_chain_lowers_with_aliasing_markers():
 # -- retrace predictions (shared bucket enumeration) -------------------------
 
 def test_bucket_simulation_dedups_equal_shapes():
+    from pathway_tpu.models.encoder import EncoderConfig
+
     spec = WorkloadSpec(
-        ingest_batches=((64, 40), (64, 40)),
-        write_batches=(64, 64),
+        ingest_batches=((8, 40), (8, 40)),
+        write_batches=(8, 8),
         query_batches=(1, 1),
         ks=(10,),
     )
-    from pathway_tpu.models.encoder import EncoderConfig
-
-    assert len(simulate_ingest_buckets(spec, EncoderConfig.tiny())) == 1
+    # an encode call's rows are one index write: four writes of 8 rows
+    # (one member of the tiny encoder's shape set holds a call whole),
+    # all inside the first 128 slots
+    assert spec.write_rows() == (8, 8, 8, 8)
     wb, sb = simulate_knn_buckets(spec)
     assert len(wb) == 1
     assert len(sb) == 1
+    predictions = analyze_device_plan(
+        workload=spec, config=EncoderConfig.tiny()
+    ).predictions
+    assert predictions["encoder.forward"]["recompiles"] == 1
+    assert predictions["knn.write"]["buckets"] == wb
+    assert predictions["knn.search"]["buckets"] == sb
+    assert predictions["knn.sharded_write"]["recompiles"] == 1
 
     # crossing the pow2 capacity IS a fresh bucket (growth reshape =
-    # fresh executable) — the simulation models it
-    grown = WorkloadSpec(
-        ingest_batches=((64, 40),) * 3, write_batches=(64,) * 3
-    )
-    assert len(
-        simulate_ingest_buckets(grown, EncoderConfig.tiny())
-    ) == 2
-    wb, _ = simulate_knn_buckets(grown)
-    assert len(wb) == 2
+    # fresh executable) — the simulation models it, whichever kind of
+    # write crosses it
+    for grown in (
+        WorkloadSpec(ingest_batches=((64, 40),) * 3, write_batches=()),
+        WorkloadSpec(ingest_batches=(), write_batches=(64,) * 3),
+    ):
+        wb, _ = simulate_knn_buckets(grown)
+        assert len(wb) == 2
 
 
 def test_excessive_bucket_set_warns(monkeypatch):
@@ -274,24 +317,24 @@ def test_excessive_bucket_set_warns(monkeypatch):
 
 def test_join_profile_flags_measured_exceeding_predicted():
     report = analyze_device_plan()
-    predicted = report.predictions["ingest.fused"]["recompiles"]
+    predicted = report.predictions["encoder.forward"]["recompiles"]
     joined = join_profile(
         analyze_device_plan(),
-        {"device_recompiles": {"ingest.fused": predicted + 5}},
+        {"device_recompiles": {"encoder.forward": predicted + 5}},
     )
     assert joined.verdict == "device-dirty"
     d = _diag(joined, "device.retrace.drift")
-    assert d.node == "ingest.fused"
-    p = joined.predictions["ingest.fused"]
+    assert d.node == "encoder.forward"
+    p = joined.predictions["encoder.forward"]
     assert p["drift"] == "exceeded"
     assert p["measured_recompiles"] == predicted + 5
 
     ok = join_profile(
         analyze_device_plan(),
-        {"device_recompiles": {"ingest.fused": predicted}},
+        {"device_recompiles": {"encoder.forward": predicted}},
     )
     assert ok.verdict == "device-clean"
-    assert ok.predictions["ingest.fused"]["drift"] == "ok"
+    assert ok.predictions["encoder.forward"]["drift"] == "ok"
 
 
 # -- analyzer / CLI integration ----------------------------------------------
@@ -347,7 +390,7 @@ def test_cli_profile_join(tmp_path, capsys):
 
     trace = tmp_path / "trace.json"
     trace.write_text(json.dumps(
-        {"device_recompiles": {"ingest.fused": 10_000}}
+        {"device_recompiles": {"encoder.forward": 10_000}}
     ))
     rc = main(["--device-plan", "--profile", str(trace)])
     assert rc == 2  # drift is an error
@@ -359,13 +402,11 @@ def test_cli_profile_join(tmp_path, capsys):
 def test_registry_covers_every_dispatch_site():
     # registrations live next to their dispatch sites — importing the
     # dispatch modules populates the registry (analyze_device_plan pulls
-    # most in; pallas + the serving gateway register on import here)
+    # most in; the serving gateway and the decoder register on import here)
     import pathway_tpu.io.http._server  # noqa: F401
     import pathway_tpu.models.decoder  # noqa: F401
     import pathway_tpu.models.encoder  # noqa: F401
-    import pathway_tpu.ops.ingest  # noqa: F401
     import pathway_tpu.ops.knn  # noqa: F401
-    import pathway_tpu.ops.pallas_knn  # noqa: F401
     import pathway_tpu.parallel.sharded_knn  # noqa: F401
 
     sites = registered_sites()
@@ -495,22 +536,3 @@ def test_sharded_sites_tick_recompiles():
         assert stats.device_recompiles == before
     finally:
         PLANE.disarm()
-
-
-def test_pallas_site_ticks_recompiles():
-    from pathway_tpu.ops.pallas_knn import _SEEN_BUCKETS, pallas_topk_scores
-
-    stats = ProberStats()
-    PLANE.arm(None, stats)
-    _SEEN_BUCKETS.clear()
-    try:
-        q = jnp.zeros((2, 8), jnp.float32)
-        db = jnp.zeros((64, 8), jnp.float32)
-        mask = jnp.zeros((64,), jnp.float32)
-        pallas_topk_scores(q, db, mask, k=4, block=64, interpret=True)
-        assert stats.device_recompiles["pallas.topk"] == 1
-        pallas_topk_scores(q, db, mask, k=4, block=64, interpret=True)
-        assert stats.device_recompiles["pallas.topk"] == 1
-    finally:
-        PLANE.disarm()
-        _SEEN_BUCKETS.clear()

@@ -622,8 +622,7 @@ def test_dispatch_queue_depth_tracks_inflight():
 def test_device_plane_overhead_pair_measured_under_3pct():
     """Traced-vs-untraced overhead of the device plane on the embed+KNN
     hot loop, measured as INTERLEAVED pairs (sequential blocks read
-    ordering bias) — the same methodology as the PR 8 relational lanes.
-    The smoke lane records the same number into BENCH_full.json."""
+    ordering bias) — the same methodology as the PR 8 relational lanes."""
     from pathway_tpu.models.encoder import EncoderConfig, SentenceEncoder
     from pathway_tpu.ops.knn import KnnShard
 

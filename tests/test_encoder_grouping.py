@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from pathway_tpu.analysis.device_plan import WorkloadSpec, analyze_device_plan
 from pathway_tpu.internals import device
 from pathway_tpu.internals.device import (
+    PLANE,
     batch_bucket,
     encoder_bucket,
     encoder_call_groups,
@@ -22,6 +23,7 @@ from pathway_tpu.internals.device import (
     encoder_group_shapes,
     seq_bucket,
 )
+from pathway_tpu.internals.monitoring import ProberStats
 from pathway_tpu.models import encoder as encoder_module
 from pathway_tpu.models.encoder import EncoderConfig, SentenceEncoder, pad_batch
 
@@ -239,3 +241,20 @@ def test_heavy_tailed_calls_stay_inside_the_set_and_compile_once_a_member():
     assert not late, late  # no executable keyed by a call's row count
     assert max(shares) <= 0.25, max(shares)
     assert len(enc._compiled) == len(sighted) + 1  # and the warm call's
+
+
+def test_encoder_bucket_cache_notes_recompiles():
+    """A shape's first dispatch is one ``encoder.forward`` recompile on
+    the armed plane; a shape met again is none."""
+    enc = SentenceEncoder(EncoderConfig.tiny())
+    texts = _texts([11, 10, 9, 8, 9])
+    stats = ProberStats()
+    PLANE.disarm()
+    PLANE.arm(None, stats)
+    try:
+        enc.encode(texts)      # fresh (batch, seq) bucket
+        enc.encode(texts)      # cached: no new note
+        enc.encode(texts * 4)  # larger batch bucket
+    finally:
+        PLANE.disarm()
+    assert stats.device_recompiles.get("encoder.forward") == 2

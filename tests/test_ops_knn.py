@@ -1,4 +1,4 @@
-"""Tests for the TPU KNN ops (run on CPU backend; Pallas in interpret mode).
+"""Tests for the TPU KNN ops (run on CPU backend).
 
 Mirrors the reference's brute-force index behavior coverage
 (/root/reference/src/external_integration/brute_force_knn_integration.rs tests
@@ -92,26 +92,82 @@ def test_merge_topk():
     assert list(np.asarray(i)[0]) == [0, 10, 11]
 
 
-def test_pallas_kernel_interpret_matches_oracle():
-    import jax.numpy as jnp
+def test_update_while_serving_consistency():
+    """Concurrent add/remove churn against searches in flight: no torn
+    snapshots, no donated-buffer crashes (shard.lock serializes write vs
+    read+launch), and every answer maps to a key that existed."""
+    import threading
 
-    from pathway_tpu.ops.pallas_knn import pallas_topk_scores
-
+    dim = 32
+    shard = KnnShard(dim, "cos", capacity=4096)
     rng = np.random.default_rng(3)
-    db = rng.normal(size=(256, 8)).astype(np.float32)
-    queries = rng.normal(size=(4, 8)).astype(np.float32)
-    mask = np.zeros(256, np.float32)
-    mask[100:110] = -np.inf  # deleted slots
-    vals, idx = pallas_topk_scores(
-        jnp.asarray(queries), jnp.asarray(db), jnp.asarray(mask),
-        k=5, block=64, interpret=True,
-    )
-    db_masked = db.copy()
-    scores = queries @ db_masked.T + mask[None, :]
-    want_idx = np.argsort(-scores, axis=-1, kind="stable")[:, :5]
-    np.testing.assert_array_equal(np.asarray(idx), want_idx)
-    np.testing.assert_allclose(
-        np.asarray(vals),
-        np.take_along_axis(scores, want_idx, -1),
-        rtol=1e-5,
-    )
+    shard.add(list(range(256)), rng.normal(size=(256, dim)).astype(np.float32))
+    shard.search(rng.normal(size=(1, dim)).astype(np.float32), k=4)
+
+    stop = threading.Event()
+    errors = []
+
+    def updater():
+        nk = 1000
+        try:
+            while not stop.is_set():
+                vecs = rng.normal(size=(32, dim)).astype(np.float32)
+                keys = list(range(nk, nk + 32))
+                shard.add(keys, vecs)
+                nk += 32
+                shard.remove(keys[:16])
+        except Exception as exc:  # pragma: no cover
+            errors.append(exc)
+
+    def querier(seed):
+        qrng = np.random.default_rng(seed)
+        try:
+            for _ in range(30):
+                query = qrng.normal(size=(1, dim)).astype(np.float32)
+                hits = shard.search(query, k=4)[0]
+                assert hits
+                for key, score in hits:
+                    assert isinstance(key, int)
+                    assert np.isfinite(score)
+        except Exception as exc:  # pragma: no cover
+            errors.append(exc)
+
+    ut = threading.Thread(target=updater)
+    qs = [threading.Thread(target=querier, args=(s,)) for s in range(3)]
+    ut.start()
+    for q in qs:
+        q.start()
+    for q in qs:
+        q.join(timeout=120)
+    stop.set()
+    ut.join(timeout=30)
+    assert not errors, errors
+
+
+def test_slot_reuse_between_dispatch_and_resolve_drops_hit():
+    """A slot freed (and reused by a new key) after a search's dispatch
+    must not map the in-flight score to the NEW key: the remove-epoch
+    guard of ``_resolve_hits`` drops it (removed-row semantics)."""
+    vecs = np.eye(8, dtype=np.float32)
+    shard = KnnShard(8, "cos", capacity=64)
+    shard.add(["old", "other"], vecs[:2])
+    resolve = shard._resolve_hits
+    reused = []
+
+    def resolve_after_reuse(vals, idx, k, epoch):
+        # the scan has run; its hits are not yet mapped back to keys
+        old_slot = shard.key_to_slot["old"]
+        shard.remove(["old"])
+        shard.add(["new"], vecs[1:2])  # the free list reuses the slot
+        reused.append(shard.key_to_slot["new"] == old_slot)
+        return resolve(vals, idx, k, epoch)
+
+    shard._resolve_hits = resolve_after_reuse
+    hits = shard.search(vecs[:1], k=1)[0]
+    shard._resolve_hits = resolve
+    assert reused == [True]  # reuse actually happened
+    assert all(key != "new" for key, _ in hits), hits
+
+    # a fresh search resolves against the updated mapping
+    hits2 = shard.search(vecs[1:2], k=1)[0]
+    assert hits2 and hits2[0][0] in ("new", "other")

@@ -265,6 +265,26 @@ def test_knob_registry_covers_every_env_read():
     assert not missing, f"unregistered knobs: {sorted(missing)}"
 
 
+def test_knob_registry_has_no_entry_that_nothing_reads():
+    """The inverse: a registered name that no source under the package
+    mentions (outside the registry) is an option nobody can turn — it
+    went with its reader and its entry stayed."""
+    import re
+
+    text = []
+    for root, _dirs, files in os.walk(os.path.join(REPO, "pathway_tpu")):
+        for fn in files:
+            if fn.endswith((".py", ".c", ".cc", ".cpp", ".h")) and fn != "knobs.py":
+                with open(os.path.join(root, fn), errors="replace") as f:
+                    text.append(f.read())
+    # the suite's own switch is read where the suite starts
+    with open(os.path.join(REPO, "tests", "conftest.py")) as f:
+        text.append(f.read())
+    read = set(re.findall(r"PATHWAY_[A-Z0-9_]+", "\n".join(text)))
+    unread = set(pk.KNOBS) - read
+    assert not unread, f"registered, read nowhere: {sorted(unread)}"
+
+
 def test_knob_validation_rejects_bad_values(monkeypatch):
     monkeypatch.setenv("PATHWAY_THREADS", "zero")
     findings = pk.validate_environment()

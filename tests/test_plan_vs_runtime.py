@@ -501,24 +501,18 @@ needs_jax = pytest.mark.skipif(
 
 
 @needs_jax
-def test_device_plan_predicts_fused_ingest_recompiles_exactly():
-    import numpy as np  # noqa: F401
-
+def test_device_plan_predicts_encoder_recompiles_exactly():
     from pathway_tpu.analysis.device_plan import (
         WorkloadSpec,
+        analyze_device_plan,
         join_profile,
-        simulate_ingest_buckets,
     )
     from pathway_tpu.internals.device import PLANE
     from pathway_tpu.internals.monitoring import ProberStats
     from pathway_tpu.models.encoder import EncoderConfig, SentenceEncoder
-    from pathway_tpu.ops.ingest import IngestPipeline
-    from pathway_tpu.ops.knn import KnnShard
 
     cfg = EncoderConfig.tiny()
     enc = SentenceEncoder(cfg)
-    shard = KnnShard(cfg.hidden, capacity=128)
-    pipe = IngestPipeline(enc, shard, stage_h2d=False)
     word = "retrieval"
     batches = [
         [" ".join([word] * 3)] * 4,          # small batch, short seqs
@@ -526,43 +520,39 @@ def test_device_plan_predicts_fused_ingest_recompiles_exactly():
         [" ".join([word] * 40)] * 4,         # the ladder's next rung
         [" ".join([word] * 3)] * 12,         # bigger batch bucket
     ]
-    # the declared workload: (rows, raw token length) per batch, read
-    # off the same tokenizer the pipeline stages with
+    # the declared workload: (rows, raw token length) per call, read
+    # off the same tokenizer the encoder dispatches with
     declared = []
     for texts in batches:
         ids, _ = enc.tokenizer(list(texts))
         declared.append((len(texts), ids.shape[1]))
     spec = WorkloadSpec(
-        ingest_batches=tuple(declared),
-        batch_cap=enc.batch_size,
-        initial_capacity=shard.capacity,
+        ingest_batches=tuple(declared), batch_cap=enc.batch_size
     )
-    predicted = simulate_ingest_buckets(spec, cfg)
+    report = analyze_device_plan(workload=spec, config=cfg)
+    predicted = report.predictions["encoder.forward"]["buckets"]
 
     stats = ProberStats()
     PLANE.disarm()
     PLANE.arm(None, stats)
     try:
-        for i, texts in enumerate(batches):
-            pipe.ingest([f"k{i}-{j}" for j in range(len(texts))], texts)
+        for texts in batches:
+            enc.encode(texts)
     finally:
         PLANE.disarm()
-    measured = stats.device_recompiles.get("ingest.fused", 0)
+    measured = stats.device_recompiles.get("encoder.forward", 0)
     assert measured == len(predicted), (
         f"predicted buckets {sorted(predicted)} vs measured "
         f"{measured} recompiles"
     )
     # the runtime's bucket keys ARE the predicted set (identity-shared
     # bucket functions, not merely equal counts)
-    assert pipe._seen_buckets == predicted
+    assert set(enc._compiled) == predicted
     # and the --profile drift join agrees: measured == predicted is ok
-    from pathway_tpu.analysis.device_plan import analyze_device_plan
-
     joined = join_profile(
-        analyze_device_plan(workload=spec),
-        {"device_recompiles": dict(stats.device_recompiles)},
+        report, {"device_recompiles": dict(stats.device_recompiles)}
     )
-    assert joined.predictions["ingest.fused"]["drift"] == "ok"
+    assert joined.predictions["encoder.forward"]["drift"] == "ok"
     assert joined.verdict == "device-clean"
 
 
@@ -582,6 +572,7 @@ def test_device_plan_predicts_knn_recompiles_exactly():
     query_batches = (1, 3, 8)
     ks = (5, 10)
     spec = WorkloadSpec(
+        ingest_batches=(),             # every write's vectors made elsewhere
         write_batches=write_batches,
         query_batches=query_batches,
         ks=ks,
