@@ -26,6 +26,7 @@ from pathway_tpu.engine.stream import Delta, is_native_batch
 from pathway_tpu.internals import device as _device
 from pathway_tpu.internals import faults as _faults
 from pathway_tpu.internals import flight as _flight
+from pathway_tpu.internals.api import json_hashes as _json_hashes
 
 # the mesh protocol's decisions (wave partition, quiesce guard, leg
 # elision, frontier agreement, commit walk) are NOT implemented here:
@@ -558,11 +559,13 @@ class Runtime:
             "engine.step", trace_id=time, t=time, nodes=0, short_nodes=0,
             short_ns=0,
         )
+        hashes0 = _json_hashes()
         with step:
             try:
                 self._run_step(time)
             finally:
                 self._step_span = prev
+                step.args["json_hashes"] = _json_hashes() - hashes0
 
     def _run_step(self, time: int) -> None:
         """Run all nodes with pending input at `time`, in topo order.

@@ -20,6 +20,8 @@ Delta = tuple  # (key, row, diff)
 
 import numpy as _np
 
+from pathway_tpu.internals.api import Json as _Json
+
 
 def freeze_value(v: Any) -> Any:
     """Hashable, equality-faithful stand-in for any engine value (ndarrays,
@@ -27,6 +29,9 @@ def freeze_value(v: Any) -> Any:
     insertions exactly."""
     if isinstance(v, _np.ndarray):
         return ("__ndarray__", v.shape, v.dtype.str, v.tobytes())
+    if isinstance(v, _Json):
+        # hashable by construction: no probe, which would serialise it
+        return v
     if isinstance(v, tuple):
         return tuple(freeze_value(x) for x in v)
     if isinstance(v, list):

@@ -11,6 +11,7 @@ from __future__ import annotations
 import hashlib
 import json as _json
 import struct
+import threading
 from typing import Any, Iterable
 
 import numpy as np
@@ -150,15 +151,42 @@ def json_navigate(value: Any, index: Any):
     return _NAV_MISSING
 
 
-class Json:
-    """JSON value wrapper (reference: Value::Json)."""
+# Json values serialised to be hashed in this process: the misses only (a
+# kept hash is a slot read). The ring's ``engine.step`` span carries the
+# count's increase over a step as ``json_hashes`` (engine/runtime.py).
+_json_hashes = 0
+_json_hashes_lock = threading.Lock()  # any thread may hash a Json
 
-    __slots__ = ("value",)
+
+def json_hashes() -> int:
+    return _json_hashes
+
+
+class Json:
+    """JSON value wrapper (reference: Value::Json).
+
+    Immutable once it has entered the dataflow, as any value inside an
+    arrangement always was: a retraction finds its row by the value's
+    hash and equality. The hash is the hash of the value's sorted-key
+    serialisation, a ``str`` hash and so salted per process; it is
+    computed on first use and kept on the object, never in a pickle or
+    a copy: those carry the value alone and start without one."""
+
+    __slots__ = ("value", "_hash")
 
     def __init__(self, value: Any):
         if isinstance(value, Json):
             value = value.value
         self.value = value
+        self._hash = None
+
+    def __reduce__(self):
+        return (type(self), (self.value,))
+
+    def __setstate__(self, state):
+        # a pickle made before the hash was kept: (None, {"value": v})
+        self.value = state[1]["value"]
+        self._hash = None
 
     # -- navigation ------------------------------------------------------
     def __getitem__(self, key):
@@ -202,7 +230,15 @@ class Json:
         return self.value == other
 
     def __hash__(self):
-        return hash(_json.dumps(self.value, sort_keys=True, default=str))
+        h = self._hash
+        if h is None:
+            global _json_hashes
+            with _json_hashes_lock:
+                _json_hashes += 1
+            h = self._hash = hash(
+                _json.dumps(self.value, sort_keys=True, default=str)
+            )
+        return h
 
     def __repr__(self):
         return _json.dumps(self.value, default=str)

@@ -21,6 +21,10 @@ promises:
    by the ring's own spans moved onto the trace's clock (``engine.node``
    and the main loop's waits are in the ring only).
 
+4. what a commit of documents costs the engine's thread: the steps of
+   the stretch, ``json_hashes`` a step and every node's self time
+   (``commits`` in the report).
+
 Prints one JSON object (also under ``chiprun_out/span_clock/``). Exit 0
 when 1 and 2 hold, 1 when not, the run's own code when the run failed.
 """
@@ -34,6 +38,7 @@ import glob
 import json
 import os
 import shutil
+import statistics
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -199,6 +204,39 @@ def idle_by_span(gaps, timeline, outside="outside every span"):
     return {k: v * 1e-9 for k, v in secs.most_common() if v > 0}
 
 
+def commits(ring, top: int = 12):
+    """The stretch's steps that ran at least ten recorded nodes (a commit
+    of documents, not a lone question): how many, their median length,
+    ``json_hashes`` a step (None from a tree whose step does not say) and
+    the nodes' median self ms a commit."""
+    from pathway_tpu.internals import flight
+
+    beneath = collections.Counter()
+    for s in ring:
+        beneath[s[5]] += s[3] - s[2]
+    nodes = collections.defaultdict(list)
+    for s in ring:
+        if s[1] == "engine.node":
+            nodes[s[6]].append((flight.args_of(s).get("label"), s[3] - s[2] - beneath[s[0]]))
+    steps = [s for s in ring if s[1] == "engine.step" and len(nodes[s[6]]) >= 10]
+    if not steps:
+        return None
+    by_label = collections.defaultdict(list)
+    for s in steps:
+        for label, ns in nodes[s[6]]:
+            by_label[label].append(ns * 1e-6)
+    hashes = [flight.args_of(s).get("json_hashes") for s in steps]
+    ms = {k: statistics.median(v) for k, v in by_label.items() if len(v) * 2 > len(steps)}
+    return {
+        "steps": len(steps),
+        "step_ms_median": statistics.median([(s[3] - s[2]) * 1e-6 for s in steps]),
+        "json_hashes": None if None in hashes
+        else {"min": min(hashes), "median": statistics.median(hashes), "max": max(hashes)},
+        "nodes_self_ms_sum": sum(ms.values()),
+        "node_self_ms_median": dict(sorted(ms.items(), key=lambda kv: -kv[1])[:top]),
+    }
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--workload", required=True)
@@ -293,6 +331,7 @@ def main() -> int:
             "by_innermost_pw_event_s": by_pw,
             "by_innermost_ring_span_s": idle_by_span(gaps, innermost_timeline(labelled)),
         }
+    report["commits"] = commits(ring)
     report["ok"] = bool(ok)
     text = json.dumps(report)
     print(text, flush=True)

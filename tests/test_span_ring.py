@@ -382,6 +382,56 @@ def test_patched_names_are_called(pipeline):
     assert after["_forward_compact"] > before["_forward_compact"]
 
 
+# -- (h) the step says how many Json values it serialised to hash them --------------
+
+
+def _is_flat(record):
+    return all(
+        v is None or type(v) in (int, float, str, bool) for v in record
+    )
+
+
+def test_step_carries_json_hashes(pipeline):
+    """``json_hashes`` on ``engine.step``: the Json values the process
+    serialised to hash over the step (a kept hash is not counted), an int
+    beside ``nodes`` / ``short_nodes`` / ``short_ns`` in the one flat
+    tuple a span leaves behind."""
+    lo = time.monotonic_ns()
+    pipeline.ingest(64, 16)  # documents carry ``_metadata``, a Json
+    spans = flight.spans_between(lo, time.monotonic_ns())
+    (add,) = _named(spans, "index.add_batch")
+    (step,) = [
+        s for s in _named(spans, "engine.step") if s[S_TRACE] == add[S_TRACE]
+    ]
+    args = _args(step)
+    assert list(args) == ["t", "nodes", "short_nodes", "short_ns", "json_hashes"]
+    assert type(args["json_hashes"]) is int
+    # at least the chunk's Json, at most the three made a document
+    assert 16 <= args["json_hashes"] <= 3 * 16
+    assert _is_flat(step)
+
+    # a step that touched no Json reads zero
+    from pathway_tpu.internals.graph_runner import GraphRunner
+
+    lo = time.monotonic_ns()
+    t = pw.debug.table_from_markdown(
+        """
+        word | n
+        a    | 1
+        b    | 2
+        a    | 3
+        """
+    )
+    GraphRunner().run_tables(t.groupby(t.word).reduce(t.word, s=pw.reducers.sum(t.n)))
+    mine = [
+        s for s in _named(flight.spans_between(lo, time.monotonic_ns()), "engine.step")
+        if s[S_THREAD] == threading.get_ident()
+    ]
+    assert mine and all(_is_flat(s) for s in mine)
+    assert [_args(s)["json_hashes"] for s in mine] == [0] * len(mine)
+    assert sum(_args(s)["nodes"] for s in mine) > 0
+
+
 # -- (e) the slow-request report ----------------------------------------------------
 
 

@@ -359,23 +359,25 @@ def _wait_for(pred, what, deadline_s=60.0):
         time.sleep(0.01)
 
 
+def _file_count(probe):
+    """The corpus's size as the server says it, None while it is not up."""
+    try:
+        return probe.get_vectorstore_statistics()["file_count"]
+    except ConnectionError:
+        return None
+
+
 def test_inputs_over_http_empty_corpus_and_two_questions_in_one_window():
     server, client, feed, _ = _served(window_ms=400.0)
     probe = client()
 
-    def file_count():
-        try:
-            return probe.get_vectorstore_statistics()["file_count"]
-        except ConnectionError:
-            return None
-
     # an empty corpus: no padded row shows through
-    _wait_for(lambda: file_count() == 0, "the server to answer")
+    _wait_for(lambda: _file_count(probe) == 0, "the server to answer")
     assert probe.get_input_files() == []
     assert probe.get_input_files(filepath_globpattern="*.txt") == []
 
     feed.put(_doc_rows(0, 6))
-    _wait_for(lambda: file_count() == 6, "six documents")
+    _wait_for(lambda: _file_count(probe) == 6, "six documents")
     inputs_route = server.webserver._routes[2][2].__self__
     assert inputs_route.route == "/v1/inputs"
     metrics = inputs_route.serve_metrics
@@ -496,3 +498,36 @@ def test_commit_cost_does_not_depend_on_corpus(corpus, monkeypatch):
     probe = client()
     assert probe.get_vectorstore_statistics()["file_count"] == corpus + commit
     assert len(probe.get_input_files()) == corpus + commit
+
+
+def test_a_commit_hashes_each_json_once():
+    """Through the lowered ``run_server`` graph a commit of 512 documents
+    (the third: 1,024 are in) serialises at most three ``Json``s a
+    document to hash them, the three that are made for it (``parse_doc``'s,
+    ``split_doc``'s, the index's metadata), whatever number of
+    arrangements, consolidations and frozen rows each passes through:
+    ``json_hashes`` on the ring's ``engine.step``. A count, not a time."""
+    from pathway_tpu.internals import flight
+
+    commit = 512
+    lo = time.monotonic_ns()
+    server, client, feed, thread = _served()
+    probe = client()
+
+    _wait_for(lambda: _file_count(probe) == 0, "the server to answer")
+    for n in range(3):
+        feed.put(_doc_rows(n * commit, (n + 1) * commit))
+        _wait_for(lambda: _file_count(probe) == (n + 1) * commit, f"commit {n + 1}")
+    steps = [
+        flight.args_of(s)
+        for s in flight.spans_between(lo, time.monotonic_ns())
+        if s[flight.S_NAME] == "engine.step"
+        and s[flight.S_THREAD] == thread.ident
+    ]
+    # the steps that took documents in are the three that hashed hundreds
+    doc_steps = [a for a in steps if a["json_hashes"] >= commit]
+    assert len(doc_steps) == 3, [a["json_hashes"] for a in steps]
+    for args in doc_steps:
+        assert commit <= args["json_hashes"] <= 3 * commit, args
+    # the commit after 1,024 costs what the first did
+    assert doc_steps[2]["json_hashes"] <= doc_steps[0]["json_hashes"] * 1.05
