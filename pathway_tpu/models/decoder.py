@@ -1,15 +1,29 @@
-"""Causal hybrid decoder: the answer model of the RAG plane.
+"""Causal decoder: the answer model of the RAG plane.
 
-The model family is ``granitemoehybrid`` (IBM Granite 4.0-H): a stack of
-blocks, each ``x += r * mixer(norm(x)); x += r * (moe(norm(x)) +
-shared(norm(x)))``, whose mixer is a Mamba-2 state-space layer or NoPE
-grouped-query attention by ``layer_types``, with routed experts beside
-an always-on shared MLP, and four scalar multipliers (embedding,
-residual, attention, logits). Everything a deployment divides over chips
-is told to this module, never assumed: which layers (``layer_types``),
-which experts (``experts_held``) and which rows of the tied vocabulary
-(``vocab_held``) live here. The router stays as wide as published and
-picks ``experts_per_token`` of all experts; this chip computes its own
+Two model families are written down here, as one stack of blocks ``x += r
+* mixer(norm(x)); x += r * ffn(norm(x))`` whose layers each name a mixer
+kind and a feed-forward kind:
+
+* ``granitemoehybrid`` (IBM Granite 4.0-H): the mixer is a Mamba-2
+  state-space layer or NoPE grouped-query attention by ``layer_types``;
+  every feed-forward is routed experts (the k best router logits,
+  softmax over those) beside an always-on shared MLP; a tied head; four
+  scalar multipliers (embedding, residual, attention, logits).
+* ``deepseek_v2`` (DeepSeek-V2): every mixer is multi-head latent
+  attention (``mla``: low-rank queries, one jointly compressed key/value
+  latent of ``kv_rank`` values and one decoupled rotary key of
+  ``rope_dim`` values a position, YaRN frequencies); the first
+  ``dense_layers`` feed-forwards are one dense MLP, the rest routed
+  experts under a group-limited router (softmax over all experts, the
+  best ``router_top_groups`` of ``router_groups`` groups, the k best of
+  those, gates not renormalised, times ``routed_scaling``) beside the
+  shared experts; an untied head; every multiplier 1.
+
+Everything a deployment divides over chips is told to this module, never
+assumed: which layers (``layer_types``), which experts
+(``experts_held``) and which rows of the vocabulary (``vocab_held``)
+live here. The router stays as wide as published and picks
+``experts_per_token`` of all experts; this chip computes its own
 experts' part and leaves out what the absent ones would add (on one chip
 the layer runs without its exchange).
 
@@ -31,10 +45,18 @@ grows with depth instead. Matrix operands are bfloat16 with
 float32 accumulation; the residual stream, every norm, softmax, gate and
 the SSM state are float32.
 
-``StateCache`` holds both kinds of per-sequence state side by side, by
-slot: constant-size (convolution tail, SSM state) for the Mamba layers,
-growing (keys/values) for the attention layers. ``AnswerModel`` is the
-host-facing object (prompt ids in, generated ids out) the chat UDF wraps.
+Latent attention has two paths over one cache row ``[c_kv after its
+norm | k_r after rotation]``: prefill expands keys and values from the
+latent rows through ``w_ukv``, a block of keys at a time (compute-bound);
+decode absorbs ``w_ukv``'s key half into the query and its value half
+into the output and attends over the latent rows themselves
+(bandwidth-bound). Neither holds scores over more than one block of keys.
+
+``StateCache`` holds the three kinds of per-sequence state side by side,
+by slot: constant-size (convolution tail, SSM state) for the Mamba
+layers, growing keys/values for the attention layers, growing latent rows
+for the MLA layers. ``AnswerModel`` is the host-facing object (prompt ids
+in, generated ids out) the chat UDF wraps.
 """
 
 from __future__ import annotations
@@ -60,19 +82,35 @@ from pathway_tpu.internals.device import (
 
 place_compile_cache()
 
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, ATTENTION, MLA = "mamba", "attention", "mla"      # mixer kinds
+MOE, DENSE = "moe", "dense"                              # feed-forward kinds
 DECODE_BUCKETS = (1, 2, 4, 8)
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """``rope_scaling`` of type ``yarn`` and ``rope_theta``, as published."""
+
+    theta: float = 10000.0
+    factor: float = 40.0
+    original_positions: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
-    """Published sizes, the share of them held here, and the serving
-    sizes (chunk, positions, slots)."""
+    """Published sizes of one of the two families written down here
+    (``granitemoehybrid``, ``deepseek_v2``; the defaults are Granite
+    4.0-H-small's), the share of them held here, and the serving sizes
+    (chunk, positions, slots)."""
 
     hidden: int = 4096
     layer_types: tuple = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
     vocab_size: int = 100352
-    vocab_held: tuple = (0, 100352)      # (first row, rows) of the tied table
+    vocab_held: tuple = (0, 100352)      # (first row, rows) of the table(s)
     heads: int = 32
     kv_heads: int = 8
     attention_multiplier: float = 1.0 / 128
@@ -94,11 +132,31 @@ class DecoderConfig:
     prefill_chunk: int = 512
     max_positions: int = 4096
     slots: int = 8
+    # what deepseek_v2 adds: the head, the leading dense layers, the router
+    tied_head: bool = True
+    dense_layers: int = 0                # leading layers whose feed-forward is one MLP
+    dense_width: int = 0
+    router_groups: int = 0               # 0: the k best logits, softmax over those
+    router_top_groups: int = 0
+    routed_scaling: float = 1.0
+    # latent attention (``mla`` layers)
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    rope: YarnRope | None = None
+    # latent rows a block of the decode path's attention reads: a serving
+    # constant, which only tests lower (to cross several blocks at toy lengths)
+    decode_block: int = 2048
 
     def __post_init__(self):
+        kinds = set(self.layer_types)
+        if not kinds <= {MAMBA, ATTENTION, MLA}:
+            raise ValueError(f"layer_types {sorted(kinds)}: only mamba, attention, mla")
         if self.mamba_groups != 1:
             raise ValueError("only mamba_n_groups == 1 is written down here")
-        if self.prefill_chunk % self.mamba_chunk:
+        if MAMBA in kinds and self.prefill_chunk % self.mamba_chunk:
             raise ValueError("prefill_chunk must be whole Mamba chunks")
         if self.max_positions % self.prefill_chunk:
             raise ValueError("max_positions must be whole prefill chunks")
@@ -112,6 +170,18 @@ class DecoderConfig:
         first, rows = self.vocab_held
         if first < 0 or rows < 1 or first + rows > self.vocab_size:
             raise ValueError(f"vocab_held {self.vocab_held} outside 0..{self.vocab_size}")
+        if not 0 <= self.dense_layers < len(self.layer_types):
+            raise ValueError("dense_layers must leave an expert layer")
+        if self.router_groups and (
+                self.experts % self.router_groups
+                or not 1 <= self.router_top_groups <= self.router_groups):
+            raise ValueError("router groups must divide the experts, top groups be among them")
+        if MLA in kinds:
+            if self.rope is None or min(
+                    self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim, self.v_dim) < 1:
+                raise ValueError("mla layers need q_rank, kv_rank, nope/rope/v dims and rope")
+            if self.rope_dim % 2 or self.max_positions % self.decode_rows:
+                raise ValueError("rope_dim must be even, max_positions whole decode blocks")
 
     # derived widths
     @property
@@ -130,36 +200,49 @@ class DecoderConfig:
     def in_proj_width(self) -> int:
         return self.mamba_inner + self.conv_width + self.mamba_heads
 
+    @property
+    def latent_width(self) -> int:
+        """One cache row of an MLA layer: ``[c_kv | k_r]``."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def decode_rows(self) -> int:
+        return min(self.decode_block, self.max_positions)
+
+    @property
+    def ffn_types(self) -> tuple:
+        n = len(self.layer_types)
+        return (DENSE,) * self.dense_layers + (MOE,) * (n - self.dense_layers)
+
+    @property
+    def layers(self) -> tuple:
+        """(mixer kind, feed-forward kind) a layer."""
+        return tuple(zip(self.layer_types, self.ffn_types))
+
+    @property
+    def expert_layers(self) -> int:
+        return len(self.layer_types) - self.dense_layers
+
     @classmethod
     def from_hf(cls, hf: dict, *, layers: int | None = None,
                 experts_held: tuple | None = None,
                 vocab_held: tuple | None = None, **serving) -> "DecoderConfig":
-        """From a ``granitemoehybrid`` ``config.json``. ``layers`` keeps
-        the first that many of ``layer_types``; ``experts_held`` and
-        ``vocab_held`` give this chip's share (default: everything)."""
-        types = tuple(hf["layer_types"])[: layers or hf["num_hidden_layers"]]
-        if hf.get("position_embedding_type", "nope") != "nope":
-            raise ValueError("only position_embedding_type 'nope' is written down here")
-        if hf["mamba_expand"] * hf["hidden_size"] != hf["mamba_n_heads"] * hf["mamba_d_head"]:
-            raise ValueError("mamba_expand x hidden_size != mamba_n_heads x mamba_d_head")
+        """From a ``config.json`` of ``model_type`` ``granitemoehybrid``
+        (also when the key is missing) or ``deepseek_v2``; any other is
+        refused. ``layers`` keeps the first that many; ``experts_held``
+        and ``vocab_held`` give this chip's share (default: everything)."""
+        model_type = hf.get("model_type", "granitemoehybrid")
+        if model_type not in _FROM_HF:
+            raise ValueError(
+                f"model_type {model_type!r} is not written down here (has: {sorted(_FROM_HF)})")
+        fields, experts_key = _FROM_HF[model_type](hf, layers or hf["num_hidden_layers"])
         return cls(
-            hidden=hf["hidden_size"], layer_types=types,
-            vocab_size=hf["vocab_size"],
+            hidden=hf["hidden_size"], vocab_size=hf["vocab_size"],
             vocab_held=tuple(vocab_held or (0, hf["vocab_size"])),
             heads=hf["num_attention_heads"], kv_heads=hf["num_key_value_heads"],
-            attention_multiplier=hf["attention_multiplier"],
-            mamba_heads=hf["mamba_n_heads"], mamba_head_dim=hf["mamba_d_head"],
-            mamba_state=hf["mamba_d_state"], mamba_groups=hf["mamba_n_groups"],
-            mamba_conv=hf["mamba_d_conv"], mamba_chunk=hf["mamba_chunk_size"],
-            experts=hf["num_local_experts"],
-            experts_per_token=hf["num_experts_per_tok"],
-            experts_held=tuple(experts_held or (0, hf["num_local_experts"])),
-            expert_width=hf["intermediate_size"],
-            shared_width=hf["shared_intermediate_size"],
-            embedding_multiplier=hf["embedding_multiplier"],
-            residual_multiplier=hf["residual_multiplier"],
-            logits_scaling=hf["logits_scaling"], rms_eps=hf["rms_norm_eps"],
-            **serving,
+            experts=hf[experts_key], experts_per_token=hf["num_experts_per_tok"],
+            experts_held=tuple(experts_held or (0, hf[experts_key])),
+            rms_eps=hf["rms_norm_eps"], **fields, **serving,
         )
 
     @classmethod
@@ -175,21 +258,97 @@ class DecoderConfig:
             shared_width=32, prefill_chunk=16, max_positions=64, slots=4,
         )
 
+    @classmethod
+    def tiny_mla(cls) -> "DecoderConfig":
+        """``tiny``'s DeepSeek-shaped sibling: a dense layer then two
+        expert layers, all latent attention (4 heads of 8 + 4, latent 16 +
+        4), 16 experts in 4 groups, the best 2 groups, top-3, one share of
+        4 experts (a whole group), a 64-row slice, an untied head."""
+        return cls(
+            hidden=32, layer_types=(MLA,) * 3, vocab_size=128, vocab_held=(0, 64),
+            heads=4, kv_heads=4, experts=16, experts_per_token=3, experts_held=(0, 4),
+            expert_width=16, shared_width=32, embedding_multiplier=1.0,
+            residual_multiplier=1.0, logits_scaling=1.0, rms_eps=1e-6,
+            prefill_chunk=16, max_positions=64, slots=4, tied_head=False,
+            dense_layers=1, dense_width=48, router_groups=4, router_top_groups=2,
+            routed_scaling=4.0, q_rank=24, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+            rope=YarnRope(factor=4.0, original_positions=16, beta_fast=4.0),
+            decode_block=16,
+        )
+
+
+def _granite_fields(hf: dict, layers: int) -> tuple[dict, str]:
+    if hf.get("position_embedding_type", "nope") != "nope":
+        raise ValueError("only position_embedding_type 'nope' is written down here")
+    if hf["mamba_expand"] * hf["hidden_size"] != hf["mamba_n_heads"] * hf["mamba_d_head"]:
+        raise ValueError("mamba_expand x hidden_size != mamba_n_heads x mamba_d_head")
+    return {
+        "layer_types": tuple(hf["layer_types"])[:layers],
+        "attention_multiplier": hf["attention_multiplier"],
+        "mamba_heads": hf["mamba_n_heads"], "mamba_head_dim": hf["mamba_d_head"],
+        "mamba_state": hf["mamba_d_state"], "mamba_groups": hf["mamba_n_groups"],
+        "mamba_conv": hf["mamba_d_conv"], "mamba_chunk": hf["mamba_chunk_size"],
+        "expert_width": hf["intermediate_size"],
+        "shared_width": hf["shared_intermediate_size"],
+        "embedding_multiplier": hf["embedding_multiplier"],
+        "residual_multiplier": hf["residual_multiplier"],
+        "logits_scaling": hf["logits_scaling"],
+    }, "num_local_experts"
+
+
+def _deepseek_v2_fields(hf: dict, layers: int) -> tuple[dict, str]:
+    scaling = hf.get("rope_scaling") or {}
+    if scaling.get("type") != "yarn":
+        raise ValueError("deepseek_v2: only rope_scaling of type 'yarn' is written down here")
+    if (hf.get("topk_method"), hf.get("scoring_func"), hf.get("norm_topk_prob")) != (
+            "group_limited_greedy", "softmax", False) or hf.get("moe_layer_freq", 1) != 1:
+        raise ValueError(
+            "deepseek_v2: only the group_limited_greedy softmax router with gates not "
+            "renormalised, on every layer past the dense ones, is written down here")
+    if not hf.get("q_lora_rank"):
+        raise ValueError("deepseek_v2: only low-rank queries (q_lora_rank) are written down here")
+    return {
+        "layer_types": (MLA,) * layers,
+        "tied_head": bool(hf.get("tie_word_embeddings", False)),
+        "dense_layers": min(hf["first_k_dense_replace"], layers),
+        "dense_width": hf["intermediate_size"],
+        "expert_width": hf["moe_intermediate_size"],
+        "shared_width": hf["n_shared_experts"] * hf["moe_intermediate_size"],
+        "router_groups": hf["n_group"], "router_top_groups": hf["topk_group"],
+        "routed_scaling": float(hf["routed_scaling_factor"]),
+        "q_rank": hf["q_lora_rank"], "kv_rank": hf["kv_lora_rank"],
+        "nope_dim": hf["qk_nope_head_dim"], "rope_dim": hf["qk_rope_head_dim"],
+        "v_dim": hf["v_head_dim"],
+        "rope": YarnRope(
+            theta=float(hf["rope_theta"]), factor=float(scaling["factor"]),
+            original_positions=int(scaling["original_max_position_embeddings"]),
+            beta_fast=float(scaling["beta_fast"]), beta_slow=float(scaling["beta_slow"]),
+            mscale=float(scaling["mscale"]), mscale_all_dim=float(scaling["mscale_all_dim"])),
+        "embedding_multiplier": 1.0, "residual_multiplier": 1.0, "logits_scaling": 1.0,
+        "attention_multiplier": 0.0,     # MLA's scale comes from its dims and YaRN
+    }, "n_routed_experts"
+
+
+_FROM_HF = {"granitemoehybrid": _granite_fields, "deepseek_v2": _deepseek_v2_fields}
+
 
 # -- parameters ------------------------------------------------------------------
 
 
-def layer_shapes(cfg: DecoderConfig, kind: str) -> dict[str, tuple]:
-    """Leaf name -> shape of one layer of ``kind``."""
+def layer_shapes(cfg: DecoderConfig, kind: str, ffn: str = MOE) -> dict[str, tuple]:
+    """Leaf name -> shape of one layer of mixer ``kind`` and feed-forward ``ffn``."""
     h, held = cfg.hidden, cfg.experts_held[1]
-    block = {
-        "norm1": (h,), "norm2": (h,),
-        "router": (h, cfg.experts),
-        "shared_in": (h, 2 * cfg.shared_width),
-        "shared_out": (cfg.shared_width, h),
-        "experts_in": (held, h, 2 * cfg.expert_width),
-        "experts_out": (held, cfg.expert_width, h),
-    }
+    block = {"norm1": (h,), "norm2": (h,)}
+    if ffn == MOE:
+        block.update({
+            "router": (h, cfg.experts),
+            "shared_in": (h, 2 * cfg.shared_width),
+            "shared_out": (cfg.shared_width, h),
+            "experts_in": (held, h, 2 * cfg.expert_width),
+            "experts_out": (held, cfg.expert_width, h),
+        })
+    else:
+        block.update({"mlp_in": (h, 2 * cfg.dense_width), "mlp_out": (cfg.dense_width, h)})
     if kind == MAMBA:
         block.update({
             "in_proj": (h, cfg.in_proj_width),
@@ -200,27 +359,35 @@ def layer_shapes(cfg: DecoderConfig, kind: str) -> dict[str, tuple]:
             "mixer_norm": (cfg.mamba_inner,),
             "out_proj": (cfg.mamba_inner, h),
         })
-    else:
+    elif kind == ATTENTION:
         qd, kvd = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
         block.update({"wq": (h, qd), "wk": (h, kvd), "wv": (h, kvd), "wo": (qd, h)})
+    else:
+        block.update({
+            "w_dq": (h, cfg.q_rank), "q_norm": (cfg.q_rank,),
+            "w_uq": (cfg.q_rank, cfg.heads * (cfg.nope_dim + cfg.rope_dim)),
+            "w_dkv": (h, cfg.latent_width), "kv_norm": (cfg.kv_rank,),
+            # a head's columns are [k_nope | v], as the published kv_b_proj's rows
+            "w_ukv": (cfg.kv_rank, cfg.heads * (cfg.nope_dim + cfg.v_dim)),
+            "wo": (cfg.heads * cfg.v_dim, h),
+        })
     return block
 
 
-_F32_LEAVES = frozenset(
-    ("norm1", "norm2", "mixer_norm", "conv_w", "conv_b", "dt_bias", "A_log", "D")
-)
+_NORM_LEAVES = frozenset(("norm1", "norm2", "mixer_norm", "q_norm", "kv_norm"))
+_F32_LEAVES = _NORM_LEAVES | frozenset(("conv_w", "conv_b", "dt_bias", "A_log", "D"))
 
 
-def init_layer(cfg: DecoderConfig, kind: str, key) -> dict:
+def init_layer(cfg: DecoderConfig, kind: str, key, ffn: str = MOE) -> dict:
     """One layer's weights from its key: matrices N(0, 0.02) in bfloat16;
     norm scales 1 + N(0, 0.02); Mamba's own conventions for the rest
     (``A_log`` = log U(1, 16), ``dt_bias`` = softplus^-1 of a step size
     log-uniform in [1e-3, 1e-1], ``D`` = 1, convolution U(-1/2, 1/2) with
     bias, which is Conv1d's default at fan-in 4)."""
     out = {}
-    for i, (name, shape) in enumerate(sorted(layer_shapes(cfg, kind).items())):
+    for i, (name, shape) in enumerate(sorted(layer_shapes(cfg, kind, ffn).items())):
         k = jax.random.fold_in(key, i)
-        if name in ("norm1", "norm2", "mixer_norm"):
+        if name in _NORM_LEAVES:
             leaf = 1.0 + 0.02 * jax.random.normal(k, shape, jnp.float32)
         elif name == "A_log":
             leaf = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0))
@@ -240,30 +407,37 @@ def init_layer(cfg: DecoderConfig, kind: str, key) -> dict:
 def init_params(cfg: DecoderConfig, seed: int = 0) -> dict:
     """Random parameters, each layer from its own key (a reference can
     then make one layer at a time): ``{"embed", "final_norm", "layers":
-    [one tree a layer]}``."""
+    [one tree a layer]}``, and ``"head"`` where the head is not tied."""
     root = jax.random.PRNGKey(seed)
     make = {
-        kind: jax.jit(functools.partial(init_layer, cfg, kind))
-        for kind in (MAMBA, ATTENTION)
+        layer: jax.jit(functools.partial(init_layer, cfg, layer[0], ffn=layer[1]))
+        for layer in set(cfg.layers)
     }
-    k_embed = jax.random.fold_in(root, 1_000_000)
     rows = cfg.vocab_held[1]
-    return {
-        "embed": (0.02 * jax.random.normal(k_embed, (rows, cfg.hidden), jnp.float32)
-                  ).astype(jnp.bfloat16),
+
+    def table(stream):
+        k = jax.random.fold_in(root, stream)
+        return (0.02 * jax.random.normal(k, (rows, cfg.hidden), jnp.float32)).astype(jnp.bfloat16)
+
+    params = {
+        "embed": table(1_000_000),
         "final_norm": jnp.ones((cfg.hidden,), jnp.float32),
         "layers": [
-            make[kind](jax.random.fold_in(root, i)) for i, kind in enumerate(cfg.layer_types)
+            make[layer](jax.random.fold_in(root, i)) for i, layer in enumerate(cfg.layers)
         ],
     }
+    if not cfg.tied_head:
+        params["head"] = table(1_000_001)
+    return params
 
 
 @functools.lru_cache(maxsize=None)
 def param_bytes(cfg: DecoderConfig) -> float:
     """HBM bytes of the held parameters (bfloat16 matrices, float32 vectors)."""
-    total = 2.0 * cfg.vocab_held[1] * cfg.hidden + 4.0 * cfg.hidden
-    for kind in cfg.layer_types:
-        for name, shape in layer_shapes(cfg, kind).items():
+    tables = 1 if cfg.tied_head else 2
+    total = tables * 2.0 * cfg.vocab_held[1] * cfg.hidden + 4.0 * cfg.hidden
+    for kind, ffn in cfg.layers:
+        for name, shape in layer_shapes(cfg, kind, ffn).items():
             total += (4.0 if name in _F32_LEAVES else 2.0) * float(np.prod(shape))
     return total
 
@@ -271,14 +445,15 @@ def param_bytes(cfg: DecoderConfig) -> float:
 def cache_bytes(cfg: DecoderConfig) -> float:
     """HBM bytes of the state cache: ``slots`` + 1 (the scratch slot padded
     decode rows write to) of convolution tail (bfloat16) and SSM state
-    (float32) a Mamba layer, keys and values (bfloat16) an attention layer."""
-    mamba = sum(k == MAMBA for k in cfg.layer_types)
-    attn = len(cfg.layer_types) - mamba
-    slot = mamba * (
-        2.0 * (cfg.mamba_conv - 1) * cfg.conv_width
-        + 4.0 * cfg.mamba_heads * cfg.mamba_head_dim * cfg.mamba_state
-    ) + attn * 2 * 2.0 * cfg.max_positions * cfg.kv_heads * cfg.head_dim
-    return (cfg.slots + 1) * slot
+    (float32) a Mamba layer, keys and values (bfloat16) an attention
+    layer, latent rows (bfloat16) an MLA layer."""
+    per_kind = {
+        MAMBA: 2.0 * (cfg.mamba_conv - 1) * cfg.conv_width
+        + 4.0 * cfg.mamba_heads * cfg.mamba_head_dim * cfg.mamba_state,
+        ATTENTION: 2 * 2.0 * cfg.max_positions * cfg.kv_heads * cfg.head_dim,
+        MLA: 2.0 * cfg.max_positions * cfg.latent_width,
+    }
+    return (cfg.slots + 1) * sum(per_kind[kind] for kind in cfg.layer_types)
 
 
 # -- layers ------------------------------------------------------------------------
@@ -304,10 +479,24 @@ def glu(a, width: int):
 def route(cfg: DecoderConfig, p: dict, u, live):
     """Router over ALL experts: (selected ids [T, k], gates [T, k] float32,
     held [T, k]: the selection is one of this chip's experts and the row
-    is live)."""
+    is live). Without router groups: the k best logits, gates their
+    softmax. With them (group-limited greedy): scores are the softmax over
+    all experts, a group's score its best expert's, only the
+    ``router_top_groups`` best groups' experts stand, the k best of those
+    are selected, gates their scores times ``routed_scaling``."""
     logits = _mm(u, p["router"])
-    top, sel = jax.lax.top_k(logits, cfg.experts_per_token)
-    gates = jax.nn.softmax(top, axis=-1)
+    if cfg.router_groups:
+        scores = jax.nn.softmax(logits, axis=-1)
+        T, G = scores.shape[0], cfg.router_groups
+        _, best = jax.lax.top_k(
+            jnp.max(scores.reshape(T, G, -1), axis=-1), cfg.router_top_groups)
+        stands = jnp.any(best[:, :, None] == jnp.arange(G)[None, None, :], axis=1)
+        stands = jnp.repeat(stands, cfg.experts // G, axis=1)
+        top, sel = jax.lax.top_k(jnp.where(stands, scores, 0.0), cfg.experts_per_token)
+        gates = cfg.routed_scaling * top
+    else:
+        top, sel = jax.lax.top_k(logits, cfg.experts_per_token)
+        gates = jax.nn.softmax(top, axis=-1)
     first, n_held = cfg.experts_held
     held = (sel >= first) & (sel < first + n_held) & live[:, None]
     return sel, gates, held
@@ -340,6 +529,13 @@ def routed_experts(cfg: DecoderConfig, p: dict, u, sel, gates, held):
 
 def shared_mlp(cfg: DecoderConfig, p: dict, u):
     return _mm(glu(_mm(u, p["shared_in"]), cfg.shared_width), p["shared_out"])
+
+
+def dense_block(cfg: DecoderConfig, p: dict, x):
+    """x + r * mlp(norm2(x)): the feed-forward of a leading dense layer."""
+    u = rms_norm(x, p["norm2"], cfg.rms_eps)
+    return x + cfg.residual_multiplier * _mm(
+        glu(_mm(u, p["mlp_in"]), cfg.dense_width), p["mlp_out"])
 
 
 def moe_block(cfg: DecoderConfig, p: dict, x, live):
@@ -480,10 +676,164 @@ def attention_decode(cfg: DecoderConfig, p: dict, u, keys, values, pos):
     return _mm(out, p["wo"]), keys, values
 
 
+# -- latent attention ---------------------------------------------------------------
+
+
+def yarn_inv_freq(cfg: DecoderConfig) -> np.ndarray:
+    """The ``rope_dim`` / 2 rotary frequencies under YaRN: pairs that turn
+    more than ``beta_fast`` times over the original positions keep their
+    frequency, those that turn less than ``beta_slow`` times are divided
+    by ``factor``, a linear ramp between (float64)."""
+    r, d = cfg.rope, cfg.rope_dim
+    freq = r.theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def dim_of(turns):        # the dim at which a pair makes ``turns`` turns
+        return d * np.log(r.original_positions / (turns * 2 * np.pi)) / (2 * np.log(r.theta))
+
+    low = max(np.floor(dim_of(r.beta_fast)), 0)
+    high = min(np.ceil(dim_of(r.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return freq / r.factor * ramp + freq * (1 - ramp)
+
+
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def mla_scale(cfg: DecoderConfig) -> float:
+    """Softmax scale: (nope + rope)^-1/2 times mscale(factor, mscale_all_dim)^2."""
+    return float((cfg.nope_dim + cfg.rope_dim) ** -0.5
+                 * _yarn_mscale(cfg.rope.factor, cfg.rope.mscale_all_dim) ** 2)
+
+
+def _rotate(cfg: DecoderConfig, x, positions):
+    """Rotary embedding of ``x`` [.., rope_dim] at ``positions`` (shaped as
+    x's leading dims, or broadcastable to them): the published code's
+    pairing (dims 2i and 2i + 1 turn together; the result holds the first
+    of every pair, then the second), cos and sin scaled by mscale(factor,
+    mscale) / mscale(factor, mscale_all_dim)."""
+    r = cfg.rope
+    angles = positions[..., None].astype(_f32) * jnp.asarray(yarn_inv_freq(cfg), _f32)
+    m = _yarn_mscale(r.factor, r.mscale) / _yarn_mscale(r.factor, r.mscale_all_dim)
+    cos, sin = jnp.cos(angles) * m, jnp.sin(angles) * m
+    a, b = x[..., 0::2].astype(_f32), x[..., 1::2].astype(_f32)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _mla_project(cfg: DecoderConfig, p: dict, u, positions):
+    """(q_nope [.., heads, nope], q_rope [.., heads, rope] rotated, cache
+    rows [.., kv_rank + rope] = [c_kv after its norm | k_r rotated]), all
+    bfloat16."""
+    lead = u.shape[:-1]
+    c_q = rms_norm(_mm(u, p["w_dq"]), p["q_norm"], cfg.rms_eps)
+    q = _mm(c_q, p["w_uq"]).reshape(*lead, cfg.heads, cfg.nope_dim + cfg.rope_dim)
+    q_rope = _rotate(cfg, q[..., cfg.nope_dim:], positions[..., None])
+    down = _mm(u, p["w_dkv"])
+    c_kv = rms_norm(down[..., :cfg.kv_rank], p["kv_norm"], cfg.rms_eps)
+    k_r = _rotate(cfg, down[..., cfg.kv_rank:], positions)
+    rows = jnp.concatenate([c_kv, k_r], axis=-1).astype(_bf16)
+    return q[..., :cfg.nope_dim].astype(_bf16), q_rope.astype(_bf16), rows
+
+
+_MASKED = -1e30     # a masked score: far below any real one, and exp() of it is 0
+
+
+def _softmax_step(carry, s, visible, weigh):
+    """One block of an attention computed a block of keys at a time:
+    ``carry`` = (running max [..], sum [..], weighted values [.., d]);
+    ``s`` [.., keys] float32 scores; ``weigh(p)`` the block's values
+    weighted by p [.., keys] (bfloat16)."""
+    m, l, acc = carry
+    s = jnp.where(visible, s, _MASKED)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    p = jnp.exp(s - m_new[..., None])
+    fade = jnp.exp(m - m_new)
+    return (m_new, l * fade + jnp.sum(p, axis=-1),
+            acc * fade[..., None] + weigh(p.astype(_bf16)))
+
+
+def mla_prefill(cfg: DecoderConfig, p: dict, u, latent, slot, pos, n):
+    """Latent attention over one chunk at positions ``pos``.., the
+    expanded path: the chunk's cache rows go into ``latent`` [slots + 1,
+    positions, kv_rank + rope] at ``slot`` (a padded position writes
+    nothing); then, a chunk-sized block of cached rows at a time up to
+    the chunk's own, keys and values are expanded from the latent rows
+    through ``w_ukv`` and the chunk's queries attend to them causally.
+    Returns (out [T, hidden], latent)."""
+    T, H, rk = u.shape[0], cfg.heads, cfg.kv_rank
+    q_nope, q_rope, rows = _mla_project(cfg, p, u, pos + jnp.arange(T))
+    old = jax.lax.dynamic_slice(latent, (slot, pos, 0), (1, T, cfg.latent_width))
+    real = (jnp.arange(T) < n)[None, :, None]
+    latent = jax.lax.dynamic_update_slice(
+        latent, jnp.where(real, rows[None], old), (slot, pos, 0))
+    scale = mla_scale(cfg)
+
+    def block(b, carry):
+        rows_b = jax.lax.dynamic_slice(
+            latent, (slot, b * T, 0), (1, T, cfg.latent_width))[0]
+        kv = _mm(rows_b[:, :rk], p["w_ukv"]).astype(_bf16).reshape(
+            T, H, cfg.nope_dim + cfg.v_dim)
+        s = jnp.einsum("thd,phd->htp", q_nope, kv[..., :cfg.nope_dim],
+                       preferred_element_type=_f32)
+        s = s + jnp.einsum("thr,pr->htp", q_rope, rows_b[:, rk:],
+                           preferred_element_type=_f32)
+        visible = (b * T + jnp.arange(T))[None, :] <= (pos + jnp.arange(T))[:, None]
+        return _softmax_step(
+            carry, s * scale, visible[None],
+            lambda w: jnp.einsum("htp,phd->htd", w, kv[..., cfg.nope_dim:],
+                                 preferred_element_type=_f32))
+
+    start = (jnp.full((H, T), _MASKED, _f32), jnp.zeros((H, T), _f32),
+             jnp.zeros((H, T, cfg.v_dim), _f32))
+    _, l, acc = jax.lax.fori_loop(0, pos // T + 1, block, start)
+    out = jnp.transpose(acc / l[..., None], (1, 0, 2)).reshape(T, H * cfg.v_dim)
+    return _mm(out, p["wo"]), latent
+
+
+def mla_decode(cfg: DecoderConfig, p: dict, u, latent, slots, pos):
+    """One token a sequence, the absorbed path: ``w_ukv``'s key half goes
+    into the query (``q~ = q_nope W_UK^T``, kv_rank wide) and its value
+    half onto the output, so the scores and the weighted sum run over the
+    latent rows themselves, ``decode_rows`` of them at a time up to the
+    batch's longest context. ``u`` [B, hidden]; ``latent`` the whole
+    slab; ``slots``, ``pos`` [B]. Returns (out [B, hidden], latent)."""
+    B, H, rk, R = u.shape[0], cfg.heads, cfg.kv_rank, cfg.decode_rows
+    q_nope, q_rope, rows = _mla_project(cfg, p, u, pos)
+    for i in range(B):      # a row at a time: a scatter of B rows copied the whole slab
+        latent = jax.lax.dynamic_update_slice(
+            latent, rows[i][None, None], (slots[i], pos[i], 0))
+    w = p["w_ukv"].reshape(rk, H, cfg.nope_dim + cfg.v_dim)
+    q_abs = jnp.einsum("bhd,chd->bhc", q_nope, w[..., :cfg.nope_dim],
+                       preferred_element_type=_f32).astype(_bf16)
+    scale = mla_scale(cfg)
+
+    def block(b, carry):
+        rows_b = jax.vmap(lambda s: jax.lax.dynamic_slice(
+            latent, (s, b * R, 0), (1, R, cfg.latent_width))[0])(slots)      # [B, R, row]
+        s = jnp.einsum("bhc,bpc->bhp", q_abs, rows_b[..., :rk], preferred_element_type=_f32)
+        s = s + jnp.einsum("bhr,bpr->bhp", q_rope, rows_b[..., rk:],
+                           preferred_element_type=_f32)
+        visible = (b * R + jnp.arange(R))[None, :] <= pos[:, None]
+        return _softmax_step(
+            carry, s * scale, visible[:, None, :],
+            lambda w_: jnp.einsum("bhp,bpc->bhc", w_, rows_b[..., :rk],
+                                  preferred_element_type=_f32))
+
+    start = (jnp.full((B, H), _MASKED, _f32), jnp.zeros((B, H), _f32),
+             jnp.zeros((B, H, rk), _f32))
+    _, l, acc = jax.lax.fori_loop(0, jnp.max(pos) // R + 1, block, start)
+    mixed = (acc / l[..., None]).astype(_bf16)
+    out = jnp.einsum("bhc,chd->bhd", mixed, w[..., cfg.nope_dim:],
+                     preferred_element_type=_f32)
+    return _mm(out.reshape(B, H * cfg.v_dim), p["wo"]), latent
+
+
 def _logits(cfg: DecoderConfig, params: dict, x):
-    """Final norm, tied head over the held rows, ``logits_scaling``."""
+    """Final norm, the head over the held rows (the embedding table
+    where it is tied), ``logits_scaling``."""
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    return jnp.dot(x.astype(_bf16), params["embed"].T,
+    table = params["embed"] if cfg.tied_head else params["head"]
+    return jnp.dot(x.astype(_bf16), table.T,
                    preferred_element_type=_f32) / cfg.logits_scaling
 
 
@@ -503,27 +853,35 @@ def prefill_chunk(cfg: DecoderConfig, params: dict, state: list, slot, ids, pos,
     """One chunk of one sequence through every layer. ``state``: the
     cache's arrays, a dict a layer, ``[slots + 1, ...]`` each. Returns
     (state, greedy next id, logits [held rows] at the chunk's last real
-    position, counts: ``sel`` [layers, T, k], ``counts`` [layers, held
-    experts], ``absent`` [layers])."""
+    position, counts, a row an expert layer: ``sel`` [expert layers, T,
+    k], ``counts`` [expert layers, held experts], ``absent`` [expert
+    layers])."""
     x = _embed(cfg, params, ids)
     live = jnp.arange(ids.shape[0]) < n
     r = cfg.residual_multiplier
     new_state, counted = [], []
-    for kind, p, s in zip(cfg.layer_types, params["layers"], state):
+    for (kind, ffn), p, s in zip(cfg.layers, params["layers"], state):
         u = rms_norm(x, p["norm1"], cfg.rms_eps)
-        mine = {nm: jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False)
-                for nm, a in s.items()}
-        if kind == MAMBA:
-            out, tail, ssm = mamba_prefill(cfg, p, u, mine["tail"], mine["ssm"], n)
-            mine = {"tail": tail, "ssm": ssm}
+        if kind == MLA:     # reads and writes its slot's rows inside the slab
+            out, latent = mla_prefill(cfg, p, u, s["latent"], slot, pos, n)
         else:
-            out, keys, values = attention_prefill(
-                cfg, p, u, mine["keys"], mine["values"], pos, n)
-            mine = {"keys": keys, "values": values}
-        x, counts = moe_block(cfg, p, x + r * out, live)
-        new_state.append({nm: jax.lax.dynamic_update_index_in_dim(s[nm], new, slot, 0)
-                          for nm, new in mine.items()})
-        counted.append(counts)
+            mine = {nm: jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False)
+                    for nm, a in s.items()}
+            if kind == MAMBA:
+                out, tail, ssm = mamba_prefill(cfg, p, u, mine["tail"], mine["ssm"], n)
+                mine = {"tail": tail, "ssm": ssm}
+            else:
+                out, keys, values = attention_prefill(
+                    cfg, p, u, mine["keys"], mine["values"], pos, n)
+                mine = {"keys": keys, "values": values}
+        if ffn == MOE:
+            x, counts = moe_block(cfg, p, x + r * out, live)
+            counted.append(counts)
+        else:
+            x = dense_block(cfg, p, x + r * out)
+        new_state.append({"latent": latent} if kind == MLA else {
+            nm: jax.lax.dynamic_update_index_in_dim(s[nm], new, slot, 0)
+            for nm, new in mine.items()})
     last = jax.lax.dynamic_index_in_dim(x, n - 1, 0, keepdims=False)
     logits = _logits(cfg, params, last)
     return new_state, jnp.argmax(logits).astype(jnp.int32), logits, _stacked(counted)
@@ -537,18 +895,24 @@ def decode_step(cfg: DecoderConfig, params: dict, state: list, slots, ids, pos, 
     x = _embed(cfg, params, ids)
     r = cfg.residual_multiplier
     new_state, counted = [], []
-    for kind, p, s in zip(cfg.layer_types, params["layers"], state):
+    for (kind, ffn), p, s in zip(cfg.layers, params["layers"], state):
         u = rms_norm(x, p["norm1"], cfg.rms_eps)
-        if kind == MAMBA:
+        if kind == MLA:
+            out, latent = mla_decode(cfg, p, u, s["latent"], slots, pos)
+        elif kind == MAMBA:
             out, tail, ssm = mamba_decode(cfg, p, u, s["tail"][slots], s["ssm"][slots])
             mine = {"tail": tail, "ssm": ssm}
         else:
             out, keys, values = attention_decode(
                 cfg, p, u, s["keys"][slots], s["values"][slots], pos)
             mine = {"keys": keys, "values": values}
-        x, counts = moe_block(cfg, p, x + r * out, live)
-        new_state.append({nm: s[nm].at[slots].set(new) for nm, new in mine.items()})
-        counted.append(counts)
+        if ffn == MOE:
+            x, counts = moe_block(cfg, p, x + r * out, live)
+            counted.append(counts)
+        else:
+            x = dense_block(cfg, p, x + r * out)
+        new_state.append({"latent": latent} if kind == MLA else {
+            nm: s[nm].at[slots].set(new) for nm, new in mine.items()})
     logits = _logits(cfg, params, x)
     return (new_state, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits,
             _stacked(counted))
@@ -566,9 +930,11 @@ def empty_state(cfg: DecoderConfig) -> list:
                 "ssm": jnp.zeros(
                     (S, cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state), _f32),
             })
-        else:
+        elif kind == ATTENTION:
             shape = (S, cfg.max_positions, cfg.kv_heads, cfg.head_dim)
             out.append({"keys": jnp.zeros(shape, _bf16), "values": jnp.zeros(shape, _bf16)})
+        else:
+            out.append({"latent": jnp.zeros((S, cfg.max_positions, cfg.latent_width), _bf16)})
     return out
 
 
@@ -583,14 +949,17 @@ def _zero_slot(state: list, slot):
 # -- cost models (device sites) -------------------------------------------------------
 
 
-def _layer_matrix_params(cfg: DecoderConfig, kind: str, experts: float) -> float:
+def _layer_matrix_params(cfg: DecoderConfig, kind: str, experts: float,
+                         ffn: str = MOE) -> float:
     """Matrix parameters one token multiplies through in one layer, with
-    ``experts`` routed experts a token."""
-    shapes = layer_shapes(cfg, kind)
+    ``experts`` routed experts a token where the layer has them."""
+    shapes = layer_shapes(cfg, kind, ffn)
     dense = sum(
         float(np.prod(s)) for name, s in shapes.items()
         if len(s) == 2 and name != "conv_w"
     )
+    if ffn != MOE:
+        return dense
     per_expert = float(np.prod(shapes["experts_in"][1:]) + np.prod(shapes["experts_out"][1:]))
     return dense + experts * per_expert
 
@@ -599,9 +968,10 @@ def _layer_matrix_params(cfg: DecoderConfig, kind: str, experts: float) -> float
 def flops_per_token(cfg: DecoderConfig) -> float:
     """Forward FLOPs of one token through the held share: two a matrix
     parameter, the routed experts at this chip's expected share of the
-    ``experts_per_token`` selections; the head is not included."""
+    ``experts_per_token`` selections; the head, and attention over the
+    context, are not included."""
     share = cfg.experts_per_token * cfg.experts_held[1] / cfg.experts
-    return 2.0 * sum(_layer_matrix_params(cfg, k, share) for k in cfg.layer_types)
+    return 2.0 * sum(_layer_matrix_params(cfg, k, share, f) for k, f in cfg.layers)
 
 
 def prefill_cost_model(cfg: DecoderConfig) -> tuple[float, float]:
@@ -623,7 +993,7 @@ device_site(
     dtypes=("int32", "bfloat16", "float32"),
     where="pathway_tpu/models/decoder.py:AnswerModel._prefill_prompt",
     donates=("state",),
-    description="one fixed chunk of one prompt through the hybrid decoder, "
+    description="one fixed chunk of one prompt through the decoder, "
                 "state carried through the cache slot",
 )
 
@@ -641,9 +1011,10 @@ device_site(
 
 
 class StateCache:
-    """Per-sequence state of both kinds, by slot: convolution tail and
-    SSM state for each Mamba layer (constant in length), keys/values for
-    each attention layer (growing, up to ``max_positions``). ``acquire``
+    """Per-sequence state of the three kinds, by slot: convolution tail
+    and SSM state for each Mamba layer (constant in length), keys/values
+    for each attention layer and latent rows for each MLA layer (growing,
+    up to ``max_positions``). ``acquire``
     zeroes a slot and hands it out; with every slot out it waits.
     ``release`` gives it back. ``state`` is the device arrays (the jitted
     programs donate and return them); ``scratch`` is the extra slot the
@@ -689,9 +1060,12 @@ class StateCache:
 class Generation:
     """What one prompt produced. ``logits`` [new tokens, held rows]
     float32 and the expert selections (``prompt_routes`` [layers, prompt
-    tokens, k], ``decode_routes`` [layers, new tokens - 1, k]) and ``ssm``
-    (the slot's final SSM state, a device array a Mamba layer) only
-    for the rows ``generate`` was asked to keep."""
+    tokens, k], ``decode_routes`` [layers, new tokens - 1, k], a row an
+    expert layer), ``ssm`` (the slot's final SSM state, a device array a
+    Mamba layer) and ``latent`` (the slot's cache rows, a device array
+    [max_positions, kv_rank + rope] an MLA layer; the sequence's are the
+    first prompt + new tokens - 1) only for the rows ``generate`` was
+    asked to keep."""
 
     prompt: np.ndarray
     tokens: np.ndarray
@@ -699,6 +1073,7 @@ class Generation:
     prompt_routes: np.ndarray | None = None
     decode_routes: np.ndarray | None = None
     ssm: list | None = None
+    latent: list | None = None
 
 
 class Counters:
@@ -706,7 +1081,8 @@ class Counters:
     may copy at any time."""
 
     def __init__(self, cfg: DecoderConfig):
-        self.expert_tokens = np.zeros((len(cfg.layer_types), cfg.experts_held[1]), np.int64)
+        # a row an expert layer
+        self.expert_tokens = np.zeros((cfg.expert_layers, cfg.experts_held[1]), np.int64)
         self.held_selections = 0
         self.absent_selections = 0
         self.prefill_real = 0
@@ -714,6 +1090,10 @@ class Counters:
         self.prompts = 0
         self.decode_steps: dict[int, int] = {}      # live rows -> steps
         self.decode_experts_touched = 0             # sum over steps and layers
+        # (query, cached position) pairs one attention layer went over
+        self.attended_positions_prefill = 0
+        self.attended_positions_decode = 0
+        self.latent_rows = 0                        # cache rows written, MLA layers summed
 
     def add_routing(self, counts: np.ndarray, absent: np.ndarray) -> None:
         self.expert_tokens += counts
@@ -741,8 +1121,14 @@ class AnswerModel:
         def answer_decode(params, state, slots, ids, pos, live):
             return decode_step(cfg, params, state, slots, ids, pos, live)
 
+        def slot_latent(state, slot):
+            return [jax.lax.dynamic_index_in_dim(s["latent"], slot, 0, keepdims=False)
+                    for s in state if "latent" in s]
+
         self._prefill = jax.jit(answer_prefill, donate_argnums=1)
         self._decode = jax.jit(answer_decode, donate_argnums=1)
+        self._slot_latent = jax.jit(slot_latent)
+        self._mla_layers = sum(kind == MLA for kind in cfg.layer_types)
         self._seen: set = set()
 
     def _dispatch(self, site: str, fn, bucket, *args, **span_args):
@@ -780,11 +1166,13 @@ class AnswerModel:
             *out, counts = self._dispatch(
                 "answer.prefill", self._prefill, T,
                 np.int32(slot), chunk, np.int32(at), np.int32(n),
-                chunk=at // T, real=n, padded=T - n,
+                chunk=at // T, real=n, padded=T - n, context=at + n,
             )
             counted.append((n, counts))
             self.counters.prefill_real += n
             self.counters.prefill_padded += T - n
+            # position at + i attends at + i + 1 cached positions
+            self.counters.attended_positions_prefill += n * at + n * (n + 1) // 2
         return out[0], out[1], counted
 
     def generate(self, prompts: Sequence[Sequence[int]], max_new_tokens: int,
@@ -838,6 +1226,7 @@ class AnswerModel:
                          "decode_routes": []} for r in keep},
         }
         chunks = len(fetch["counts"])
+        prompt_tokens = int(lengths[:rows].sum())
         with _flight.span("answer.decode", batch=rows, bucket=bucket,
                           steps=max_new_tokens - 1):
             for step in range(max_new_tokens - 1):
@@ -845,19 +1234,26 @@ class AnswerModel:
                     "answer.decode", self._decode, bucket,
                     slot_ids, ids, lengths + step, live,
                     span="answer.decode.step", batch=rows,
+                    positions=prompt_tokens + rows * (step + 1),
                 )
                 fetch["tokens"].append(ids)
                 fetch["counts"].append({k: counts[k] for k in routing})
                 for r in keep:
                     fetch["kept"][r]["logits"].append(logits[r])
                     fetch["kept"][r]["decode_routes"].append(counts["sel"][:, r])
-        self.counters.decode_steps[rows] = (
-            self.counters.decode_steps.get(rows, 0) + max_new_tokens - 1
-        )
+        steps = max_new_tokens - 1
+        self.counters.decode_steps[rows] = self.counters.decode_steps.get(rows, 0) + steps
+        # step t's token of a sequence of n prompt tokens attends n + t + 1 positions
+        self.counters.attended_positions_decode += (
+            steps * prompt_tokens + rows * steps * (steps + 1) // 2)
+        self.counters.latent_rows += self._mla_layers * (prompt_tokens + rows * steps)
         final_ssm = {
             r: [s["ssm"][slots[r]] for s in self.cache.state if "ssm" in s]
             for r in keep
         }
+        final_latent = {
+            r: self._slot_latent(self.cache.state, np.int32(slots[r])) for r in keep
+        } if self._mla_layers else {}
         with _flight.span("answer.wait"):
             # every copy queues behind the last step before anything waits
             leaves = jax.tree_util.tree_leaves(fetch)
@@ -876,6 +1272,7 @@ class AnswerModel:
         for r, kept in got["kept"].items():
             gen = out[r]
             gen.ssm = final_ssm[r]
+            gen.latent = final_latent.get(r)
             gen.logits = np.stack(kept["logits"])
             gen.prompt_routes = np.concatenate(
                 [sel[:, :n] for sel, (n, _) in zip(kept["prompt_routes"], prefilled[r][2])],

@@ -96,6 +96,10 @@ class _HFTokenizerAdapter:
 
 
 _VOCAB_ASSET = os.path.join(os.path.dirname(__file__), "assets", "wordpiece_vocab.txt")
+# a model that maps the pieces past its rows to [UNK] must hold this share
+# of the asset: below it most words would read [UNK], and the hash
+# tokenizer, which fills any table, serves a toy geometry better
+SLICE_MIN_SHARE = 0.5
 
 
 def wordpiece_tokenizer(max_length: int = 512, vocab_file: str | None = None):
@@ -121,12 +125,17 @@ def wordpiece_tokenizer(max_length: int = 512, vocab_file: str | None = None):
 
 
 def get_tokenizer(model_name_or_path: str | None = None, *, vocab_size: int = 30522,
-                  max_length: int = 512, prefer: str = "wordpiece"):
+                  max_length: int = 512, prefer: str = "wordpiece",
+                  maps_rest_to_unk: bool = False):
     """Resolve the flagship tokenizer, best first:
 
     1. a local HF checkpoint's own tokenizer (`model_name_or_path`);
     2. the trained WordPiece vocab asset (real WordPiece algorithm);
     3. the dependency-free HashTokenizer (`prefer="hash"` forces this).
+
+    `maps_rest_to_unk`: the caller maps the ids past its model's rows to
+    `unk_id`, so the asset is also taken by a model that holds at least
+    `SLICE_MIN_SHARE` of its pieces, the special ids among them.
     """
     if model_name_or_path is not None:
         try:
@@ -148,7 +157,9 @@ def get_tokenizer(model_name_or_path: str | None = None, *, vocab_size: int = 30
             tok = WordPieceTokenizer(_VOCAB_ASSET, max_length=max_length)
             # small-vocab models (tiny/test geometries) can't take the
             # asset's ids — their embedding table would be indexed OOB
-            if tok.vocab_size <= vocab_size:
+            share = SLICE_MIN_SHARE if maps_rest_to_unk else 1.0
+            if tok.vocab_size * share <= vocab_size and max(
+                    tok.pad_id, tok.unk_id, tok.cls_id, tok.sep_id) < vocab_size:
                 return tok
         except Exception:
             pass

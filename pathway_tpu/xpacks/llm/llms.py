@@ -12,6 +12,8 @@ from __future__ import annotations
 import json as _json
 from typing import Any
 
+import numpy as np
+
 from pathway_tpu.internals import dtype as dt
 from pathway_tpu.internals import expression as expr_mod
 from pathway_tpu.internals.api import Json
@@ -158,6 +160,10 @@ class TPUChat(BaseChat):
     engine step, at most the cache's slots a call) are tokenised, prefilled one
     after another in fixed chunks, decoded together in lock-step for
     ``max_new_tokens`` (greedy; there is no stop token) and detokenised.
+    A model that holds a slice of the vocabulary smaller than the
+    WordPiece asset (at least half of it) still tokenises by WordPiece: a
+    piece whose id lies outside the slice becomes ``[UNK]``, so every id
+    the model sees is one of its rows; toy geometries hash.
 
     ``model``: an :class:`pathway_tpu.models.decoder.AnswerModel`.
     Declared non-deterministic like the remote chats, so the engine keeps
@@ -172,8 +178,11 @@ class TPUChat(BaseChat):
         cfg = model.cfg
         self.max_new_tokens = int(max_new_tokens)
         self.max_prompt_tokens = cfg.max_positions - self.max_new_tokens
+        self.rows = cfg.vocab_held[1]
+        self.last_unk = 0
         self.tokenizer = get_tokenizer(
-            None, vocab_size=cfg.vocab_held[1], max_length=self.max_prompt_tokens + 1)
+            None, vocab_size=self.rows, max_length=self.max_prompt_tokens + 1,
+            maps_rest_to_unk=True)
         vocab = getattr(self.tokenizer, "vocab", None)
         self._pieces = (
             [piece for piece, _ in sorted(vocab.items(), key=lambda kv: kv[1])]
@@ -190,6 +199,7 @@ class TPUChat(BaseChat):
             with flight.span("answer.tokenize", texts=len(texts)) as sp:
                 prompts = self.tokenize(texts)
                 sp.args["tokens"] = sum(len(p) for p in prompts)
+                sp.args["unk"] = self.last_unk
             made = self.model.generate(prompts, self.max_new_tokens)
             return [self.detokenize(g.tokens) for g in made]
 
@@ -205,6 +215,10 @@ class TPUChat(BaseChat):
         continues the text), cut to what the cache leaves room for."""
         ids, mask = self.tokenizer(
             [t or "" for t in texts], max_length=self.max_prompt_tokens + 1)
+        outside = ids >= self.rows
+        self.last_unk = int(outside.sum())      # pieces the held rows lack, this call
+        if self.last_unk:
+            ids = np.where(outside, self.tokenizer.unk_id, ids)
         return [row[: int(n) - 1] for row, n in zip(ids, mask.sum(axis=1))]
 
     def detokenize(self, ids) -> str:

@@ -1,11 +1,13 @@
 #!/usr/bin/env python3
-"""The answer model at its published widths on the chip, outside the
-server: one chip's share of granite-4.0-h-small made from a seed, a few
-prompts prefilled in chunks and decoded through the cache, held to
-benchmark/reference_decoder.py's one full forward; times a chunk and a
-decode step; prints the device's memory peak.
+"""An answer model at its published widths on the chip, outside the
+server: one chip's share of an answer cell's configuration (``--workload``:
+granite-4.0-h-small.answer-steady, DeepSeek-V2.answer-long) made from a
+seed, a few prompts prefilled in chunks and decoded through the cache,
+held to the configuration's plain reference's one full forward; times a
+request alone; prints the device's memory peak.
 
-    chiprun -- python scripts/answer_chip_check.py [--seed N] [--control 1]
+    chiprun -- python scripts/answer_chip_check.py [--workload CELL] [--seed N] \
+        [--lengths 700,1400,3100] [--control 1]
 
 Refuses any backend but ``tpu``."""
 
@@ -24,6 +26,7 @@ import numpy as np  # noqa: E402
 
 def main() -> int:
     parser = argparse.ArgumentParser()
+    parser.add_argument("--workload", default="granite-4.0-h-small.answer-steady")
     parser.add_argument("--seed", type=int, default=2147484001)
     parser.add_argument("--lengths", default="700,1400,3100")
     parser.add_argument("--control", type=int, default=0)
@@ -33,12 +36,14 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         print("answer_chip_check: no TPU -- refusing", file=sys.stderr)
         return 2
+    import importlib
+
     import loader
-    import reference_decoder as ref
     from pathway_tpu.models import decoder as dec
 
-    cell = loader.Cell(loader.load(), "granite-4.0-h-small.answer-steady")
+    cell = loader.Cell(loader.load(), args.workload)
     config = cell.config
+    ref = importlib.import_module(config.get("reference", "reference_decoder"))
     a = ref.arch_of(config)
     cfg = dec.DecoderConfig.from_hf(
         {**config, **{k: config["published"][k] for k in config["reduced"]}},
@@ -47,7 +52,7 @@ def main() -> int:
     t0 = time.monotonic()
 
     class Ctx:
-        darch, seed = a, args.seed
+        darch, seed, config = a, args.seed, cell.config
 
     params = cell.pipeline.decoder_params(Ctx)
     jax.block_until_ready(params)
@@ -57,7 +62,8 @@ def main() -> int:
           flush=True)
     rng = np.random.default_rng(args.seed & 0xFFFFFFFF)
     lengths = [int(n) for n in args.lengths.split(",")]
-    prompts = [rng.integers(1000, 26000, size=n).astype(np.int32) for n in lengths]
+    prompts = [rng.integers(1000, min(26000, a["vocab_rows"]), size=n).astype(np.int32)
+               for n in lengths]
     new = 32
     for label in ("cold", "warm", "warm2"):
         t = time.monotonic()
@@ -74,7 +80,11 @@ def main() -> int:
     stats = jax.devices()[0].memory_stats() or {}
     print(json.dumps({"memory_peak_bytes": stats.get("peak_bytes_in_use"),
                       "bytes_in_use": stats.get("bytes_in_use")}), flush=True)
-    states = [[np.asarray(layer) for layer in g.ssm] for g in made]
+    # the state the reference gives back: final SSM states, or the
+    # sequence's own latent cache rows
+    states = [[np.asarray(layer) for layer in g.ssm] if g.latent is None else
+              [np.asarray(rows[:len(g.prompt) + new - 1], np.float32) for rows in g.latent]
+              for g in made]
     for leaf in jax.tree_util.tree_leaves((model.params, model.cache.state)):
         leaf.delete()
     seqs = [np.concatenate([g.prompt, g.tokens[:-1]]) for g in made]

@@ -1,17 +1,17 @@
 #!/usr/bin/env python3
-"""Which share of its capacity the answer cell can be offered: one set-up
-a seed, then the cell's own window (its fixed schedule, its questions, its
+"""Which share of its capacity an answer cell (``--workload``) can be
+offered: one set-up a seed, then the cell's own window (its fixed schedule, its questions, its
 ``--seconds``) once at each rate, and last, if asked, one window above
 capacity whose completions a second are the capacity itself.
 
-    chiprun -- python scripts/answer_rate_check.py --seed <n> \
+    chiprun -- python scripts/answer_rate_check.py [--workload CELL] --seed <n> \
         --rates 2.0,1.7,1.4 [--over 3.6] [--seconds 51]
 
 Run it on six seeds and hold each rate's ``query_p50_ms`` /
 ``query_p95_ms`` spread against half its bound (``--spread`` does that
 over the files of earlier runs). The rate found is written into
-``benchmark/traffic/answer-steady.json`` as a number; no check runs this.
-Each run leaves ``chiprun_out/rate_check/<seed>.json``.
+the cell's mix (``benchmark/traffic/``) as a number; no check runs this.
+Each run leaves ``chiprun_out/rate_check/<cell>/<seed>.json``.
 """
 
 from __future__ import annotations
@@ -27,7 +27,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
 OUT = os.path.join(REPO, "chiprun_out", "rate_check")
-CELL = "granite-4.0-h-small.answer-steady"
 
 
 def spread(values: list, drop_farthest: bool = False) -> float:
@@ -39,8 +38,8 @@ def spread(values: list, drop_farthest: bool = False) -> float:
     return (q[2] - q[0]) / statistics.median(values)
 
 
-def report() -> int:
-    runs = [json.load(open(p)) for p in sorted(glob.glob(os.path.join(OUT, "*.json")))]
+def report(cell: str) -> int:
+    runs = [json.load(open(p)) for p in sorted(glob.glob(os.path.join(OUT, cell, "*.json")))]
     rates = sorted({w["rate"] for r in runs for w in r["windows"] if not w["over"]})
     for rate in rates:
         rows = [w for r in runs for w in r["windows"] if w["rate"] == rate and not w["over"]]
@@ -61,6 +60,7 @@ def report() -> int:
 
 def main() -> int:
     parser = argparse.ArgumentParser()
+    parser.add_argument("--workload", default="granite-4.0-h-small.answer-steady")
     parser.add_argument("--seed", type=int, default=1)
     parser.add_argument("--rates", default="")
     parser.add_argument("--over", type=float, default=0.0)
@@ -68,8 +68,8 @@ def main() -> int:
     parser.add_argument("--spread", action="store_true")
     args = parser.parse_args()
     if args.spread:
-        return report()
-    args.trace, args.workload = 0, CELL
+        return report(args.workload)
+    args.trace = 0
 
     import run  # sets the paths
     import loader
@@ -79,9 +79,9 @@ def main() -> int:
     if jax.default_backend() != run.PLATFORM:
         print("answer_rate_check: no TPU", file=sys.stderr)
         return 2
-    windows = offer(loader.Cell(loader.load(), CELL), args)
-    os.makedirs(OUT, exist_ok=True)
-    with open(os.path.join(OUT, f"{args.seed}.json"), "w") as f:
+    windows = offer(loader.Cell(loader.load(), args.workload), args)
+    os.makedirs(os.path.join(OUT, args.workload), exist_ok=True)
+    with open(os.path.join(OUT, args.workload, f"{args.seed}.json"), "w") as f:
         json.dump({"seed": args.seed, "seconds": args.seconds, "windows": windows}, f)
     return 0
 
