@@ -47,10 +47,12 @@ the SSM state are float32.
 
 Latent attention has two paths over one cache row ``[c_kv after its
 norm | k_r after rotation]``: prefill expands keys and values from the
-latent rows through ``w_ukv``, a block of keys at a time (compute-bound);
-decode absorbs ``w_ukv``'s key half into the query and its value half
-into the output and attends over the latent rows themselves
-(bandwidth-bound). Neither holds scores over more than one block of keys.
+latent rows through ``w_ukv``, a block of keys at a time (compute-bound),
+inside one Pallas kernel a layer that keeps a block's scores in the chip's
+vector memory (Mosaic on a TPU, Pallas' interpreter elsewhere); decode
+absorbs ``w_ukv``'s key half into the query and its value half into the
+output and attends over the latent rows themselves (bandwidth-bound).
+Neither holds scores over more than one block of keys.
 
 ``StateCache`` holds the three kinds of per-sequence state side by side,
 by slot: constant-size (convolution tail, SSM state) for the Mamba
@@ -63,6 +65,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import threading
 from typing import Sequence
 
@@ -70,6 +73,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from pathway_tpu.internals import device as _devsup
 from pathway_tpu.internals import flight as _flight
@@ -752,42 +757,128 @@ def _softmax_step(carry, s, visible, weigh):
             acc * fade[..., None] + weigh(p.astype(_bf16)))
 
 
+MLA_HEAD_GROUP = 8      # heads a grid step of the prefill attention kernel
+
+
+def mla_lowering() -> str:
+    """How ``mla_attend``'s kernel is lowered here: by Mosaic on a TPU,
+    by Pallas' interpreter on any other backend."""
+    return "mosaic" if jax.default_backend() == "tpu" else "interpret"
+
+
+def _mla_attend_kernel(at_ref, q_nope_ref, q_rope_ref, rows_ref, w_ref, out_ref,
+                       m_ref, l_ref, acc_ref, *, scale: float, rk: int, nope: int):
+    """One grid step (head group g, cached block b): the block's keys and
+    values expanded from its latent rows through the group's rows of
+    ``w_ukv`` transposed, the scores, the running-softmax step
+    (``_softmax_step``'s, statement for statement) and the weighted
+    values, a head at a time. The cached block arrives as the slab holds
+    it, positions along the lanes (``rows_ref`` [1, kv_rank + rope,
+    keys]), so keys and values are made transposed ([nope + v, keys]) and
+    the scores are [T, keys] with no transposition in between. Running
+    max, sum and weighted values stay in vector memory from block to
+    block; the group's last block writes the output."""
+    b, T = pl.program_id(1), rows_ref.shape[2]
+    pos = at_ref[1]
+    width = w_ref.shape[0] // q_nope_ref.shape[0]       # nope + v, one head's rows
+
+    @pl.when(b == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, _f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, _f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _f32)
+
+    rows = rows_ref[0]
+    c_kv, k_r = rows[:rk], rows[rk:]                     # [kv_rank, keys], [rope, keys]
+    query_at = pos + jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    key_at = b * T + jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    visible = key_at <= query_at
+    for h in range(q_nope_ref.shape[0]):
+        kv = jnp.dot(w_ref[h * width:(h + 1) * width], c_kv,
+                     preferred_element_type=_f32).astype(_bf16)         # [nope + v, keys]
+        s = jnp.dot(q_nope_ref[h], kv[:nope], preferred_element_type=_f32)
+        s = s + jnp.dot(q_rope_ref[h], k_r, preferred_element_type=_f32)
+        s = jnp.where(visible, s * scale, _MASKED)
+        m = m_ref[h]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        fade = jnp.exp(m - m_new)
+        m_ref[h] = m_new
+        l_ref[h] = l_ref[h] * fade + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * fade + jax.lax.dot_general(
+            p.astype(_bf16), kv[nope:], (((1,), (1,)), ((), ())),
+            preferred_element_type=_f32)
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _finish():
+        out_ref[...] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
+
+
+def mla_attend(cfg: DecoderConfig, q_nope, q_rope, latent, w_ukv, slot, pos):
+    """Causal attention of one chunk's queries (``q_nope`` [heads, T,
+    nope], ``q_rope`` [heads, T, rope] rotated, at positions ``pos``..)
+    over ``slot``'s cached rows up to the chunk's own, a chunk-sized block
+    of rows at a time: one Pallas kernel over (head group, cached block)
+    whose scores never leave the chip's vector memory. ``slot`` reaches it
+    as a prefetched scalar and the blocks it goes over (``pos // T + 1``)
+    as the grid's dynamic bound, so the context shapes nothing: one
+    executable. The slab is handed over with its positions last: the
+    order a TPU keeps an array in whose rows are not whole lanes (kv_rank
+    + rope = 576), so no copy is made of it. Returns [heads, T, v]
+    bfloat16."""
+    H, T, nope = q_nope.shape
+    rk, v = cfg.kv_rank, cfg.v_dim
+    lowering = mla_lowering()
+    if lowering == "mosaic" and T % 128 and T != latent.shape[1]:
+        # a cached block is [kv_rank + rope, T] with T along the lanes
+        raise ValueError(
+            f"prefill_chunk % 128 must be 0 for latent attention on a TPU, not {T}: "
+            "the kernel reads a chunk of cached positions as whole lanes")
+    G = math.gcd(H, MLA_HEAD_GROUP)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(H // G, pos // T + 1),
+        in_specs=[
+            pl.BlockSpec((G, T, nope), lambda g, b, at: (g, 0, 0)),
+            pl.BlockSpec((G, T, cfg.rope_dim), lambda g, b, at: (g, 0, 0)),
+            pl.BlockSpec((1, cfg.latent_width, T), lambda g, b, at: (at[0], 0, b)),
+            pl.BlockSpec((G * (nope + v), rk), lambda g, b, at: (g, 0)),
+        ],
+        out_specs=pl.BlockSpec((G, T, v), lambda g, b, at: (g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((G, T, 1), _f32), pltpu.VMEM((G, T, 1), _f32),
+                        pltpu.VMEM((G, T, v), _f32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_attend_kernel, scale=mla_scale(cfg), rk=rk, nope=nope),
+        out_shape=jax.ShapeDtypeStruct((H, T, v), _bf16),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=lowering == "interpret",
+        name="mla_prefill_attention",
+    )(jnp.stack([slot, pos]).astype(jnp.int32), q_nope, q_rope,
+      jnp.swapaxes(latent, 1, 2), w_ukv.T)
+
+
 def mla_prefill(cfg: DecoderConfig, p: dict, u, latent, slot, pos, n):
     """Latent attention over one chunk at positions ``pos``.., the
     expanded path: the chunk's cache rows go into ``latent`` [slots + 1,
     positions, kv_rank + rope] at ``slot`` (a padded position writes
-    nothing); then, a chunk-sized block of cached rows at a time up to
-    the chunk's own, keys and values are expanded from the latent rows
-    through ``w_ukv`` and the chunk's queries attend to them causally.
-    Returns (out [T, hidden], latent)."""
-    T, H, rk = u.shape[0], cfg.heads, cfg.kv_rank
+    nothing); then ``mla_attend`` expands keys and values from the cached
+    rows through ``w_ukv`` and the chunk's queries attend to them
+    causally. Returns (out [T, hidden], latent)."""
+    T, H = u.shape[0], cfg.heads
     q_nope, q_rope, rows = _mla_project(cfg, p, u, pos + jnp.arange(T))
     old = jax.lax.dynamic_slice(latent, (slot, pos, 0), (1, T, cfg.latent_width))
     real = (jnp.arange(T) < n)[None, :, None]
     latent = jax.lax.dynamic_update_slice(
         latent, jnp.where(real, rows[None], old), (slot, pos, 0))
-    scale = mla_scale(cfg)
-
-    def block(b, carry):
-        rows_b = jax.lax.dynamic_slice(
-            latent, (slot, b * T, 0), (1, T, cfg.latent_width))[0]
-        kv = _mm(rows_b[:, :rk], p["w_ukv"]).astype(_bf16).reshape(
-            T, H, cfg.nope_dim + cfg.v_dim)
-        s = jnp.einsum("thd,phd->htp", q_nope, kv[..., :cfg.nope_dim],
-                       preferred_element_type=_f32)
-        s = s + jnp.einsum("thr,pr->htp", q_rope, rows_b[:, rk:],
-                           preferred_element_type=_f32)
-        visible = (b * T + jnp.arange(T))[None, :] <= (pos + jnp.arange(T))[:, None]
-        return _softmax_step(
-            carry, s * scale, visible[None],
-            lambda w: jnp.einsum("htp,phd->htd", w, kv[..., cfg.nope_dim:],
-                                 preferred_element_type=_f32))
-
-    start = (jnp.full((H, T), _MASKED, _f32), jnp.zeros((H, T), _f32),
-             jnp.zeros((H, T, cfg.v_dim), _f32))
-    _, l, acc = jax.lax.fori_loop(0, pos // T + 1, block, start)
-    out = jnp.transpose(acc / l[..., None], (1, 0, 2)).reshape(T, H * cfg.v_dim)
-    return _mm(out, p["wo"]), latent
+    mixed = mla_attend(cfg, jnp.transpose(q_nope, (1, 0, 2)), jnp.transpose(q_rope, (1, 0, 2)),
+                       latent, p["w_ukv"], slot, pos)
+    out = jnp.einsum("htd,hdo->to", mixed, p["wo"].reshape(H, cfg.v_dim, -1),
+                     preferred_element_type=_f32)
+    return out, latent
 
 
 def mla_decode(cfg: DecoderConfig, p: dict, u, latent, slots, pos):
@@ -1131,13 +1222,15 @@ class AnswerModel:
         self._mla_layers = sum(kind == MLA for kind in cfg.layer_types)
         self._seen: set = set()
 
-    def _dispatch(self, site: str, fn, bucket, *args, **span_args):
+    def _dispatch(self, site: str, fn, bucket, *args, first_says=None, **span_args):
         """One supervised device call that takes and returns the cache's
-        arrays; its ring span, and the armed plane's record."""
+        arrays; its ring span (with ``first_says`` too on the site's first
+        dispatch at this bucket), and the armed plane's record."""
         first = (site, bucket) not in self._seen
         if first:
             self._seen.add((site, bucket))
             _DEVICE.note_recompile(site)
+            span_args.update(first_says or {})
         dev = _DEVICE.begin(site, first=first, **span_args)
         try:
             state, *out = _devsup.supervised_dispatch(
@@ -1163,10 +1256,15 @@ class AnswerModel:
             n = min(T, len(ids) - at)
             chunk = np.zeros(T, np.int32)
             chunk[:n] = ids[at:at + n]
+            # latent attention: the cached-row blocks the chunk goes over, a
+            # layer, and on the site's first dispatch what lowered its kernel
+            latent_attention = {
+                "blocks": at // T + 1, "first_says": {"kernel": mla_lowering()},
+            } if self._mla_layers else {}
             *out, counts = self._dispatch(
                 "answer.prefill", self._prefill, T,
                 np.int32(slot), chunk, np.int32(at), np.int32(n),
-                chunk=at // T, real=n, padded=T - n, context=at + n,
+                chunk=at // T, real=n, padded=T - n, context=at + n, **latent_attention,
             )
             counted.append((n, counts))
             self.counters.prefill_real += n

@@ -23,7 +23,11 @@ promises:
 
 4. what a commit of documents costs the engine's thread: the steps of
    the stretch, ``json_hashes`` a step and every node's self time
-   (``commits`` in the report).
+   (``commits`` in the report);
+5. what a prefill chunk's latent attention went over: the chunks of the
+   stretch, the cached-row ``blocks`` a chunk, and the ``kernel`` the
+   ``answer.prefill`` site's first dispatch says its attention was
+   lowered by (``prefill`` in the report).
 
 Prints one JSON object (also under ``chiprun_out/span_clock/``). Exit 0
 when 1 and 2 hold, 1 when not, the run's own code when the run failed.
@@ -237,6 +241,29 @@ def commits(ring, top: int = 12):
     }
 
 
+def prefill_chunks(ring, hi):
+    """What the stretch's ``answer.prefill`` spans say of a chunk's latent
+    attention: how many chunks, the cached-row blocks a chunk went over a
+    layer (``blocks``: None from a tree whose span does not say), and
+    which lowering the site's first dispatch met (``kernel``; that
+    dispatch is set-up's, so it is looked for in all the ring still
+    holds). None for a cell that prefills nothing."""
+    from pathway_tpu.internals import flight
+
+    chunks = [flight.args_of(s) for s in ring if s[1] == "answer.prefill"]
+    if not chunks:
+        return None
+    blocks = [a.get("blocks") for a in chunks]
+    first = [a for a in map(flight.args_of, flight.spans_between(0, hi))
+             if a.get("first") and "kernel" in a]
+    return {
+        "chunks": len(chunks),
+        "blocks": None if None in blocks
+        else {"min": min(blocks), "mean": statistics.mean(blocks), "max": max(blocks)},
+        "kernel": first[0]["kernel"] if first else None,
+    }
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--workload", required=True)
@@ -332,6 +359,7 @@ def main() -> int:
             "by_innermost_ring_span_s": idle_by_span(gaps, innermost_timeline(labelled)),
         }
     report["commits"] = commits(ring)
+    report["prefill"] = prefill_chunks(ring, hi)
     report["ok"] = bool(ok)
     text = json.dumps(report)
     print(text, flush=True)

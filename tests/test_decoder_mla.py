@@ -179,6 +179,111 @@ def test_absorbed_decode_equals_expanded_attention(world):
     assert float(jnp.max(jnp.abs(expanded[0]))) > 0      # and neither is trivially zero
 
 
+def plain_mla_prefill(cfg, p, u, latent, slot, pos, n):
+    """``mla_prefill`` stated plainly, a block of cached rows a trip of a
+    loop, every block's float32 scores of every head a whole array: what
+    the kernel is held to."""
+    T, H, rk = u.shape[0], cfg.heads, cfg.kv_rank
+    q_nope, q_rope, rows = dec._mla_project(cfg, p, u, pos + jnp.arange(T))
+    old = jax.lax.dynamic_slice(latent, (slot, pos, 0), (1, T, cfg.latent_width))
+    real = (jnp.arange(T) < n)[None, :, None]
+    latent = jax.lax.dynamic_update_slice(
+        latent, jnp.where(real, rows[None], old), (slot, pos, 0))
+    carry = (jnp.full((H, T), dec._MASKED, jnp.float32), jnp.zeros((H, T), jnp.float32),
+             jnp.zeros((H, T, cfg.v_dim), jnp.float32))
+    for b in range(pos // T + 1):
+        rows_b = latent[slot, b * T:(b + 1) * T]
+        kv = dec._mm(rows_b[:, :rk], p["w_ukv"]).astype(jnp.bfloat16).reshape(
+            T, H, cfg.nope_dim + cfg.v_dim)
+        s = jnp.einsum("thd,phd->htp", q_nope, kv[..., :cfg.nope_dim],
+                       preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("thr,pr->htp", q_rope, rows_b[:, rk:],
+                           preferred_element_type=jnp.float32)
+        visible = (b * T + jnp.arange(T))[None, :] <= (pos + jnp.arange(T))[:, None]
+        carry = dec._softmax_step(
+            carry, s * dec.mla_scale(cfg), visible[None],
+            lambda w: jnp.einsum("htp,phd->htd", w, kv[..., cfg.nope_dim:],
+                                 preferred_element_type=jnp.float32))
+    _, l, acc = carry
+    out = jnp.transpose(acc / l[..., None], (1, 0, 2)).reshape(T, H * cfg.v_dim)
+    return dec._mm(out, p["wo"]), latent
+
+
+@pytest.mark.parametrize("slot, chunks, n", [(0, 0, 16), (0, 3, 16), (0, 2, 5), (3, 1, 16)],
+                         ids=["first-chunk", "several-cached-blocks", "padded", "another-slot"])
+def test_the_prefill_kernel_equals_the_plain_block_loop(world, slot, chunks, n):
+    """The Pallas kernel (interpreted here) against the plain statement:
+    ``chunks`` chunks of context in ``slot``, then one chunk of ``n`` real
+    positions through both. The output within a bfloat16 step of the
+    values that go into the output product, the slab equal bit for bit,
+    the other slots untouched."""
+    _, cfg, w = world
+    p, T = w["layers"][1], cfg.prefill_chunk
+    assert dec.mla_lowering() == "interpret"
+    key = jax.random.PRNGKey(7 + slot + chunks)
+    latent = jnp.zeros((cfg.slots + 1, cfg.max_positions, cfg.latent_width), jnp.bfloat16)
+    for at in range(chunks):
+        u = jax.random.normal(jax.random.fold_in(key, at), (T, cfg.hidden), jnp.float32)
+        _, latent = plain_mla_prefill(cfg, p, u, latent, slot, at * T, T)
+    u = jax.random.normal(jax.random.fold_in(key, 99), (T, cfg.hidden), jnp.float32)
+    pos = chunks * T
+    want, slab_want = plain_mla_prefill(cfg, p, u, latent, slot, pos, n)
+    got, slab_got = jax.jit(
+        lambda u, latent, slot, pos, n: dec.mla_prefill(cfg, p, u, latent, slot, pos, n)
+    )(u, latent, jnp.int32(slot), jnp.int32(pos), jnp.int32(n))
+    assert np.array_equal(np.asarray(slab_got).view(np.uint16),
+                          np.asarray(slab_want).view(np.uint16))
+    written = np.asarray(slab_got, np.float32)
+    assert written[slot, pos:pos + n].any() and not written[slot, pos + n:].any()
+    assert not np.delete(written, slot, axis=0).any()
+    assert np.isfinite(np.asarray(got)).all()               # the padded rows too
+    assert rel(got[:n], want[:n]) < 2 ** -8
+    assert float(jnp.max(jnp.abs(want[:n]))) > 0
+
+
+def test_granites_prefill_lowers_without_a_kernel():
+    """The control cell's program: no mixer of ``DecoderConfig.tiny()``
+    (Granite's kinds) reaches the kernel: its jaxpr has no
+    ``pallas_call`` and its text no custom call; ``tiny_mla``'s jaxpr has
+    one a layer (the interpreter leaves the text no name to find)."""
+    def traced(cfg):
+        params = jax.eval_shape(lambda: dec.init_params(cfg, 0))
+        state = jax.eval_shape(lambda: dec.empty_state(cfg))
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        ids = jax.ShapeDtypeStruct((cfg.prefill_chunk,), jnp.int32)
+        return jax.jit(
+            lambda p, s, slot, ids, pos, n: dec.prefill_chunk(cfg, p, s, slot, ids, pos, n)
+        ).trace(params, state, i32, ids, i32, i32)
+
+    granite = traced(dec.DecoderConfig.tiny())
+    assert "pallas_call" not in str(granite.jaxpr)
+    text = granite.lower().as_text()
+    assert "custom_call" not in text and "pallas" not in text.lower()
+    assert str(traced(dec.DecoderConfig.tiny_mla()).jaxpr).count("pallas_call[") == 3
+
+
+def test_the_prefill_span_says_blocks_and_the_first_dispatch_the_lowering(world):
+    from pathway_tpu.internals import flight
+
+    _, cfg, w = world
+    t0 = flight._time.monotonic_ns()
+    model = dec.AnswerModel(cfg, w)
+    model.generate([np.arange(1, 41)], 2)
+    model.generate([np.arange(1, 20)], 2)
+    chunks = [flight.args_of(s) for s in flight.spans_between(t0, flight._time.monotonic_ns())
+              if s[1] == "answer.prefill"]
+    # 40 positions in chunks of 16, then 19: a chunk at 16 k attends k + 1 blocks a layer
+    assert [a["blocks"] for a in chunks] == [1, 2, 3, 1, 2]
+    assert [a.get("kernel") for a in chunks] == ["interpret", None, None, None, None]
+    assert chunks[0]["first"] and not chunks[1]["first"]
+    # a model without latent attention says neither
+    t0 = flight._time.monotonic_ns()
+    dec.AnswerModel(dec.DecoderConfig.tiny()).generate([[1, 2, 3]], 2)
+    plain = [flight.args_of(s) for s in flight.spans_between(t0, flight._time.monotonic_ns())
+             if s[1] == "answer.prefill"]
+    assert plain and all("blocks" not in a and "kernel" not in a for a in plain)
+
+
 def _router_inputs(arch, p, tokens=48):
     x = jax.random.normal(jax.random.PRNGKey(5), (tokens, arch["hidden"]), jnp.float32)
     return ref.rms_norm(x, p["norm2"], arch["rms_eps"])
