@@ -1,6 +1,6 @@
 """Causal decoder: the answer model of the RAG plane.
 
-Two model families are written down here, as one stack of blocks ``x += r
+Three model families are written down here, as one stack of blocks ``x += r
 * mixer(norm(x)); x += r * ffn(norm(x))`` whose layers each name a mixer
 kind and a feed-forward kind:
 
@@ -18,6 +18,15 @@ kind and a feed-forward kind:
   best ``router_top_groups`` of ``router_groups`` groups, the k best of
   those, gates not renormalised, times ``routed_scaling``) beside the
   shared experts; an untied head; every multiplier 1.
+* ``glm_moe_dsa`` (GLM-5.2): DeepSeek's latent attention under plain
+  rotary frequencies, **over a chosen subset of the cache**: a layer
+  whose ``indexer_types`` entry is ``full`` owns an indexer (``index_heads``
+  small query heads, one cached key of ``index_dim`` values a position)
+  that scores every cached position for every query and keeps the
+  ``index_topk`` best; the ``shared`` layers after it own none and attend
+  its choice. The router's third kind: sigmoid scores, the k best of
+  score + a learned bias, gates the chosen scores renormalised times
+  ``routed_scaling``.
 
 Everything a deployment divides over chips is told to this module, never
 assumed: which layers (``layer_types``), which experts
@@ -52,12 +61,16 @@ inside one Pallas kernel a layer that keeps a block's scores in the chip's
 vector memory (Mosaic on a TPU, Pallas' interpreter elsewhere); decode
 absorbs ``w_ukv``'s key half into the query and its value half into the
 output and attends over the latent rows themselves (bandwidth-bound).
-Neither holds scores over more than one block of keys.
+Neither holds scores over more than one block of keys. Under an indexer
+both paths compute every visible block and mask it to the query's chosen
+rows (the choice is a mask over the slot's positions: the ``index_topk``
+best index scores of a row, found by bisection on the scores' bits).
 
-``StateCache`` holds the three kinds of per-sequence state side by side,
+``StateCache`` holds the kinds of per-sequence state side by side,
 by slot: constant-size (convolution tail, SSM state) for the Mamba
 layers, growing keys/values for the attention layers, growing latent rows
-for the MLA layers. ``AnswerModel`` is the host-facing object (prompt ids
+for the MLA layers and, beside them, the indexer's key rows for the
+layers that own an indexer. ``AnswerModel`` is the host-facing object (prompt ids
 in, generated ids out) the chat UDF wraps.
 """
 
@@ -66,6 +79,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import operator
 import threading
 from typing import Sequence
 
@@ -106,10 +120,20 @@ class YarnRope:
 
 
 @dataclasses.dataclass(frozen=True)
+class PlainRope:
+    """``rope_parameters`` of type ``default``: ``rope_theta`` and nothing else."""
+
+    theta: float = 10000.0
+
+
+FULL, SHARED = "full", "shared"                          # indexer kinds
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
-    """Published sizes of one of the two families written down here
-    (``granitemoehybrid``, ``deepseek_v2``; the defaults are Granite
-    4.0-H-small's), the share of them held here, and the serving sizes
+    """Published sizes of one of the three families written down here
+    (``granitemoehybrid``, ``deepseek_v2``, ``glm_moe_dsa``; the defaults
+    are Granite 4.0-H-small's), the share of them held here, and the serving sizes
     (chunk, positions, slots)."""
 
     hidden: int = 4096
@@ -150,10 +174,17 @@ class DecoderConfig:
     nope_dim: int = 0
     rope_dim: int = 0
     v_dim: int = 0
-    rope: YarnRope | None = None
+    rope: YarnRope | PlainRope | None = None
     # latent rows a block of the decode path's attention reads: a serving
     # constant, which only tests lower (to cross several blocks at toy lengths)
     decode_block: int = 2048
+    # what glm_moe_dsa adds: the router's third kind (sigmoid scores, chosen
+    # by score + a learned bias, gates renormalised) and the indexer
+    router_bias: bool = False
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0                  # cached rows a query keeps
+    indexer_types: tuple = ()            # a layer: "full" owns an indexer, "shared" reuses one
 
     def __post_init__(self):
         kinds = set(self.layer_types)
@@ -187,6 +218,18 @@ class DecoderConfig:
                 raise ValueError("mla layers need q_rank, kv_rank, nope/rope/v dims and rope")
             if self.rope_dim % 2 or self.max_positions % self.decode_rows:
                 raise ValueError("rope_dim must be even, max_positions whole decode blocks")
+        if self.router_bias and self.router_groups:
+            raise ValueError("the bias-corrected router is written down without groups")
+        if self.indexer_types:
+            if kinds != {MLA} or len(self.indexer_types) != len(self.layer_types) \
+                    or not set(self.indexer_types) <= {FULL, SHARED}:
+                raise ValueError("indexer_types: 'full' or 'shared' for each of the mla layers")
+            if self.indexer_types[0] != FULL:
+                raise ValueError("the first layer held here must own its indexer ('full')")
+            if min(self.index_heads, self.index_topk) < 1 or self.index_dim < self.rope_dim:
+                raise ValueError("an indexer needs index_heads, index_topk, index_dim >= rope_dim")
+            if self.max_positions % 8:
+                raise ValueError("under an indexer max_positions must be whole bytes of row bits")
 
     # derived widths
     @property
@@ -228,19 +271,42 @@ class DecoderConfig:
     def expert_layers(self) -> int:
         return len(self.layer_types) - self.dense_layers
 
+    @property
+    def index_types(self) -> tuple:
+        """``indexer_types``, or None a layer where no layer has an indexer."""
+        return self.indexer_types or (None,) * len(self.layer_types)
+
+    @property
+    def index_layers(self) -> int:
+        """Layers that own an indexer (and its key rows in the cache)."""
+        return sum(kind == FULL for kind in self.indexer_types)
+
+    @property
+    def index_spans(self) -> tuple:
+        """For each layer that owns an indexer, the layers that attend its
+        choice: itself and those that share it."""
+        owners = [i for i, kind in enumerate(self.indexer_types) if kind == FULL]
+        return tuple(b - a for a, b in zip(owners, owners[1:] + [len(self.indexer_types)]))
+
     @classmethod
-    def from_hf(cls, hf: dict, *, layers: int | None = None,
+    def from_hf(cls, hf: dict, *, layers: int | None = None, first_layer: int = 0,
                 experts_held: tuple | None = None,
                 vocab_held: tuple | None = None, **serving) -> "DecoderConfig":
         """From a ``config.json`` of ``model_type`` ``granitemoehybrid``
-        (also when the key is missing) or ``deepseek_v2``; any other is
-        refused. ``layers`` keeps the first that many; ``experts_held``
-        and ``vocab_held`` give this chip's share (default: everything)."""
+        (also when the key is missing), ``deepseek_v2`` or ``glm_moe_dsa``;
+        any other is refused. ``layers`` keeps that many of the published
+        layers from ``first_layer`` on; ``experts_held`` and ``vocab_held``
+        give this chip's share (default: everything)."""
         model_type = hf.get("model_type", "granitemoehybrid")
         if model_type not in _FROM_HF:
             raise ValueError(
                 f"model_type {model_type!r} is not written down here (has: {sorted(_FROM_HF)})")
-        fields, experts_key = _FROM_HF[model_type](hf, layers or hf["num_hidden_layers"])
+        layers = layers or hf["num_hidden_layers"] - first_layer
+        if first_layer < 0 or layers < 1 or first_layer + layers > hf["num_hidden_layers"]:
+            raise ValueError(
+                f"layers {first_layer}..{first_layer + layers} outside the published "
+                f"{hf['num_hidden_layers']}")
+        fields, experts_key = _FROM_HF[model_type](hf, first_layer, layers)
         return cls(
             hidden=hf["hidden_size"], vocab_size=hf["vocab_size"],
             vocab_held=tuple(vocab_held or (0, hf["vocab_size"])),
@@ -281,14 +347,28 @@ class DecoderConfig:
             decode_block=16,
         )
 
+    @classmethod
+    def tiny_dsa(cls) -> "DecoderConfig":
+        """``tiny_mla``'s GLM-shaped sibling: five latent-attention layers
+        under plain rotary frequencies whose indexers go ``full`` (the
+        dense layer) ``shared shared full shared``, 3 index heads of 8
+        that keep 8 cached rows a query, 16 experts top-3 under the
+        sigmoid bias-corrected router, one share of 4."""
+        return dataclasses.replace(
+            cls.tiny_mla(), layer_types=(MLA,) * 5, rms_eps=1e-5, router_groups=0,
+            router_top_groups=0, routed_scaling=2.5, rope=PlainRope(theta=10000.0),
+            router_bias=True, index_heads=3, index_dim=8, index_topk=8,
+            indexer_types=(FULL, SHARED, SHARED, FULL, SHARED),
+        )
 
-def _granite_fields(hf: dict, layers: int) -> tuple[dict, str]:
+
+def _granite_fields(hf: dict, first: int, layers: int) -> tuple[dict, str]:
     if hf.get("position_embedding_type", "nope") != "nope":
         raise ValueError("only position_embedding_type 'nope' is written down here")
     if hf["mamba_expand"] * hf["hidden_size"] != hf["mamba_n_heads"] * hf["mamba_d_head"]:
         raise ValueError("mamba_expand x hidden_size != mamba_n_heads x mamba_d_head")
     return {
-        "layer_types": tuple(hf["layer_types"])[:layers],
+        "layer_types": tuple(hf["layer_types"])[first:first + layers],
         "attention_multiplier": hf["attention_multiplier"],
         "mamba_heads": hf["mamba_n_heads"], "mamba_head_dim": hf["mamba_d_head"],
         "mamba_state": hf["mamba_d_state"], "mamba_groups": hf["mamba_n_groups"],
@@ -301,7 +381,25 @@ def _granite_fields(hf: dict, layers: int) -> tuple[dict, str]:
     }, "num_local_experts"
 
 
-def _deepseek_v2_fields(hf: dict, layers: int) -> tuple[dict, str]:
+def _mla_fields(hf: dict, first: int, layers: int) -> dict:
+    """What the two latent-attention families share."""
+    return {
+        "layer_types": (MLA,) * layers,
+        "tied_head": bool(hf.get("tie_word_embeddings", False)),
+        "dense_layers": min(max(hf["first_k_dense_replace"] - first, 0), layers),
+        "dense_width": hf["intermediate_size"],
+        "expert_width": hf["moe_intermediate_size"],
+        "shared_width": hf["n_shared_experts"] * hf["moe_intermediate_size"],
+        "routed_scaling": float(hf["routed_scaling_factor"]),
+        "q_rank": hf["q_lora_rank"], "kv_rank": hf["kv_lora_rank"],
+        "nope_dim": hf["qk_nope_head_dim"], "rope_dim": hf["qk_rope_head_dim"],
+        "v_dim": hf["v_head_dim"],
+        "embedding_multiplier": 1.0, "residual_multiplier": 1.0, "logits_scaling": 1.0,
+        "attention_multiplier": 0.0,     # MLA's scale comes from its dims (and YaRN)
+    }
+
+
+def _deepseek_v2_fields(hf: dict, first: int, layers: int) -> tuple[dict, str]:
     scaling = hf.get("rope_scaling") or {}
     if scaling.get("type") != "yarn":
         raise ValueError("deepseek_v2: only rope_scaling of type 'yarn' is written down here")
@@ -313,35 +411,71 @@ def _deepseek_v2_fields(hf: dict, layers: int) -> tuple[dict, str]:
     if not hf.get("q_lora_rank"):
         raise ValueError("deepseek_v2: only low-rank queries (q_lora_rank) are written down here")
     return {
-        "layer_types": (MLA,) * layers,
-        "tied_head": bool(hf.get("tie_word_embeddings", False)),
-        "dense_layers": min(hf["first_k_dense_replace"], layers),
-        "dense_width": hf["intermediate_size"],
-        "expert_width": hf["moe_intermediate_size"],
-        "shared_width": hf["n_shared_experts"] * hf["moe_intermediate_size"],
+        **_mla_fields(hf, first, layers),
         "router_groups": hf["n_group"], "router_top_groups": hf["topk_group"],
-        "routed_scaling": float(hf["routed_scaling_factor"]),
-        "q_rank": hf["q_lora_rank"], "kv_rank": hf["kv_lora_rank"],
-        "nope_dim": hf["qk_nope_head_dim"], "rope_dim": hf["qk_rope_head_dim"],
-        "v_dim": hf["v_head_dim"],
         "rope": YarnRope(
             theta=float(hf["rope_theta"]), factor=float(scaling["factor"]),
             original_positions=int(scaling["original_max_position_embeddings"]),
             beta_fast=float(scaling["beta_fast"]), beta_slow=float(scaling["beta_slow"]),
             mscale=float(scaling["mscale"]), mscale_all_dim=float(scaling["mscale_all_dim"])),
-        "embedding_multiplier": 1.0, "residual_multiplier": 1.0, "logits_scaling": 1.0,
-        "attention_multiplier": 0.0,     # MLA's scale comes from its dims and YaRN
     }, "n_routed_experts"
 
 
-_FROM_HF = {"granitemoehybrid": _granite_fields, "deepseek_v2": _deepseek_v2_fields}
+def _glm_moe_dsa_fields(hf: dict, first: int, layers: int) -> tuple[dict, str]:
+    """What is written down of ``glm_moe_dsa``; everything else is refused.
+    The draft module (``num_nextn_predict_layers``) is no layer of the main
+    pass and is not loaded."""
+    rope = hf.get("rope_parameters") or {}
+    if rope.get("rope_type") != "default" or "rope_theta" not in rope:
+        raise ValueError("glm_moe_dsa: only rope_parameters of rope_type 'default' are written down here")
+    if not (hf.get("rope_interleave") and hf.get("indexer_rope_interleave")):
+        raise ValueError("glm_moe_dsa: only interleaved rotary pairs (2i, 2i + 1) are written down here")
+    if (hf.get("topk_method"), hf.get("scoring_func"), hf.get("norm_topk_prob")) != (
+            "noaux_tc", "sigmoid", True) or hf.get("moe_layer_freq", 1) != 1:
+        raise ValueError(
+            "glm_moe_dsa: only the sigmoid bias-corrected (noaux_tc) router with "
+            "renormalised gates, on every layer past the dense ones, is written down here")
+    if (hf.get("n_group", 1), hf.get("topk_group", 1)) != (1, 1):
+        raise ValueError("glm_moe_dsa: only n_group == topk_group == 1 (no group limit) is written down here")
+    if hf.get("index_topk_pattern") is not None:
+        raise ValueError("glm_moe_dsa: only index_topk_pattern null (one index_topk) is written down here")
+    if not hf.get("q_lora_rank"):
+        raise ValueError("glm_moe_dsa: only low-rank queries (q_lora_rank) are written down here")
+    if hf.get("attention_bias"):
+        raise ValueError("glm_moe_dsa: attention_bias is not written down here")
+    kinds = tuple(hf["indexer_types"])[first:first + layers]
+    if not set(kinds) <= {FULL, SHARED}:
+        raise ValueError(f"glm_moe_dsa: indexer_types {sorted(set(kinds))}: only 'full' and 'shared'")
+    if kinds[0] != FULL:
+        raise ValueError(
+            "glm_moe_dsa: the first layer held here is 'shared': its choice comes from a "
+            "layer that is not here")
+    fields = _mla_fields(hf, first, layers)
+    ffn = tuple(hf["mlp_layer_types"])[first:first + layers]
+    want = ("dense",) * fields["dense_layers"] + ("sparse",) * (layers - fields["dense_layers"])
+    if ffn != want:
+        raise ValueError(
+            "glm_moe_dsa: mlp_layer_types other than first_k_dense_replace dense layers, "
+            "then sparse ones, are not written down here")
+    return {
+        **fields, "rope": PlainRope(theta=float(rope["rope_theta"])), "router_bias": True,
+        "indexer_types": kinds, "index_heads": hf["index_n_heads"],
+        "index_dim": hf["index_head_dim"], "index_topk": hf["index_topk"],
+    }, "n_routed_experts"
+
+
+_FROM_HF = {"granitemoehybrid": _granite_fields, "deepseek_v2": _deepseek_v2_fields,
+            "glm_moe_dsa": _glm_moe_dsa_fields}
 
 
 # -- parameters ------------------------------------------------------------------
 
 
-def layer_shapes(cfg: DecoderConfig, kind: str, ffn: str = MOE) -> dict[str, tuple]:
-    """Leaf name -> shape of one layer of mixer ``kind`` and feed-forward ``ffn``."""
+def layer_shapes(cfg: DecoderConfig, kind: str, ffn: str = MOE,
+                 index: str | None = None) -> dict[str, tuple]:
+    """Leaf name -> shape of one layer of mixer ``kind`` and feed-forward
+    ``ffn``; ``index`` ``"full"``: the layer owns an indexer (a ``shared``
+    layer has no leaf of its own for it)."""
     h, held = cfg.hidden, cfg.experts_held[1]
     block = {"norm1": (h,), "norm2": (h,)}
     if ffn == MOE:
@@ -352,6 +486,8 @@ def layer_shapes(cfg: DecoderConfig, kind: str, ffn: str = MOE) -> dict[str, tup
             "experts_in": (held, h, 2 * cfg.expert_width),
             "experts_out": (held, cfg.expert_width, h),
         })
+        if cfg.router_bias:
+            block["router_bias"] = (cfg.experts,)
     else:
         block.update({"mlp_in": (h, 2 * cfg.dense_width), "mlp_out": (cfg.dense_width, h)})
     if kind == MAMBA:
@@ -376,24 +512,39 @@ def layer_shapes(cfg: DecoderConfig, kind: str, ffn: str = MOE) -> dict[str, tup
             "w_ukv": (cfg.kv_rank, cfg.heads * (cfg.nope_dim + cfg.v_dim)),
             "wo": (cfg.heads * cfg.v_dim, h),
         })
+        if index == FULL:
+            block.update({
+                "w_iq": (cfg.q_rank, cfg.index_heads * cfg.index_dim),
+                "w_ik": (h, cfg.index_dim),
+                "ik_norm": (cfg.index_dim,), "ik_bias": (cfg.index_dim,),   # a LayerNorm's
+                "w_iw": (h, cfg.index_heads),
+            })
     return block
 
 
-_NORM_LEAVES = frozenset(("norm1", "norm2", "mixer_norm", "q_norm", "kv_norm"))
-_F32_LEAVES = _NORM_LEAVES | frozenset(("conv_w", "conv_b", "dt_bias", "A_log", "D"))
+_NORM_LEAVES = frozenset(("norm1", "norm2", "mixer_norm", "q_norm", "kv_norm", "ik_norm"))
+_BIAS_LEAVES = frozenset(("router_bias", "ik_bias"))
+_F32_LEAVES = _NORM_LEAVES | _BIAS_LEAVES | frozenset(
+    ("conv_w", "conv_b", "dt_bias", "A_log", "D"))
+ROUTER_BIAS_STD = 0.01      # the correction bias of random weights: N(0, 0.01) (assumed)
 
 
-def init_layer(cfg: DecoderConfig, kind: str, key, ffn: str = MOE) -> dict:
+def init_layer(cfg: DecoderConfig, kind: str, key, ffn: str = MOE,
+               index: str | None = None) -> dict:
     """One layer's weights from its key: matrices N(0, 0.02) in bfloat16;
-    norm scales 1 + N(0, 0.02); Mamba's own conventions for the rest
+    norm scales 1 + N(0, 0.02); the router's correction bias N(0, 0.01) and
+    the index keys' LayerNorm bias N(0, 0.02); Mamba's own conventions for the rest
     (``A_log`` = log U(1, 16), ``dt_bias`` = softplus^-1 of a step size
     log-uniform in [1e-3, 1e-1], ``D`` = 1, convolution U(-1/2, 1/2) with
     bias, which is Conv1d's default at fan-in 4)."""
     out = {}
-    for i, (name, shape) in enumerate(sorted(layer_shapes(cfg, kind, ffn).items())):
+    for i, (name, shape) in enumerate(sorted(layer_shapes(cfg, kind, ffn, index).items())):
         k = jax.random.fold_in(key, i)
         if name in _NORM_LEAVES:
             leaf = 1.0 + 0.02 * jax.random.normal(k, shape, jnp.float32)
+        elif name in _BIAS_LEAVES:
+            std = ROUTER_BIAS_STD if name == "router_bias" else 0.02
+            leaf = std * jax.random.normal(k, shape, jnp.float32)
         elif name == "A_log":
             leaf = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0))
         elif name == "dt_bias":
@@ -414,9 +565,10 @@ def init_params(cfg: DecoderConfig, seed: int = 0) -> dict:
     then make one layer at a time): ``{"embed", "final_norm", "layers":
     [one tree a layer]}``, and ``"head"`` where the head is not tied."""
     root = jax.random.PRNGKey(seed)
+    kinds = [(*layer, index) for layer, index in zip(cfg.layers, cfg.index_types)]
     make = {
-        layer: jax.jit(functools.partial(init_layer, cfg, layer[0], ffn=layer[1]))
-        for layer in set(cfg.layers)
+        layer: jax.jit(functools.partial(init_layer, cfg, layer[0], ffn=layer[1], index=layer[2]))
+        for layer in set(kinds)
     }
     rows = cfg.vocab_held[1]
 
@@ -428,7 +580,7 @@ def init_params(cfg: DecoderConfig, seed: int = 0) -> dict:
         "embed": table(1_000_000),
         "final_norm": jnp.ones((cfg.hidden,), jnp.float32),
         "layers": [
-            make[layer](jax.random.fold_in(root, i)) for i, layer in enumerate(cfg.layers)
+            make[layer](jax.random.fold_in(root, i)) for i, layer in enumerate(kinds)
         ],
     }
     if not cfg.tied_head:
@@ -441,8 +593,8 @@ def param_bytes(cfg: DecoderConfig) -> float:
     """HBM bytes of the held parameters (bfloat16 matrices, float32 vectors)."""
     tables = 1 if cfg.tied_head else 2
     total = tables * 2.0 * cfg.vocab_held[1] * cfg.hidden + 4.0 * cfg.hidden
-    for kind, ffn in cfg.layers:
-        for name, shape in layer_shapes(cfg, kind, ffn).items():
+    for (kind, ffn), index in zip(cfg.layers, cfg.index_types):
+        for name, shape in layer_shapes(cfg, kind, ffn, index).items():
             total += (4.0 if name in _F32_LEAVES else 2.0) * float(np.prod(shape))
     return total
 
@@ -451,14 +603,16 @@ def cache_bytes(cfg: DecoderConfig) -> float:
     """HBM bytes of the state cache: ``slots`` + 1 (the scratch slot padded
     decode rows write to) of convolution tail (bfloat16) and SSM state
     (float32) a Mamba layer, keys and values (bfloat16) an attention
-    layer, latent rows (bfloat16) an MLA layer."""
+    layer, latent rows (bfloat16) an MLA layer and, where it owns an
+    indexer, its key rows (bfloat16) beside them."""
     per_kind = {
         MAMBA: 2.0 * (cfg.mamba_conv - 1) * cfg.conv_width
         + 4.0 * cfg.mamba_heads * cfg.mamba_head_dim * cfg.mamba_state,
         ATTENTION: 2 * 2.0 * cfg.max_positions * cfg.kv_heads * cfg.head_dim,
         MLA: 2.0 * cfg.max_positions * cfg.latent_width,
     }
-    return (cfg.slots + 1) * sum(per_kind[kind] for kind in cfg.layer_types)
+    index_rows = 2.0 * cfg.max_positions * cfg.index_dim * cfg.index_layers
+    return (cfg.slots + 1) * (sum(per_kind[kind] for kind in cfg.layer_types) + index_rows)
 
 
 # -- layers ------------------------------------------------------------------------
@@ -488,9 +642,17 @@ def route(cfg: DecoderConfig, p: dict, u, live):
     softmax. With them (group-limited greedy): scores are the softmax over
     all experts, a group's score its best expert's, only the
     ``router_top_groups`` best groups' experts stand, the k best of those
-    are selected, gates their scores times ``routed_scaling``."""
+    are selected, gates their scores times ``routed_scaling``. With a
+    bias (``noaux_tc``): scores are sigmoids, the k best of score + bias
+    are selected (the bias chooses and does not weigh), gates the selected
+    scores over their sum, times ``routed_scaling``."""
     logits = _mm(u, p["router"])
-    if cfg.router_groups:
+    if cfg.router_bias:
+        scores = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(scores + p["router_bias"], cfg.experts_per_token)
+        top = jnp.take_along_axis(scores, sel, axis=-1)
+        gates = cfg.routed_scaling * top / jnp.sum(top, axis=-1, keepdims=True)
+    elif cfg.router_groups:
         scores = jax.nn.softmax(logits, axis=-1)
         T, G = scores.shape[0], cfg.router_groups
         _, best = jax.lax.top_k(
@@ -685,12 +847,15 @@ def attention_decode(cfg: DecoderConfig, p: dict, u, keys, values, pos):
 
 
 def yarn_inv_freq(cfg: DecoderConfig) -> np.ndarray:
-    """The ``rope_dim`` / 2 rotary frequencies under YaRN: pairs that turn
-    more than ``beta_fast`` times over the original positions keep their
+    """The ``rope_dim`` / 2 rotary frequencies (float64). Plain:
+    theta^(-2i / rope_dim). Under YaRN: pairs that turn more than
+    ``beta_fast`` times over the original positions keep their
     frequency, those that turn less than ``beta_slow`` times are divided
-    by ``factor``, a linear ramp between (float64)."""
+    by ``factor``, a linear ramp between."""
     r, d = cfg.rope, cfg.rope_dim
     freq = r.theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if isinstance(r, PlainRope):
+        return freq
 
     def dim_of(turns):        # the dim at which a pair makes ``turns`` turns
         return d * np.log(r.original_positions / (turns * 2 * np.pi)) / (2 * np.log(r.theta))
@@ -706,21 +871,26 @@ def _yarn_mscale(factor: float, m: float) -> float:
 
 
 def mla_scale(cfg: DecoderConfig) -> float:
-    """Softmax scale: (nope + rope)^-1/2 times mscale(factor, mscale_all_dim)^2."""
-    return float((cfg.nope_dim + cfg.rope_dim) ** -0.5
-                 * _yarn_mscale(cfg.rope.factor, cfg.rope.mscale_all_dim) ** 2)
+    """Softmax scale: (nope + rope)^-1/2, under YaRN times mscale(factor, mscale_all_dim)^2."""
+    plain = (cfg.nope_dim + cfg.rope_dim) ** -0.5
+    if isinstance(cfg.rope, PlainRope):
+        return float(plain)
+    return float(plain * _yarn_mscale(cfg.rope.factor, cfg.rope.mscale_all_dim) ** 2)
 
 
 def _rotate(cfg: DecoderConfig, x, positions):
     """Rotary embedding of ``x`` [.., rope_dim] at ``positions`` (shaped as
     x's leading dims, or broadcastable to them): the published code's
     pairing (dims 2i and 2i + 1 turn together; the result holds the first
-    of every pair, then the second), cos and sin scaled by mscale(factor,
-    mscale) / mscale(factor, mscale_all_dim)."""
+    of every pair, then the second), under YaRN cos and sin scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     r = cfg.rope
     angles = positions[..., None].astype(_f32) * jnp.asarray(yarn_inv_freq(cfg), _f32)
-    m = _yarn_mscale(r.factor, r.mscale) / _yarn_mscale(r.factor, r.mscale_all_dim)
-    cos, sin = jnp.cos(angles) * m, jnp.sin(angles) * m
+    if isinstance(r, PlainRope):
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+    else:
+        m = _yarn_mscale(r.factor, r.mscale) / _yarn_mscale(r.factor, r.mscale_all_dim)
+        cos, sin = jnp.cos(angles) * m, jnp.sin(angles) * m
     a, b = x[..., 0::2].astype(_f32), x[..., 1::2].astype(_f32)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
@@ -728,7 +898,8 @@ def _rotate(cfg: DecoderConfig, x, positions):
 def _mla_project(cfg: DecoderConfig, p: dict, u, positions):
     """(q_nope [.., heads, nope], q_rope [.., heads, rope] rotated, cache
     rows [.., kv_rank + rope] = [c_kv after its norm | k_r rotated]), all
-    bfloat16."""
+    bfloat16; and the normed query latent c_q [.., q_rank] float32, which
+    an indexer's queries are made from."""
     lead = u.shape[:-1]
     c_q = rms_norm(_mm(u, p["w_dq"]), p["q_norm"], cfg.rms_eps)
     q = _mm(c_q, p["w_uq"]).reshape(*lead, cfg.heads, cfg.nope_dim + cfg.rope_dim)
@@ -737,13 +908,97 @@ def _mla_project(cfg: DecoderConfig, p: dict, u, positions):
     c_kv = rms_norm(down[..., :cfg.kv_rank], p["kv_norm"], cfg.rms_eps)
     k_r = _rotate(cfg, down[..., cfg.kv_rank:], positions)
     rows = jnp.concatenate([c_kv, k_r], axis=-1).astype(_bf16)
-    return q[..., :cfg.nope_dim].astype(_bf16), q_rope.astype(_bf16), rows
+    return q[..., :cfg.nope_dim].astype(_bf16), q_rope.astype(_bf16), rows, c_q
+
+
+# -- the indexer: which cached rows a query attends ---------------------------------
+
+INDEX_NORM_EPS = 1e-6       # the index keys' LayerNorm (assumed: no key of the config)
+
+
+def index_project(cfg: DecoderConfig, p: dict, u, c_q, positions):
+    """The indexer's side of one layer's inputs: (q_I [.., index_heads,
+    index_dim] bfloat16, the heads' weights w [.., index_heads] float32,
+    k_I [.., index_dim] bfloat16: one key a position, the row the cache
+    keeps). The first ``rope_dim`` dims of q_I and k_I are rotated, with
+    the attention's frequencies and pairing."""
+    lead, J, D, r = u.shape[:-1], cfg.index_heads, cfg.index_dim, cfg.rope_dim
+    q = _mm(c_q, p["w_iq"]).reshape(*lead, J, D)
+    q = jnp.concatenate([_rotate(cfg, q[..., :r], positions[..., None]), q[..., r:]], axis=-1)
+    k = _mm(u, p["w_ik"])
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * jax.lax.rsqrt(jnp.mean(jnp.square(k), axis=-1, keepdims=True) + INDEX_NORM_EPS)
+    k = k * p["ik_norm"] + p["ik_bias"]
+    k = jnp.concatenate([_rotate(cfg, k[..., :r], positions), k[..., r:]], axis=-1)
+    w = _mm(u, p["w_iw"]) * float(J ** -0.5 * D ** -0.5)
+    return q.astype(_bf16), w, k.astype(_bf16)
+
+
+def _index_block(q, w, keys):
+    """Index scores of one block of cached keys: sum over the index heads
+    of w x relu(q . k). ``q`` [.., J, D], ``w`` [.., J], ``keys`` [.., R,
+    D] -> [.., R] float32. The heads' scores [.., J, R] are the largest
+    array the indexer makes."""
+    s = jnp.einsum("...jd,...pd->...jp", q, keys, preferred_element_type=_f32)
+    return jnp.sum(w[..., None] * jnp.maximum(s, 0.0), axis=-2)
+
+
+def select_rows(scores, visible, k: int):
+    """The ``k`` best visible entries of each row of ``scores`` [rows, P]
+    float32 (all of them where a row sees no more than ``k``), the lowest
+    position first among equal scores: a mask [rows, P]. No sort: the k-th
+    largest value is found bit by bit on the scores' ordered bit
+    patterns, 32 counting passes, and the last equal position to take by
+    as many passes as P has bits."""
+    R, P = scores.shape
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    # as unsigned integers in the scores' order; 0 is below every score's
+    order = jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, ~bits, bits | jnp.int32(-2 ** 31)), jnp.uint32)
+    order = jnp.where(visible, order, jnp.uint32(0))
+
+    def value_bit(i, kth):
+        probe = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(order >= probe[:, None], axis=1, dtype=jnp.int32) >= k
+        return jnp.where(enough, probe, kth)
+
+    kth = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((R,), jnp.uint32))
+    above = order > kth[:, None]
+    equal = (order == kth[:, None]) & visible
+    room = k - jnp.sum(above, axis=1, dtype=jnp.int32)      # equal ones still to take
+    at = jnp.arange(P, dtype=jnp.int32)[None, :]
+    n_bits = int(P).bit_length()
+
+    def position_bit(i, end):
+        probe = end | (jnp.int32(1) << (n_bits - 1 - i))
+        fits = jnp.sum(equal & (at < probe[:, None]), axis=1, dtype=jnp.int32) <= room
+        return jnp.where(fits, probe, end)
+
+    end = jax.lax.fori_loop(0, n_bits, position_bit, jnp.zeros((R,), jnp.int32))
+    return (above | (equal & (at < end[:, None]))) & visible
+
+
+def pack_rows(chosen):
+    """A choice of rows [.., P] (not 0: chosen; NumPy or a device array)
+    as bits [.., P / 8] uint8: bit k of byte j is row k * P / 8 + j, so
+    that on the chip a byte is made of eight whole slices of lanes and no
+    value changes its lane: one loop fusion (``numpy.packbits``' order
+    gathers eight neighbours, and compiles to two transposing copies of
+    the 42 MB choice at the published sizes)."""
+    width = chosen.shape[-1] // 8
+    return functools.reduce(operator.or_, (
+        (chosen[..., k * width:(k + 1) * width] != 0).astype("uint8") << k for k in range(8)))
+
+
+def unpack_rows(bits: np.ndarray) -> np.ndarray:
+    """``pack_rows`` undone on the host: [.., P / 8] uint8 -> [.., P] bool."""
+    return np.concatenate([(bits >> k) & 1 for k in range(8)], axis=-1).astype(bool)
 
 
 _MASKED = -1e30     # a masked score: far below any real one, and exp() of it is 0
 
 
-def _softmax_step(carry, s, visible, weigh):
+def _softmax_step(carry, s, visible, weigh, sparse: bool = False):
     """One block of an attention computed a block of keys at a time:
     ``carry`` = (running max [..], sum [..], weighted values [.., d]);
     ``s`` [.., keys] float32 scores; ``weigh(p)`` the block's values
@@ -752,6 +1007,8 @@ def _softmax_step(carry, s, visible, weigh):
     s = jnp.where(visible, s, _MASKED)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
     p = jnp.exp(s - m_new[..., None])
+    if sparse:      # a row may have seen nothing so far: its m_new is the mask's own
+        p = jnp.where(visible, p, 0.0)
     fade = jnp.exp(m - m_new)
     return (m_new, l * fade + jnp.sum(p, axis=-1),
             acc * fade[..., None] + weigh(p.astype(_bf16)))
@@ -766,8 +1023,8 @@ def mla_lowering() -> str:
     return "mosaic" if jax.default_backend() == "tpu" else "interpret"
 
 
-def _mla_attend_kernel(at_ref, q_nope_ref, q_rope_ref, rows_ref, w_ref, out_ref,
-                       m_ref, l_ref, acc_ref, *, scale: float, rk: int, nope: int):
+def _mla_attend_kernel(at_ref, q_nope_ref, q_rope_ref, rows_ref, w_ref, *refs,
+                       scale: float, rk: int, nope: int):
     """One grid step (head group g, cached block b): the block's keys and
     values expanded from its latent rows through the group's rows of
     ``w_ukv`` transposed, the scores, the running-softmax step
@@ -777,7 +1034,12 @@ def _mla_attend_kernel(at_ref, q_nope_ref, q_rope_ref, rows_ref, w_ref, out_ref,
     keys]), so keys and values are made transposed ([nope + v, keys]) and
     the scores are [T, keys] with no transposition in between. Running
     max, sum and weighted values stay in vector memory from block to
-    block; the group's last block writes the output."""
+    block; the group's last block writes the output. Under an indexer one
+    more input comes before the output: the block's columns of the
+    queries' chosen rows (``chosen_ref`` [T, keys] int32, not 0: chosen);
+    a query attends the chosen of its visible rows, and a block may hold
+    none of them."""
+    chosen_ref, out_ref, m_ref, l_ref, acc_ref = refs if len(refs) == 5 else (None, *refs)
     b, T = pl.program_id(1), rows_ref.shape[2]
     pos = at_ref[1]
     width = w_ref.shape[0] // q_nope_ref.shape[0]       # nope + v, one head's rows
@@ -793,6 +1055,8 @@ def _mla_attend_kernel(at_ref, q_nope_ref, q_rope_ref, rows_ref, w_ref, out_ref,
     query_at = pos + jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
     key_at = b * T + jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
     visible = key_at <= query_at
+    if chosen_ref is not None:
+        visible = visible & (chosen_ref[...] != 0)
     for h in range(q_nope_ref.shape[0]):
         kv = jnp.dot(w_ref[h * width:(h + 1) * width], c_kv,
                      preferred_element_type=_f32).astype(_bf16)         # [nope + v, keys]
@@ -802,6 +1066,8 @@ def _mla_attend_kernel(at_ref, q_nope_ref, q_rope_ref, rows_ref, w_ref, out_ref,
         m = m_ref[h]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if chosen_ref is not None:      # a row with nothing chosen so far: m_new is the mask's
+            p = jnp.where(visible, p, 0.0)
         fade = jnp.exp(m - m_new)
         m_ref[h] = m_new
         l_ref[h] = l_ref[h] * fade + jnp.sum(p, axis=1, keepdims=True)
@@ -814,7 +1080,7 @@ def _mla_attend_kernel(at_ref, q_nope_ref, q_rope_ref, rows_ref, w_ref, out_ref,
         out_ref[...] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
 
 
-def mla_attend(cfg: DecoderConfig, q_nope, q_rope, latent, w_ukv, slot, pos):
+def mla_attend(cfg: DecoderConfig, q_nope, q_rope, latent, w_ukv, slot, pos, chosen=None):
     """Causal attention of one chunk's queries (``q_nope`` [heads, T,
     nope], ``q_rope`` [heads, T, rope] rotated, at positions ``pos``..)
     over ``slot``'s cached rows up to the chunk's own, a chunk-sized block
@@ -824,8 +1090,10 @@ def mla_attend(cfg: DecoderConfig, q_nope, q_rope, latent, w_ukv, slot, pos):
     as the grid's dynamic bound, so the context shapes nothing: one
     executable. The slab is handed over with its positions last: the
     order a TPU keeps an array in whose rows are not whole lanes (kv_rank
-    + rope = 576), so no copy is made of it. Returns [heads, T, v]
-    bfloat16."""
+    + rope = 576), so no copy is made of it. ``chosen`` [T, positions]
+    int32 (an indexer's choice, not 0: chosen) masks each query to its
+    chosen rows: every visible block is still computed. Returns [heads,
+    T, v] bfloat16."""
     H, T, nope = q_nope.shape
     rk, v = cfg.kv_rank, cfg.v_dim
     lowering = mla_lowering()
@@ -835,6 +1103,7 @@ def mla_attend(cfg: DecoderConfig, q_nope, q_rope, latent, w_ukv, slot, pos):
             f"prefill_chunk % 128 must be 0 for latent attention on a TPU, not {T}: "
             "the kernel reads a chunk of cached positions as whole lanes")
     G = math.gcd(H, MLA_HEAD_GROUP)
+    masks = () if chosen is None else (chosen,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(H // G, pos // T + 1),
@@ -843,7 +1112,7 @@ def mla_attend(cfg: DecoderConfig, q_nope, q_rope, latent, w_ukv, slot, pos):
             pl.BlockSpec((G, T, cfg.rope_dim), lambda g, b, at: (g, 0, 0)),
             pl.BlockSpec((1, cfg.latent_width, T), lambda g, b, at: (at[0], 0, b)),
             pl.BlockSpec((G * (nope + v), rk), lambda g, b, at: (g, 0)),
-        ],
+        ] + [pl.BlockSpec((T, T), lambda g, b, at: (0, b)) for _ in masks],
         out_specs=pl.BlockSpec((G, T, v), lambda g, b, at: (g, 0, 0)),
         scratch_shapes=[pltpu.VMEM((G, T, 1), _f32), pltpu.VMEM((G, T, 1), _f32),
                         pltpu.VMEM((G, T, v), _f32)],
@@ -858,38 +1127,96 @@ def mla_attend(cfg: DecoderConfig, q_nope, q_rope, latent, w_ukv, slot, pos):
         interpret=lowering == "interpret",
         name="mla_prefill_attention",
     )(jnp.stack([slot, pos]).astype(jnp.int32), q_nope, q_rope,
-      jnp.swapaxes(latent, 1, 2), w_ukv.T)
+      jnp.swapaxes(latent, 1, 2), w_ukv.T, *masks)
 
 
-def mla_prefill(cfg: DecoderConfig, p: dict, u, latent, slot, pos, n):
+def index_prefill(cfg: DecoderConfig, p: dict, u, c_q, keys, slot, pos, n):
+    """One chunk through a layer's indexer: its keys go into ``keys``
+    [slots + 1, positions, index_dim] at ``slot`` (a padded position
+    writes nothing); its queries score the slot's cached keys up to the
+    chunk's own, a chunk-sized block at a time, and each keeps its
+    ``index_topk`` best visible rows. Returns (chosen [T, positions] int32,
+    1 chosen; the index scores [T, positions] float32, 0 past the chunk's
+    last block; keys)."""
+    T, P = u.shape[0], keys.shape[1]
+    at = pos + jnp.arange(T)
+    q, w, k = index_project(cfg, p, u, c_q, at)
+    old = jax.lax.dynamic_slice(keys, (slot, pos, 0), (1, T, cfg.index_dim))
+    real = (jnp.arange(T) < n)[None, :, None]
+    keys = jax.lax.dynamic_update_slice(keys, jnp.where(real, k[None], old), (slot, pos, 0))
+
+    def block(b, scores):
+        cached = jax.lax.dynamic_slice(keys, (slot, b * T, 0), (1, T, cfg.index_dim))[0]
+        return jax.lax.dynamic_update_slice(scores, _index_block(q, w, cached), (0, b * T))
+
+    scores = jax.lax.fori_loop(0, pos // T + 1, block, jnp.zeros((T, P), _f32))
+    visible = jnp.arange(P)[None, :] <= at[:, None]
+    return select_rows(scores, visible, cfg.index_topk).astype(jnp.int32), scores, keys
+
+
+def index_decode(cfg: DecoderConfig, p: dict, u, c_q, keys, slots, pos):
+    """One token a sequence through a layer's indexer, ``decode_rows``
+    cached keys at a time up to the batch's longest context. Returns
+    (chosen [B, positions] bool, the index scores [B, positions], keys)."""
+    B, P, R = u.shape[0], keys.shape[1], cfg.decode_rows
+    q, w, k = index_project(cfg, p, u, c_q, pos)
+    for i in range(B):
+        keys = jax.lax.dynamic_update_slice(keys, k[i][None, None], (slots[i], pos[i], 0))
+
+    def block(b, scores):
+        cached = jax.vmap(lambda s: jax.lax.dynamic_slice(
+            keys, (s, b * R, 0), (1, R, cfg.index_dim))[0])(slots)          # [B, R, D]
+        return jax.lax.dynamic_update_slice(scores, _index_block(q, w, cached), (0, b * R))
+
+    scores = jax.lax.fori_loop(0, jnp.max(pos) // R + 1, block, jnp.zeros((B, P), _f32))
+    visible = jnp.arange(P)[None, :] <= pos[:, None]
+    return select_rows(scores, visible, cfg.index_topk), scores, keys
+
+
+def mla_prefill(cfg: DecoderConfig, p: dict, u, latent, slot, pos, n, *,
+                index=None, chosen=None):
     """Latent attention over one chunk at positions ``pos``.., the
     expanded path: the chunk's cache rows go into ``latent`` [slots + 1,
     positions, kv_rank + rope] at ``slot`` (a padded position writes
     nothing); then ``mla_attend`` expands keys and values from the cached
     rows through ``w_ukv`` and the chunk's queries attend to them
-    causally. Returns (out [T, hidden], latent)."""
+    causally. Returns (out [T, hidden], latent). Under an indexer: a
+    layer that owns one (``index``: its key slab) chooses each query's
+    rows first (``index_prefill``), a layer that shares one is handed the
+    choice (``chosen``); the queries attend the chosen rows, and the
+    result ends with what the indexer made: (out, latent, (chosen, index
+    scores or None, index keys or None))."""
     T, H = u.shape[0], cfg.heads
-    q_nope, q_rope, rows = _mla_project(cfg, p, u, pos + jnp.arange(T))
+    q_nope, q_rope, rows, c_q = _mla_project(cfg, p, u, pos + jnp.arange(T))
     old = jax.lax.dynamic_slice(latent, (slot, pos, 0), (1, T, cfg.latent_width))
     real = (jnp.arange(T) < n)[None, :, None]
     latent = jax.lax.dynamic_update_slice(
         latent, jnp.where(real, rows[None], old), (slot, pos, 0))
+    scores = None
+    if index is not None:
+        chosen, scores, index = index_prefill(cfg, p, u, c_q, index, slot, pos, n)
     mixed = mla_attend(cfg, jnp.transpose(q_nope, (1, 0, 2)), jnp.transpose(q_rope, (1, 0, 2)),
-                       latent, p["w_ukv"], slot, pos)
+                       latent, p["w_ukv"], slot, pos, chosen)
     out = jnp.einsum("htd,hdo->to", mixed, p["wo"].reshape(H, cfg.v_dim, -1),
                      preferred_element_type=_f32)
-    return out, latent
+    if chosen is None:
+        return out, latent
+    return out, latent, (chosen, scores, index)
 
 
-def mla_decode(cfg: DecoderConfig, p: dict, u, latent, slots, pos):
+def mla_decode(cfg: DecoderConfig, p: dict, u, latent, slots, pos, *,
+               index=None, chosen=None):
     """One token a sequence, the absorbed path: ``w_ukv``'s key half goes
     into the query (``q~ = q_nope W_UK^T``, kv_rank wide) and its value
     half onto the output, so the scores and the weighted sum run over the
     latent rows themselves, ``decode_rows`` of them at a time up to the
     batch's longest context. ``u`` [B, hidden]; ``latent`` the whole
-    slab; ``slots``, ``pos`` [B]. Returns (out [B, hidden], latent)."""
+    slab; ``slots``, ``pos`` [B]. Returns (out [B, hidden], latent).
+    Under an indexer, as ``mla_prefill``: ``chosen`` [B, positions] bool
+    masks each block, and the result ends with (chosen, index scores or
+    None, index keys or None)."""
     B, H, rk, R = u.shape[0], cfg.heads, cfg.kv_rank, cfg.decode_rows
-    q_nope, q_rope, rows = _mla_project(cfg, p, u, pos)
+    q_nope, q_rope, rows, c_q = _mla_project(cfg, p, u, pos)
     for i in range(B):      # a row at a time: a scatter of B rows copied the whole slab
         latent = jax.lax.dynamic_update_slice(
             latent, rows[i][None, None], (slots[i], pos[i], 0))
@@ -897,6 +1224,9 @@ def mla_decode(cfg: DecoderConfig, p: dict, u, latent, slots, pos):
     q_abs = jnp.einsum("bhd,chd->bhc", q_nope, w[..., :cfg.nope_dim],
                        preferred_element_type=_f32).astype(_bf16)
     scale = mla_scale(cfg)
+    scores = None
+    if index is not None:
+        chosen, scores, index = index_decode(cfg, p, u, c_q, index, slots, pos)
 
     def block(b, carry):
         rows_b = jax.vmap(lambda s: jax.lax.dynamic_slice(
@@ -905,10 +1235,13 @@ def mla_decode(cfg: DecoderConfig, p: dict, u, latent, slots, pos):
         s = s + jnp.einsum("bhr,bpr->bhp", q_rope, rows_b[..., rk:],
                            preferred_element_type=_f32)
         visible = (b * R + jnp.arange(R))[None, :] <= pos[:, None]
+        if chosen is not None:
+            visible = visible & jax.lax.dynamic_slice_in_dim(chosen, b * R, R, axis=1)
         return _softmax_step(
             carry, s * scale, visible[:, None, :],
             lambda w_: jnp.einsum("bhp,bpc->bhc", w_, rows_b[..., :rk],
-                                  preferred_element_type=_f32))
+                                  preferred_element_type=_f32),
+            sparse=chosen is not None)
 
     start = (jnp.full((B, H), _MASKED, _f32), jnp.zeros((B, H), _f32),
              jnp.zeros((B, H, rk), _f32))
@@ -916,7 +1249,10 @@ def mla_decode(cfg: DecoderConfig, p: dict, u, latent, slots, pos):
     mixed = (acc / l[..., None]).astype(_bf16)
     out = jnp.einsum("bhc,chd->bhd", mixed, w[..., cfg.nope_dim:],
                      preferred_element_type=_f32)
-    return _mm(out.reshape(B, H * cfg.v_dim), p["wo"]), latent
+    out = _mm(out.reshape(B, H * cfg.v_dim), p["wo"])
+    if chosen is None:
+        return out, latent
+    return out, latent, (chosen, scores, index)
 
 
 def _logits(cfg: DecoderConfig, params: dict, x):
@@ -946,14 +1282,25 @@ def prefill_chunk(cfg: DecoderConfig, params: dict, state: list, slot, ids, pos,
     (state, greedy next id, logits [held rows] at the chunk's last real
     position, counts, a row an expert layer: ``sel`` [expert layers, T,
     k], ``counts`` [expert layers, held experts], ``absent`` [expert
-    layers])."""
+    layers]); and, under an indexer, what the layers that own one chose:
+    ``{"chosen": [index layers, T, positions / 8] uint8, each query's
+    chosen rows as bits (``pack_rows``: 2.6 MB a chunk at the published
+    sizes; the index scores, 84 MB, are not returned), "kept": [index
+    layers] int32, the rows the real queries kept, counted on the mask
+    the attention is handed}``."""
     x = _embed(cfg, params, ids)
     live = jnp.arange(ids.shape[0]) < n
     r = cfg.residual_multiplier
-    new_state, counted = [], []
-    for (kind, ffn), p, s in zip(cfg.layers, params["layers"], state):
+    new_state, counted, indexed, chosen = [], [], [], None
+    for (kind, ffn), index, p, s in zip(cfg.layers, cfg.index_types, params["layers"], state):
         u = rms_norm(x, p["norm1"], cfg.rms_eps)
-        if kind == MLA:     # reads and writes its slot's rows inside the slab
+        if index is not None:   # a choice travels from the layer that made it to those that share it
+            out, latent, (chosen, scores, keys) = mla_prefill(
+                cfg, p, u, s["latent"], slot, pos, n, index=s.get("index"), chosen=chosen)
+            if index == FULL:
+                indexed.append({"chosen": pack_rows(chosen),
+                                "kept": jnp.sum(jnp.where(live[:, None], chosen, 0))})
+        elif kind == MLA:     # reads and writes its slot's rows inside the slab
             out, latent = mla_prefill(cfg, p, u, s["latent"], slot, pos, n)
         else:
             mine = {nm: jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False)
@@ -970,25 +1317,38 @@ def prefill_chunk(cfg: DecoderConfig, params: dict, state: list, slot, ids, pos,
             counted.append(counts)
         else:
             x = dense_block(cfg, p, x + r * out)
-        new_state.append({"latent": latent} if kind == MLA else {
-            nm: jax.lax.dynamic_update_index_in_dim(s[nm], new, slot, 0)
-            for nm, new in mine.items()})
+        if index == FULL:
+            new_state.append({"latent": latent, "index": keys})
+        else:
+            new_state.append({"latent": latent} if kind == MLA else {
+                nm: jax.lax.dynamic_update_index_in_dim(s[nm], new, slot, 0)
+                for nm, new in mine.items()})
     last = jax.lax.dynamic_index_in_dim(x, n - 1, 0, keepdims=False)
     logits = _logits(cfg, params, last)
-    return new_state, jnp.argmax(logits).astype(jnp.int32), logits, _stacked(counted)
+    out = (new_state, jnp.argmax(logits).astype(jnp.int32), logits, _stacked(counted))
+    return out + (_stacked(indexed),) if indexed else out
 
 
 def decode_step(cfg: DecoderConfig, params: dict, state: list, slots, ids, pos, live):
     """One token for each row: ``slots``, ``ids``, ``pos``, ``live`` [B]
     (a padded row is not live: it reads and writes the scratch slot and
     routes nowhere). Returns (state, next ids [B] (greedy), logits
-    [B, held rows], counts as ``prefill_chunk`` with T = B)."""
+    [B, held rows], counts as ``prefill_chunk`` with T = B and, under an
+    indexer, ``{"scores": [index layers, B, positions] float32, "chosen":
+    the same shape, bool, "kept": [index layers] int32 over the live
+    rows}``)."""
     x = _embed(cfg, params, ids)
     r = cfg.residual_multiplier
-    new_state, counted = [], []
-    for (kind, ffn), p, s in zip(cfg.layers, params["layers"], state):
+    new_state, counted, indexed, chosen = [], [], [], None
+    for (kind, ffn), index, p, s in zip(cfg.layers, cfg.index_types, params["layers"], state):
         u = rms_norm(x, p["norm1"], cfg.rms_eps)
-        if kind == MLA:
+        if index is not None:
+            out, latent, (chosen, scores, keys) = mla_decode(
+                cfg, p, u, s["latent"], slots, pos, index=s.get("index"), chosen=chosen)
+            if index == FULL:
+                indexed.append({"scores": scores, "chosen": chosen, "kept": jnp.sum(
+                    chosen & live[:, None], dtype=jnp.int32)})
+        elif kind == MLA:
             out, latent = mla_decode(cfg, p, u, s["latent"], slots, pos)
         elif kind == MAMBA:
             out, tail, ssm = mamba_decode(cfg, p, u, s["tail"][slots], s["ssm"][slots])
@@ -1002,11 +1362,14 @@ def decode_step(cfg: DecoderConfig, params: dict, state: list, slots, ids, pos, 
             counted.append(counts)
         else:
             x = dense_block(cfg, p, x + r * out)
-        new_state.append({"latent": latent} if kind == MLA else {
-            nm: s[nm].at[slots].set(new) for nm, new in mine.items()})
+        if index == FULL:
+            new_state.append({"latent": latent, "index": keys})
+        else:
+            new_state.append({"latent": latent} if kind == MLA else {
+                nm: s[nm].at[slots].set(new) for nm, new in mine.items()})
     logits = _logits(cfg, params, x)
-    return (new_state, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits,
-            _stacked(counted))
+    out = (new_state, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, _stacked(counted))
+    return out + (_stacked(indexed),) if indexed else out
 
 
 def empty_state(cfg: DecoderConfig) -> list:
@@ -1014,7 +1377,7 @@ def empty_state(cfg: DecoderConfig) -> list:
     (the last slot is the scratch slot)."""
     S = cfg.slots + 1
     out = []
-    for kind in cfg.layer_types:
+    for kind, index in zip(cfg.layer_types, cfg.index_types):
         if kind == MAMBA:
             out.append({
                 "tail": jnp.zeros((S, cfg.mamba_conv - 1, cfg.conv_width), _bf16),
@@ -1026,6 +1389,8 @@ def empty_state(cfg: DecoderConfig) -> list:
             out.append({"keys": jnp.zeros(shape, _bf16), "values": jnp.zeros(shape, _bf16)})
         else:
             out.append({"latent": jnp.zeros((S, cfg.max_positions, cfg.latent_width), _bf16)})
+            if index == FULL:   # the indexer's keys, a row a position beside the latent row
+                out[-1]["index"] = jnp.zeros((S, cfg.max_positions, cfg.index_dim), _bf16)
     return out
 
 
@@ -1041,10 +1406,10 @@ def _zero_slot(state: list, slot):
 
 
 def _layer_matrix_params(cfg: DecoderConfig, kind: str, experts: float,
-                         ffn: str = MOE) -> float:
+                         ffn: str = MOE, index: str | None = None) -> float:
     """Matrix parameters one token multiplies through in one layer, with
     ``experts`` routed experts a token where the layer has them."""
-    shapes = layer_shapes(cfg, kind, ffn)
+    shapes = layer_shapes(cfg, kind, ffn, index)
     dense = sum(
         float(np.prod(s)) for name, s in shapes.items()
         if len(s) == 2 and name != "conv_w"
@@ -1059,10 +1424,11 @@ def _layer_matrix_params(cfg: DecoderConfig, kind: str, experts: float,
 def flops_per_token(cfg: DecoderConfig) -> float:
     """Forward FLOPs of one token through the held share: two a matrix
     parameter, the routed experts at this chip's expected share of the
-    ``experts_per_token`` selections; the head, and attention over the
-    context, are not included."""
+    ``experts_per_token`` selections; the head, and attention and the
+    indexer's scores over the context, are not included."""
     share = cfg.experts_per_token * cfg.experts_held[1] / cfg.experts
-    return 2.0 * sum(_layer_matrix_params(cfg, k, share, f) for k, f in cfg.layers)
+    return 2.0 * sum(_layer_matrix_params(cfg, k, share, f, i)
+                     for (k, f), i in zip(cfg.layers, cfg.index_types))
 
 
 def prefill_cost_model(cfg: DecoderConfig) -> tuple[float, float]:
@@ -1102,10 +1468,11 @@ device_site(
 
 
 class StateCache:
-    """Per-sequence state of the three kinds, by slot: convolution tail
+    """Per-sequence state of every kind, by slot: convolution tail
     and SSM state for each Mamba layer (constant in length), keys/values
-    for each attention layer and latent rows for each MLA layer (growing,
-    up to ``max_positions``). ``acquire``
+    for each attention layer, latent rows for each MLA layer and the index
+    keys of each layer that owns an indexer (growing, up to
+    ``max_positions``). ``acquire``
     zeroes a slot and hands it out; with every slot out it waits.
     ``release`` gives it back. ``state`` is the device arrays (the jitted
     programs donate and return them); ``scratch`` is the extra slot the
@@ -1154,9 +1521,13 @@ class Generation:
     tokens, k], ``decode_routes`` [layers, new tokens - 1, k], a row an
     expert layer), ``ssm`` (the slot's final SSM state, a device array a
     Mamba layer) and ``latent`` (the slot's cache rows, a device array
-    [max_positions, kv_rank + rope] an MLA layer; the sequence's are the
+    [max_positions, kv_rank + rope] an MLA layer, then [max_positions,
+    index_dim] a layer that owns an indexer; the sequence's are the
     first prompt + new tokens - 1) only for the rows ``generate`` was
-    asked to keep."""
+    asked to keep. Under an indexer such a row also keeps ``indexed``:
+    what its dispatches returned of the indexers' work, on the device
+    as ``ssm`` and ``latent`` are (the reply does not wait for it);
+    ``choices()`` fetches it."""
 
     prompt: np.ndarray
     tokens: np.ndarray
@@ -1165,6 +1536,27 @@ class Generation:
     decode_routes: np.ndarray | None = None
     ssm: list | None = None
     latent: list | None = None
+    indexed: dict | None = None
+
+    def choices(self) -> tuple[np.ndarray, np.ndarray | None]:
+        """(every query's chosen rows as bits [index layers, prompt + new
+        tokens - 1, max_positions / 8] uint8, ``unpack_rows`` undoes them;
+        the index scores of the decode steps' queries [index layers, new
+        tokens - 1, max_positions] float32: a prefill chunk's would be 84
+        MB at the published sizes and are not kept)."""
+        got = jax.device_get(self.indexed)
+        row = got["row"]
+        chosen = [bits[:, :n] for bits, n in zip(got["chunks"], got["real"])]
+        chosen += [pack_rows(step["chosen"][:, row:row + 1]) for step in got["steps"]]
+        scores = [step["scores"][:, row:row + 1] for step in got["steps"]]
+        return np.concatenate(chosen, axis=1), np.concatenate(scores, axis=1) if scores else None
+
+
+def _pairs(before: int, n: int, keep: int = 0) -> int:
+    """(query, cached position) pairs of ``n`` queries after ``before``
+    positions, each over its own context or at most ``keep`` rows of it."""
+    contexts = before + 1 + np.arange(n, dtype=np.int64)
+    return int((np.minimum(contexts, keep) if keep else contexts).sum())
 
 
 class Counters:
@@ -1185,6 +1577,17 @@ class Counters:
         self.attended_positions_prefill = 0
         self.attended_positions_decode = 0
         self.latent_rows = 0                        # cache rows written, MLA layers summed
+        # under an indexer: pairs one layer that owns it scored (the
+        # host's count of the visible ones), pairs one layer attended after
+        # the choice (what attended_positions counts too: the rows of the
+        # mask, COUNTED ON THE DEVICE and fetched with a call's routing
+        # counts, the mean over the layers), index key rows written (the
+        # owning layers summed)
+        self.indexed_positions_prefill = 0
+        self.indexed_positions_decode = 0
+        self.selected_positions_prefill = 0
+        self.selected_positions_decode = 0
+        self.index_rows = 0
 
     def add_routing(self, counts: np.ndarray, absent: np.ndarray) -> None:
         self.expert_tokens += counts
@@ -1212,9 +1615,9 @@ class AnswerModel:
         def answer_decode(params, state, slots, ids, pos, live):
             return decode_step(cfg, params, state, slots, ids, pos, live)
 
-        def slot_latent(state, slot):
-            return [jax.lax.dynamic_index_in_dim(s["latent"], slot, 0, keepdims=False)
-                    for s in state if "latent" in s]
+        def slot_latent(state, slot):     # the latent rows, then the index keys
+            return [jax.lax.dynamic_index_in_dim(s[name], slot, 0, keepdims=False)
+                    for name in ("latent", "index") for s in state if name in s]
 
         self._prefill = jax.jit(answer_prefill, donate_argnums=1)
         self._decode = jax.jit(answer_decode, donate_argnums=1)
@@ -1247,38 +1650,56 @@ class AnswerModel:
                     transfer_bytes=nbytes_of(*args))
         return out
 
-    def _prefill_prompt(self, slot: int, ids: np.ndarray):
+    def _prefill_prompt(self, slot: int, ids: np.ndarray, kept: bool = False):
         """Every chunk of one prompt; (first generated id, logits at the
-        prompt's last position, [(real positions, counts) a chunk])."""
-        T = self.cfg.prefill_chunk
-        out, counted = None, []
+        prompt's last position, [(real positions, counts, rows the
+        indexers' queries kept or None) a chunk], and of a ``kept`` row
+        under an indexer every chunk's choice of rows as bits, still on
+        the device)."""
+        T, keep = self.cfg.prefill_chunk, self.cfg.index_topk
+        out, counted, packed = None, [], []
         for at in range(0, len(ids), T):
             n = min(T, len(ids) - at)
             chunk = np.zeros(T, np.int32)
             chunk[:n] = ids[at:at + n]
+            # position at + i sees at + i + 1 cached positions, and attends
+            # them all or, under an indexer, the index_topk it is to keep of
+            # them: what the host can say when it queues the chunk (the
+            # counters hold what the device kept)
+            seen, attended = _pairs(at, n), _pairs(at, n, keep)
             # latent attention: the cached-row blocks the chunk goes over, a
             # layer, and on the site's first dispatch what lowered its kernel
             latent_attention = {
                 "blocks": at // T + 1, "first_says": {"kernel": mla_lowering()},
             } if self._mla_layers else {}
-            *out, counts = self._dispatch(
+            if keep:    # pairs a layer that owns an indexer scores, pairs a layer is to attend
+                latent_attention.update(scored=seen, selected=attended)
+                latent_attention["first_says"].update(
+                    selection="bisection", attention="masked-dense")
+            out = self._dispatch(
                 "answer.prefill", self._prefill, T,
                 np.int32(slot), chunk, np.int32(at), np.int32(n),
                 chunk=at // T, real=n, padded=T - n, context=at + n, **latent_attention,
             )
-            counted.append((n, counts))
+            counted.append((n, out[2], out[3]["kept"] if keep else None))
             self.counters.prefill_real += n
             self.counters.prefill_padded += T - n
-            # position at + i attends at + i + 1 cached positions
-            self.counters.attended_positions_prefill += n * at + n * (n + 1) // 2
-        return out[0], out[1], counted
+            if keep:
+                self.counters.indexed_positions_prefill += seen
+                if kept:    # a reference held: the other rows' bits are freed as they come
+                    packed.append(out[3]["chosen"])
+            else:
+                self.counters.attended_positions_prefill += attended
+        return out[0], out[1], counted, packed
 
     def generate(self, prompts: Sequence[Sequence[int]], max_new_tokens: int,
                  keep: Sequence[int] = ()) -> list[Generation]:
         """Greedy continuations of ``prompts`` (token ids of the held
         vocabulary rows), ``max_new_tokens`` each. At most ``slots``
         prompts a call. The rows named in ``keep`` also return their
-        logits and expert selections."""
+        logits, expert selections, final state and, under an indexer,
+        every query's choice of rows (``Generation.choices``): the same
+        two programs run for them as for every other row."""
         cfg = self.cfg
         if not 1 <= len(prompts) <= cfg.slots:
             raise ValueError(f"a call takes 1..{cfg.slots} prompts, got {len(prompts)}")
@@ -1305,7 +1726,14 @@ class AnswerModel:
 
     def _generate(self, prompts, slots, max_new_tokens, keep):
         rows = len(prompts)
-        prefilled = [self._prefill_prompt(slot, ids) for slot, ids in zip(slots, prompts)]
+        topk = self.cfg.index_topk
+        prefilled, indexed = [], {}
+        for r, (slot, ids) in enumerate(zip(slots, prompts)):
+            *made, packed = self._prefill_prompt(slot, ids, r in keep)
+            prefilled.append(made)
+            if packed:
+                indexed[r] = {"row": r, "real": [n for n, *_ in made[2]],
+                              "chunks": packed, "steps": []}
         self.counters.prompts += rows
         # lock-step decode: the ids stay on the device from step to step
         bucket = next(b for b in DECODE_BUCKETS if b >= rows)
@@ -1314,37 +1742,52 @@ class AnswerModel:
         live = np.asarray([True] * rows + [False] * pad)
         lengths = np.asarray([len(p) for p in prompts] + [0] * pad, np.int32)
         ids = jnp.stack([p[0] for p in prefilled] + [jnp.int32(0)] * pad)
-        routing = ("counts", "absent")
+
+        def counts_of(counts, kept):    # a dispatch's routing counts and, under an indexer, rows kept
+            return {"counts": counts["counts"], "absent": counts["absent"],
+                    **({"kept": kept} if topk else {})}
+
         fetch = {
             "tokens": [ids],
-            # every dispatch's routing counts: the prefill chunks', then the steps'
-            "counts": [{k: c[k] for k in routing} for p in prefilled for _, c in p[2]],
+            # every dispatch's counts: the prefill chunks', then the steps'
+            "counts": [counts_of(c, kept) for p in prefilled for _, c, kept in p[2]],
             "kept": {r: {"logits": [prefilled[r][1]],
-                         "prompt_routes": [c["sel"] for _, c in prefilled[r][2]],
+                         "prompt_routes": [c["sel"] for _, c, _ in prefilled[r][2]],
                          "decode_routes": []} for r in keep},
         }
         chunks = len(fetch["counts"])
         prompt_tokens = int(lengths[:rows].sum())
+        seen = 0
         with _flight.span("answer.decode", batch=rows, bucket=bucket,
                           steps=max_new_tokens - 1):
             for step in range(max_new_tokens - 1):
-                ids, logits, counts = self._dispatch(
+                # step t's token of a sequence of n prompt tokens sees n + t + 1
+                # positions, and attends them all or the index_topk it is to keep
+                positions = prompt_tokens + rows * (step + 1)
+                seen += positions
+                ids, logits, counts, *index = self._dispatch(
                     "answer.decode", self._decode, bucket,
                     slot_ids, ids, lengths + step, live,
-                    span="answer.decode.step", batch=rows,
-                    positions=prompt_tokens + rows * (step + 1),
+                    span="answer.decode.step", batch=rows, positions=positions,
+                    **({"selected": int(np.minimum(lengths[:rows] + step + 1, topk).sum())}
+                       if topk else {}),
                 )
                 fetch["tokens"].append(ids)
-                fetch["counts"].append({k: counts[k] for k in routing})
+                fetch["counts"].append(counts_of(counts, index[0]["kept"] if topk else None))
                 for r in keep:
                     fetch["kept"][r]["logits"].append(logits[r])
                     fetch["kept"][r]["decode_routes"].append(counts["sel"][:, r])
+                for r in indexed:
+                    indexed[r]["steps"].append(
+                        {"scores": index[0]["scores"], "chosen": index[0]["chosen"]})
         steps = max_new_tokens - 1
         self.counters.decode_steps[rows] = self.counters.decode_steps.get(rows, 0) + steps
-        # step t's token of a sequence of n prompt tokens attends n + t + 1 positions
-        self.counters.attended_positions_decode += (
-            steps * prompt_tokens + rows * steps * (steps + 1) // 2)
         self.counters.latent_rows += self._mla_layers * (prompt_tokens + rows * steps)
+        if topk:
+            self.counters.indexed_positions_decode += seen
+            self.counters.index_rows += self.cfg.index_layers * (prompt_tokens + rows * steps)
+        else:
+            self.counters.attended_positions_decode += seen
         final_ssm = {
             r: [s["ssm"][slots[r]] for s in self.cache.state if "ssm" in s]
             for r in keep
@@ -1365,15 +1808,25 @@ class AnswerModel:
             self.counters.add_routing(c["counts"], c["absent"])
         for c in got["counts"][chunks:]:
             self.counters.decode_experts_touched += int((c["counts"] > 0).sum())
+        if topk:    # the rows the device kept, a layer: each owner's over the layers that attend it
+            spans = np.asarray(self.cfg.index_spans, np.int64)
+            in_prefill, in_decode = (
+                int(sum(c["kept"] @ spans for c in part)) // int(spans.sum())
+                for part in (got["counts"][:chunks], got["counts"][chunks:]))
+            self.counters.selected_positions_prefill += in_prefill
+            self.counters.attended_positions_prefill += in_prefill
+            self.counters.selected_positions_decode += in_decode
+            self.counters.attended_positions_decode += in_decode
         tokens = np.stack(got["tokens"], axis=1)                    # [bucket, new]
         out = [Generation(prompt=prompts[r], tokens=tokens[r]) for r in range(rows)]
         for r, kept in got["kept"].items():
             gen = out[r]
             gen.ssm = final_ssm[r]
             gen.latent = final_latent.get(r)
+            gen.indexed = indexed.get(r)
             gen.logits = np.stack(kept["logits"])
             gen.prompt_routes = np.concatenate(
-                [sel[:, :n] for sel, (n, _) in zip(kept["prompt_routes"], prefilled[r][2])],
+                [sel[:, :n] for sel, (n, *_) in zip(kept["prompt_routes"], prefilled[r][2])],
                 axis=1)
             gen.decode_routes = np.stack(kept["decode_routes"], axis=1) \
                 if kept["decode_routes"] else gen.prompt_routes[:, :0]

@@ -1,13 +1,20 @@
 #!/usr/bin/env python3
 """An answer model at its published widths on the chip, outside the
 server: one chip's share of an answer cell's configuration (``--workload``:
-granite-4.0-h-small.answer-steady, DeepSeek-V2.answer-long) made from a
-seed, a few prompts prefilled in chunks and decoded through the cache,
-held to the configuration's plain reference's one full forward; times a
-request alone; prints the device's memory peak.
+granite-4.0-h-small.answer-steady, DeepSeek-V2.answer-long,
+GLM-5.2.answer-sparse) made from a seed, a few prompts prefilled in chunks
+and decoded through the cache, held to the configuration's plain
+reference's one full forward; times a request alone; prints the device's
+memory peak. Under an indexer the reference follows the served choice of
+rows on every query inside the cell's index tolerance (a kept generation
+carries them as bits; the fp8 control's own choice is judged the same way), and ``index_gap`` / ``wrong_selections`` say how
+far the two indexers lie apart (the scores: on the decode steps);
+``--alone 1`` also runs it following the choice of those last queries
+only (``kept``) and choosing for itself everywhere
+(``alone``): what near-ties between the two indexers cost each number.
 
     chiprun -- python scripts/answer_chip_check.py [--workload CELL] [--seed N] \
-        [--lengths 700,1400,3100] [--control 1]
+        [--lengths 700,1400,3100] [--control 1] [--alone 1]
 
 Refuses any backend but ``tpu``."""
 
@@ -30,6 +37,7 @@ def main() -> int:
     parser.add_argument("--seed", type=int, default=2147484001)
     parser.add_argument("--lengths", default="700,1400,3100")
     parser.add_argument("--control", type=int, default=0)
+    parser.add_argument("--alone", type=int, default=0)
     args = parser.parse_args()
     import jax
 
@@ -65,6 +73,7 @@ def main() -> int:
     prompts = [rng.integers(1000, min(26000, a["vocab_rows"]), size=n).astype(np.int32)
                for n in lengths]
     new = 32
+    sparse = bool(getattr(cfg, "index_topk", 0))
     for label in ("cold", "warm", "warm2"):
         t = time.monotonic()
         made = model.generate(prompts, new, keep=range(len(prompts)))
@@ -81,38 +90,64 @@ def main() -> int:
     print(json.dumps({"memory_peak_bytes": stats.get("peak_bytes_in_use"),
                       "bytes_in_use": stats.get("bytes_in_use")}), flush=True)
     # the state the reference gives back: final SSM states, or the
-    # sequence's own latent cache rows
+    # sequence's own latent cache rows (and index keys)
     states = [[np.asarray(layer) for layer in g.ssm] if g.latent is None else
               [np.asarray(rows[:len(g.prompt) + new - 1], np.float32) for rows in g.latent]
               for g in made]
+    tol = cell.limits.get("tolerances", {})
+    following = {}
+    if sparse:      # the served indexers' work, for the reference to follow
+        index_tol = float(tol["index"])
+        choices = [g.choices() for g in made]
+        following = {"index_tol": index_tol, "selections": [
+            {"at": np.arange(len(g.prompt) + new - 1), "chosen": chosen, "scores": scores}
+            for g, (chosen, scores) in zip(made, choices)]}
+        last = [len(g.prompt) + np.arange(new - 1) for g in made]
+        kept = {"index_tol": index_tol, "selections": [
+            {"at": at, "chosen": chosen[:, at], "scores": scores}
+            for at, (chosen, scores) in zip(last, choices)]}
     for leaf in jax.tree_util.tree_leaves((model.params, model.cache.state)):
         leaf.delete()
     seqs = [np.concatenate([g.prompt, g.tokens[:-1]]) for g in made]
     routes = [np.concatenate([g.prompt_routes, g.decode_routes], axis=1) for g in made]
-    for precision in ("f32",) + (("fp8",) if args.control else ()):
+    router_tol = float(tol.get("router", 0.05))
+    runs = ("f32",) + (("kept", "alone") if sparse and args.alone else ()) + (
+        ("fp8",) if args.control else ())
+    for precision in runs:
         t = time.monotonic()
-        out = ref.forward(a, args.seed, seqs, last=new, routes=routes if precision == "f32" else None,
-                          router_tol=0.05, precision=precision)
-        secs = round(time.monotonic() - t, 2)
         if precision == "fp8":
+            out = ref.forward(a, args.seed, seqs, last=new, router_tol=router_tol, precision="fp8",
+                              **({"keep_chosen": True} if sparse else {}))
+        else:       # "alone": the reference chooses its rows for itself on every query
+            follow = {"f32": following, "kept": kept, "alone": {}}[precision]
+            out = ref.forward(a, args.seed, seqs, last=new, routes=routes, router_tol=router_tol,
+                              **follow)
+        secs = round(time.monotonic() - t, 2)
+        if precision == "fp8":   # the control's own choice of rows is followed and judged too
+            own = {"index_tol": index_tol, "selections": [
+                {"at": np.arange(len(s)), "chosen": o["chosen"], "scores": o["index_scores"]}
+                for s, o in zip(seqs, out)]} if sparse else {}
             base = ref.forward(a, args.seed, seqs, last=new, routes=[o["routes"] for o in out],
-                               router_tol=0.05)
+                               router_tol=router_tol, **own)
         for i, (g, o) in enumerate(zip(made, out)):
-            want = (o if precision == "f32" else base[i])["logits"].astype(np.float64)
-            got = g.logits if precision == "f32" else o["logits"]
-            ref_states = (o if precision == "f32" else base[i])["states"]
-            got_states = states[i] if precision == "f32" else o["states"]
+            want = (base[i] if precision == "fp8" else o)["logits"].astype(np.float64)
+            served = precision != "fp8"
+            got = g.logits if served else o["logits"]
+            ref_states = (o if served else base[i])["states"]
+            got_states = states[i] if served else o["states"]
             spread = want.max(-1) - want.min(-1)
             gap = (np.abs(got - want).max(-1) / spread).max()
-            tok = g.tokens if precision == "f32" else got.argmax(-1)
+            tok = g.tokens if served else got.argmax(-1)
             behind = ((want.max(-1) - want[np.arange(new), tok]) / spread).max()
             state_gap = max(float(np.abs(x - y).max() / np.abs(y).max())
                             for x, y in zip(got_states, ref_states))
-            rgap = (o if precision == "f32" else base[i])
+            rgap = (o if served else base[i])
+            index = {k: rgap[k] for k in ("index_gap", "wrong_selections")
+                     if k in rgap and precision != "alone"}
             print(json.dumps({
                 "precision": precision, "tokens": len(seqs[i]), "reference_s": secs,
                 "logit_gap": float(gap), "token_gap": float(behind), "state_gap": state_gap,
-                "router_gap": rgap["router_gap"], "wrong_routes": rgap["wrong_routes"],
+                "router_gap": rgap["router_gap"], "wrong_routes": rgap["wrong_routes"], **index,
                 "logit_spread": float(spread.mean()),
                 "agree": int((tok == want.argmax(-1)).sum()),
             }), flush=True)

@@ -27,7 +27,10 @@ promises:
 5. what a prefill chunk's latent attention went over: the chunks of the
    stretch, the cached-row ``blocks`` a chunk, and the ``kernel`` the
    ``answer.prefill`` site's first dispatch says its attention was
-   lowered by (``prefill`` in the report).
+   lowered by; under an indexer the pairs a layer that owns one ``scored``
+   and the pairs a layer attended after the choice (``selected``), over
+   the stretch's chunks and decode steps, and what the first dispatch
+   says chose and attended them (``prefill`` in the report).
 
 Prints one JSON object (also under ``chiprun_out/span_clock/``). Exit 0
 when 1 and 2 hold, 1 when not, the run's own code when the run failed.
@@ -245,9 +248,11 @@ def prefill_chunks(ring, hi):
     """What the stretch's ``answer.prefill`` spans say of a chunk's latent
     attention: how many chunks, the cached-row blocks a chunk went over a
     layer (``blocks``: None from a tree whose span does not say), and
-    which lowering the site's first dispatch met (``kernel``; that
-    dispatch is set-up's, so it is looked for in all the ring still
-    holds). None for a cell that prefills nothing."""
+    which lowering the site's first dispatch met (``kernel``, and under an
+    indexer ``selection`` and ``attention``; that dispatch is set-up's, so
+    it is looked for in all the ring still holds); under an indexer the
+    (query, cached position) pairs scored and selected, chunks and decode
+    steps apart. None for a cell that prefills nothing."""
     from pathway_tpu.internals import flight
 
     chunks = [flight.args_of(s) for s in ring if s[1] == "answer.prefill"]
@@ -256,12 +261,21 @@ def prefill_chunks(ring, hi):
     blocks = [a.get("blocks") for a in chunks]
     first = [a for a in map(flight.args_of, flight.spans_between(0, hi))
              if a.get("first") and "kernel" in a]
-    return {
+    out = {
         "chunks": len(chunks),
         "blocks": None if None in blocks
         else {"min": min(blocks), "mean": statistics.mean(blocks), "max": max(blocks)},
         "kernel": first[0]["kernel"] if first else None,
     }
+    if chunks and all("scored" in a for a in chunks):
+        steps = [flight.args_of(s) for s in ring if s[1] == "answer.decode.step"]
+        out.update(
+            scored=sum(a["scored"] for a in chunks), selected=sum(a["selected"] for a in chunks),
+            decode_steps=len(steps), decode_positions=sum(a["positions"] for a in steps),
+            decode_selected=sum(a.get("selected", 0) for a in steps),
+            selection=first[0].get("selection") if first else None,
+            attention=first[0].get("attention") if first else None)
+    return out
 
 
 def main() -> int:

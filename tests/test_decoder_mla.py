@@ -184,7 +184,7 @@ def plain_mla_prefill(cfg, p, u, latent, slot, pos, n):
     loop, every block's float32 scores of every head a whole array: what
     the kernel is held to."""
     T, H, rk = u.shape[0], cfg.heads, cfg.kv_rank
-    q_nope, q_rope, rows = dec._mla_project(cfg, p, u, pos + jnp.arange(T))
+    q_nope, q_rope, rows, _ = dec._mla_project(cfg, p, u, pos + jnp.arange(T))
     old = jax.lax.dynamic_slice(latent, (slot, pos, 0), (1, T, cfg.latent_width))
     real = (jnp.arange(T) < n)[None, :, None]
     latent = jax.lax.dynamic_update_slice(
