@@ -258,8 +258,11 @@ KNOBS: dict[str, Knob] = {
            "get 504 and are evicted from the batch window.", lo=0.001,
            hi=86400),
         _k("PATHWAY_SERVE_WINDOW_MS", "float", 5.0,
-           "Dynamic batch window of the serving gateway: requests "
-           "coalesce into ONE dataflow commit until the window closes "
+           "Ceiling on the serving gateway's dynamic batch window: "
+           "requests coalesce into ONE dataflow commit while the route "
+           "has a request in flight; the window closes at once on an "
+           "idle route, when the route's last outstanding request is "
+           "answered, or after this many ms, whichever first "
            "(0 = commit per request).", lo=0, hi=60_000),
         _k("PATHWAY_SERVE_MAX_BATCH", "int", 32,
            "Close the serving batch window early once this many requests "

@@ -61,6 +61,8 @@ SERVE_LATENCY_BUCKETS_MS = (
     2500.0, 5000.0, 15000.0,
 )
 SERVE_OCCUPANCY_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
+# what closed a serving gateway's batch window (serve_window_closes_total)
+SERVE_WINDOW_CLOSE_CAUSES = ("idle", "answered", "timer", "full")
 
 # event-time lag watermarks (flight recorder, ISSUE 8): commit→emit
 # freshness per output — sub-ms fused chains up to multi-minute backlogs
@@ -151,6 +153,10 @@ class ServeMetrics:
     window_wait: _Histogram = field(
         default_factory=lambda: _Histogram(SERVE_LATENCY_BUCKETS_MS)
     )
+    # batch windows closed, by trigger (io/http/_server.py _close_window)
+    window_closes: dict = field(
+        default_factory=lambda: dict.fromkeys(SERVE_WINDOW_CLOSE_CAUSES, 0)
+    )
 
     def on_request(self) -> None:
         self.requests += 1
@@ -182,6 +188,12 @@ class ServeMetrics:
         fused device dispatch downstream)."""
         self.commits += 1
         self.occupancy.observe(occupancy)
+
+    def on_window_close(self, by: str) -> None:
+        """One batch window closed, by its trigger: ``idle`` (the route
+        had nothing in flight), ``answered`` (its last outstanding
+        request was answered or left), ``timer`` or ``full``."""
+        self.window_closes[by] += 1
 
 
 @dataclass
@@ -1021,6 +1033,13 @@ class ProberStats:
                 for sm in self.serve:
                     lines.append(
                         f'{metric}{{route="{sm.route}"}} {getattr(sm, attr)}'
+                    )
+            lines.append("# TYPE serve_window_closes_total counter")
+            for sm in self.serve:
+                for by, n in sm.window_closes.items():
+                    lines.append(
+                        f'serve_window_closes_total{{route="{sm.route}",'
+                        f'by="{by}"}} {n}'
                     )
             lines.append("# TYPE serve_breaker_state gauge")
             for sm in self.serve:

@@ -8,8 +8,13 @@ response-writer table produces the row with the same id.
 
 Serving gateway (ROADMAP item 1 — serve at the device bound): requests do
 NOT commit one-by-one. Each admitted request joins the route's dynamic
-batch window; the window closes on ``PATHWAY_SERVE_WINDOW_MS`` elapsed or
-``PATHWAY_SERVE_MAX_BATCH`` collected — whichever first — and the whole
+batch window, which closes at the earliest of: at once, when the route
+has no dispatched request still waiting for its response (``idle``);
+when the route's last outstanding request is answered (``answered``);
+``PATHWAY_SERVE_WINDOW_MS`` after its first request (``timer``: the
+window is a ceiling on the wait, not a fixed delay); or at
+``PATHWAY_SERVE_MAX_BATCH`` collected (``full``). Batching is worth a
+wait only while a step is in flight. The whole
 window enters the dataflow as ONE commit (= one dataflow timestamp = one
 BSP round = one fused KNN+rerank device dispatch downstream, because the
 external-index operator batches queries per timestamp). Responses fan out
@@ -328,9 +333,10 @@ class RestServerSubject(ConnectorSubject):
     """Request-coalescing serving gateway over the python connector.
 
     Pipeline per request: admission (bounded; overflow shed with 503 +
-    Retry-After) → dynamic batch window (closes on
-    ``PATHWAY_SERVE_WINDOW_MS`` or ``PATHWAY_SERVE_MAX_BATCH``, whichever
-    first) → a dispatch worker turns the window into upserts + ONE
+    Retry-After) → dynamic batch window (closes at once on an idle
+    route, else when the route's outstanding requests are answered, on
+    ``PATHWAY_SERVE_WINDOW_MS`` or at ``PATHWAY_SERVE_MAX_BATCH``,
+    whichever first) → a dispatch worker turns the window into upserts + ONE
     ``commit()`` (one dataflow timestamp, one fused device dispatch
     downstream) → the response table's batched subscribe callback
     resolves the whole window's futures in one cross-thread hop.
@@ -818,8 +824,7 @@ class RestServerSubject(ConnectorSubject):
             pending.evicted = True
             raise
         finally:
-            self._inflight -= 1
-            self._tasks.pop(key, None)
+            self._leave(key)
         metrics.on_latency_ms(
             (_time.monotonic_ns() - pending.admitted_at) / 1e6
         )
@@ -859,32 +864,59 @@ class RestServerSubject(ConnectorSubject):
         return max(1, min(60, math.ceil(self._inflight / qps)))
 
     # -- batch window (event-loop thread) ---------------------------------
+    def _route_idle(self) -> bool:
+        """No dispatched request of this route still waits for its
+        response: every admitted, unanswered request is a live member of
+        the collecting window. A request the route cannot account for —
+        parked or aborted at a rollback, or filtered by the pipeline and
+        never answered — stays in ``_inflight`` until its handler
+        returns, so the route reads busy and the timer decides."""
+        live = sum(1 for p in self._window if not p.evicted)
+        return live > 0 and self._inflight <= live
+
+    def _leave(self, key: Pointer) -> None:
+        """An admitted request's handler is done with it (answered, timed
+        out or gone). If it was the route's last outstanding request,
+        what collected meanwhile need not wait out its timer."""
+        self._inflight -= 1
+        self._tasks.pop(key, None)
+        if self._window and self._route_idle():
+            self._close_window(self._window, "answered")
+
     def _join_window(self, pending: _PendingRequest) -> None:
         self._ensure_gateway()  # first request may beat the run() thread
         self._window.append(pending)
-        if self.window_s <= 0 or len(self._window) >= self.max_batch:
-            self._close_window(self._window)
-            return
-        if len(self._window) == 1:
+        if len(self._window) >= self.max_batch:
+            self._close_window(self._window, "full")
+        elif self.window_s <= 0:
+            self._close_window(self._window, "timer")
+        elif self._route_idle():
+            self._close_window(self._window, "idle")
+        elif len(self._window) == 1:
             self._window_timer = asyncio.get_event_loop().call_later(
-                self.window_s, self._close_window, self._window
+                self.window_s, self._close_window, self._window, "timer"
             )
 
-    def _close_window(self, window: list) -> None:
+    def _close_window(self, window: list, by: str) -> None:
+        """Close the collecting window; ``by`` is the trigger (``idle``,
+        ``answered``, ``timer`` or ``full``), counted by
+        ``ServeMetrics.on_window_close`` and carried on every member's
+        ``gateway.queue`` span as ``closed``."""
         if window is not self._window:
-            return  # already closed by the max-batch trigger
+            return  # already closed by an earlier trigger
         if self._window_timer is not None:
             self._window_timer.cancel()
             self._window_timer = None
         self._window = []
         self._window_seq += 1
+        self.serve_metrics.on_window_close(by)
         now = _time.monotonic_ns()
         for p in window:
             p.t_closed = now
             p.window = self._window_seq
             _flight.note_span(
                 "gateway.queue", p.admitted_at, now, trace_id=int(p.key),
-                parent=p.span_id, window=self._window_seq,
+                parent=p.span_id, window=self._window_seq, closed=by,
             )
         self._windows_q.put(window)
 
@@ -1123,7 +1155,9 @@ def rest_connector(
     batched callback per delivered window.
 
     The gateway coalesces requests into batch windows (``window_ms`` /
-    ``max_batch``, defaulting to the registered serve knobs) and
+    ``max_batch``, defaulting to the registered serve knobs; a window
+    closes at once while the route has nothing in flight, so
+    ``window_ms`` is a ceiling on the wait) and
     commits one dataflow timestamp per window, so
     ``autocommit_duration_ms`` defaults to None — the window IS the
     commit cadence, and a timer flush racing a window's upserts would

@@ -34,6 +34,10 @@ promises:
    grouped product of the routed experts (``experts``: ``pallas`` or
    ``ragged_dot``) each answer site's first dispatch says its program
    took, the prefill's and decode's by bucket (``prefill.experts``).
+6. what closed the gateway's batch windows: the stretch's windows by the
+   ``closed`` arg of their ``gateway.queue`` spans (``idle``,
+   ``answered``, ``timer``, ``full``), as shares of the windows, with the
+   mean members a window of each (``window_closes`` in the report).
 
 Prints one JSON object (also under ``chiprun_out/span_clock/``). Exit 0
 when 1 and 2 hold, 1 when not, the run's own code when the run failed.
@@ -247,6 +251,35 @@ def commits(ring, top: int = 12):
     }
 
 
+def window_closes(ring, lo, hi):
+    """The gateway's batch windows closed in the stretch, by what closed
+    them (the ``closed`` arg of their members' ``gateway.queue`` spans:
+    ``idle``, ``answered``, ``timer``, ``full``): how many, each cause's
+    share of them and its mean members a window. None where no window
+    closed, or where the spans do not say (a tree before the arg)."""
+    from pathway_tpu.internals import flight
+
+    members = collections.Counter()
+    cause = {}
+    for s in ring:
+        if s[1] == "gateway.queue" and lo <= s[3] <= hi:
+            args = flight.args_of(s)
+            window = (args.get("window"), s[3])  # a window's members share its close
+            members[window] += 1
+            cause[window] = args.get("closed")
+    if not cause or None in cause.values():
+        return None
+    by = collections.Counter(cause.values())
+    return {
+        "windows": len(cause),
+        "share": {k: n / len(cause) for k, n in by.most_common()},
+        "members_mean": {
+            k: sum(members[w] for w, c in cause.items() if c == k) / n
+            for k, n in by.most_common()
+        },
+    }
+
+
 def prefill_chunks(ring, hi):
     """What the stretch's ``answer.prefill`` spans say of a chunk's latent
     attention: how many chunks, the cached-row blocks a chunk went over a
@@ -385,6 +418,7 @@ def main() -> int:
             "by_innermost_ring_span_s": idle_by_span(gaps, innermost_timeline(labelled)),
         }
     report["commits"] = commits(ring)
+    report["window_closes"] = window_closes(ring, lo, hi)
     report["prefill"] = prefill_chunks(ring, hi)
     report["ok"] = bool(ok)
     text = json.dumps(report)

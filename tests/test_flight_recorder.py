@@ -270,6 +270,8 @@ _SERVE_CALLS = {
     "on_timeout": (),
     "on_latency_ms": (12.5,),
     "on_window": (4,),
+    # batch windows closed, by trigger (serve_window_closes_total)
+    "on_window_close": ("idle",),
     # serving-through-rollback (ISSUE 9): brownout answers and windows
     # aborted at an epoch rollback
     "on_brownout": (),

@@ -1,5 +1,8 @@
 """Async batching gateway tests (io/http/_server.py): one commit per
-batch window under load, bit-identical parity with the per-request path
+batch window under load, the rule that closes a window (at once on an
+idle route, when the route's last outstanding request is answered, on
+its timer, at max_batch; never after its timer, in live runs and in
+virtual time), bit-identical parity with the per-request path
 on an out-of-order mixed-timeout workload, admission shedding with
 Retry-After, timed-out-request eviction, GET coercion 400s, serve
 metrics, the batched subscribe egress, and the Plan Doctor's
@@ -70,6 +73,57 @@ def _start_run():
     return t
 
 
+def _held_gateway(port, **kw):
+    """``_gateway`` whose pipeline holds a request of value -1 until the
+    returned event is set — a dispatched request still waiting for its
+    response, so the route is busy and later requests collect — and
+    drops values >= 900 (never answered). Returns (subject, url,
+    release, closes): every window close as (trigger, members, ms since
+    the window's first admission)."""
+    release = threading.Event()
+
+    def hold(v):
+        if v < 0:
+            release.wait(30)
+        return v * 3
+
+    subject, url = _gateway(
+        port,
+        pipeline=lambda q: q.filter(pw.this.value < 900).select(
+            result=pw.apply(hold, pw.this.value)
+        ),
+        **kw,
+    )
+    closes = []
+    close = subject._close_window
+
+    def recording(window, by):
+        if window is subject._window:
+            closes.append(
+                (by, len(window),
+                 (time.monotonic_ns() - window[0].admitted_at) / 1e6)
+            )
+        close(window, by)
+
+    subject._close_window = recording
+    return subject, url, release, closes
+
+
+def _until(pred, what, deadline_s=15.0):
+    end = time.monotonic() + deadline_s
+    while not pred():
+        assert time.monotonic() < end, f"timed out: {what}"
+        time.sleep(0.005)
+
+
+def _fire_later(url, values, timeout=15):
+    """``_fire`` on a thread of its own: (thread, its results)."""
+    out = {}
+    t = threading.Thread(target=lambda: out.update(_fire(url, values, timeout)))
+    t.start()
+    return t, out
+
+
 def _fire(url, values, timeout=15):
     """Concurrent closed clients; returns {value: (status, result)}."""
     out = {}
@@ -99,10 +153,12 @@ def _fire(url, values, timeout=15):
 def test_one_commit_per_window_under_load():
     """The pinned tentpole invariant: N concurrent requests coalesce
     into a handful of windows, each window is exactly ONE subject
-    commit, and the occupancy histogram proves multi-request windows."""
+    commit, and the occupancy histogram proves multi-request windows.
+    The route is busy first (a held dispatch): requests coalesce while a
+    step is in flight."""
     port = _next_port()
-    subject, url = _gateway(
-        port, window_ms=60.0, max_batch=64, workers=1
+    subject, url, release, _ = _held_gateway(
+        port, window_ms=2000.0, max_batch=64, workers=1
     )
     commits = [0]
     orig_commit = subject.commit
@@ -115,13 +171,23 @@ def test_one_commit_per_window_under_load():
     _start_run()
 
     n = 48
-    out = _fire(url, range(n))
+    held, held_out = _fire_later(url, [-1])
+    _until(lambda: commits[0] == 1, "the held request's commit")
+    try:
+        load, out = _fire_later(url, range(n))
+        _until(lambda: subject._inflight == n + 1, "every request admitted")
+    finally:
+        release.set()
+    load.join()
+    held.join()
+    assert held_out == {-1: (200, -3)}
     assert all(st == 200 and res == v * 3 for v, (st, res) in out.items())
+    assert len(out) == n
     m = subject.serve_metrics
-    assert m.requests == n
+    assert m.requests == n + 1
     # every request is accounted to exactly one window, and coalescing
-    # engaged: far fewer commits than requests, occupancy sums to n
-    assert m.occupancy.sum == n
+    # engaged: far fewer commits than requests, occupancy sums to n + 1
+    assert m.occupancy.sum == n + 1
     assert m.commits == m.occupancy.total == commits[0]
     assert commits[0] <= n // 4, (commits[0], n)
     # multi-request windows: at least one window carried > 2 requests
@@ -171,10 +237,13 @@ def test_parity_with_per_request_path_out_of_order_mixed_timeouts():
 
 def test_admission_shedding_503_with_retry_after():
     port = _next_port()
-    subject, url = _gateway(
+    subject, url, release, _ = _held_gateway(
         port, window_ms=600.0, max_batch=1000, queue_cap=4
     )
     _start_run()
+    # a held dispatch keeps the route busy: what follows collects
+    held, held_out = _fire_later(url, [-1])
+    _until(lambda: subject._inflight == 1, "the held request")
 
     n = 16
     headers = {}
@@ -202,15 +271,24 @@ def test_admission_shedding_503_with_retry_after():
     threads = [threading.Thread(target=client, args=(v,)) for v in range(n)]
     for t in threads:
         t.start()
+    try:
+        _until(
+            lambda: subject._inflight + subject.serve_metrics.shed == n + 1,
+            "every request admitted or shed",
+        )
+    finally:
+        release.set()
     for t in threads:
         t.join()
+    held.join()
+    assert held_out == {-1: (200, -3)}
 
     ok = [v for v, (st, _) in out.items() if st == 200]
     shed = [v for v, (st, _) in out.items() if st == 503]
     assert len(ok) + len(shed) == n
-    # the 600 ms window holds admitted requests in flight, so the cap
-    # must have shed the overflow — with 503, a Retry-After >= 1s, and
-    # the shed counter agreeing
+    # the held dispatch and the window hold admitted requests in flight,
+    # so the cap must have shed the overflow — with 503, a Retry-After
+    # >= 1s, and the shed counter agreeing
     assert len(shed) >= 1 and len(ok) >= 1
     assert all(h is not None and int(h) >= 1 for h in headers.values())
     assert subject.serve_metrics.shed == len(shed)
@@ -219,30 +297,252 @@ def test_admission_shedding_503_with_retry_after():
 
 
 def test_timed_out_requests_evicted_before_dispatch():
-    """A request that times out while its window is still collecting is
+    """A request that times out before its window is dispatched is
     evicted: the window dispatches empty — no commit, no device work,
-    no occupancy sample."""
+    no occupancy sample. The dispatch path is held first (the first
+    commit waits at a gate), so the request's window queues behind it."""
     port = _next_port()
     subject, url = _gateway(
-        port, window_ms=800.0, max_batch=1000, timeout_s=0.15
+        port, window_ms=800.0, max_batch=1000, timeout_s=0.3
     )
     commits = [0]
+    gate = threading.Event()
     orig_commit = subject.commit
 
-    def counting_commit():
+    def held_commit():
+        gate.wait(30)
         commits[0] += 1
         orig_commit()
 
-    subject.commit = counting_commit
+    subject.commit = held_commit
     _start_run()
 
-    with pytest.raises(urllib.error.HTTPError) as e:
-        _post(url, {"value": 1})
-    assert e.value.code == 504
-    assert subject.serve_metrics.timeouts == 1
-    time.sleep(1.2)  # let the window timer fire and dispatch
-    assert commits[0] == 0
-    assert subject.serve_metrics.occupancy.total == 0
+    held, held_out = _fire_later(url, [1])
+    _until(
+        lambda: subject._window_seq == 1 and subject._windows_q.empty(),
+        "the held request's dispatch",
+    )
+    try:
+        with pytest.raises(urllib.error.HTTPError) as e:
+            _post(url, {"value": 2})
+        assert e.value.code == 504
+    finally:
+        gate.set()
+    held.join()
+    assert held_out[1][0] == 504  # it timed out at the gate too
+    assert subject.serve_metrics.timeouts == 2
+    _until(lambda: subject._windows_q.empty(), "the queued window's dispatch")
+    time.sleep(0.2)
+    # the held request's commit alone: the evicted one's window was empty
+    assert commits[0] == 1
+    assert subject.serve_metrics.occupancy.total == 1
+
+
+_CLOSE_CASES = {
+    # case: (gateway knobs, the closes expected in order, as (trigger, members))
+    "idle": (dict(window_ms=600.0), [("idle", 1)]),
+    "answered": (dict(window_ms=5000.0), [("idle", 1), ("answered", 3)]),
+    "timer": (dict(window_ms=300.0), [("idle", 1), ("timer", 2)]),
+    "zero": (dict(window_ms=0.0), [("timer", 1)] * 4),
+    "full": (
+        dict(window_ms=5000.0, max_batch=2),
+        [("idle", 1), ("full", 2), ("full", 2)],
+    ),
+    "filtered": (
+        dict(window_ms=300.0, timeout_s=2.0), [("idle", 1), ("timer", 1)]
+    ),
+    "evicted": (
+        dict(window_ms=300.0, timeout_s=3.0),
+        [("idle", 1), ("timer", 1), ("timer", 1)],
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CLOSE_CASES))
+def test_window_close_rule(case):
+    """A window closes at the earliest of: at once on a route with no
+    dispatched request still waiting for its response (``idle``); when
+    the route's last outstanding request is answered (``answered``); its
+    ``window_ms`` timer (``timer``); ``max_batch`` (``full``). A request
+    the route cannot account for — filtered and never answered, or
+    parked at a rollback — leaves the timer to decide, never a hang. No
+    window closes later than its timer, and
+    ``serve_window_closes_total{by=...}`` counts each trigger."""
+    knobs, want = _CLOSE_CASES[case]
+    window_ms = knobs["window_ms"]
+    subject, url, release, closes = _held_gateway(_next_port(), **knobs)
+    _start_run()
+    m = subject.serve_metrics
+    try:
+        if case == "idle":
+            # a lone request on an idle route commits at once
+            t0 = time.monotonic()
+            assert _post(url, {"value": 4}) == 12
+            assert time.monotonic() - t0 < window_ms / 1e3
+        elif case == "filtered":
+            # a request the pipeline drops is never answered: the route
+            # reads busy until its deadline, so the next one waits on the
+            # timer — and no longer
+            lost, lost_out = _fire_later(url, [900])
+            _until(lambda: closes, "the dropped request's window")
+            t0 = time.monotonic()
+            assert _post(url, {"value": 5}) == 15
+            assert window_ms / 1e3 <= time.monotonic() - t0 < 2.0
+            lost.join()
+            assert lost_out == {900: (504, None)}
+        else:
+            held, held_out = _fire_later(url, [-1])
+            _until(lambda: closes, "the held request's window")
+            if case == "evicted":
+                _until(lambda: m.commits == 1, "the held request's commit")
+                parked, parked_out = _fire_later(url, [7])
+                _until(lambda: subject._inflight == 2, "the parked request")
+                # a rollback parks the collecting window: its request
+                # still awaits an answer the route cannot account for
+                assert subject.abort_windows_for_rollback() == 1
+                release.set()
+                held.join()
+                _until(lambda: len(closes) == 2, "the parked window's timer")
+                t0 = time.monotonic()
+                assert _post(url, {"value": 5}) == 15
+                assert window_ms / 1e3 <= time.monotonic() - t0 < 3.0
+                parked.join()
+                assert parked_out == {7: (504, None)}
+                assert m.commits == 2  # the parked window committed nothing
+            else:
+                values = {"answered": [1, 2, 3], "timer": [1, 2],
+                          "zero": [1, 2, 3], "full": [1, 2, 3, 4]}[case]
+                rest, out = _fire_later(url, values)
+                _until(
+                    lambda: subject._inflight == len(values) + 1,
+                    "every request admitted",
+                )
+                # while the held request is in flight nothing closes but
+                # the timer and max_batch
+                _until(lambda: len(closes) == len(want) - (case == "answered"),
+                       "the closes made while the request is held")
+                release.set()
+                rest.join()
+                assert out == {v: (200, v * 3) for v in values}
+            held.join()
+            assert held_out == {-1: (200, -3)}
+    finally:
+        release.set()
+    assert [(by, n) for by, n, _ in closes] == want
+    for by, n, ms in closes:
+        # never later than the timer the window was armed with
+        assert ms <= window_ms + 300.0, (by, ms)
+        if by == "timer" and window_ms > 0:
+            assert ms >= window_ms * 0.98, ms
+    from pathway_tpu.internals.monitoring import SERVE_WINDOW_CLOSE_CAUSES
+
+    stats = ProberStats()
+    stats.mount_serve_metrics(m)
+    text = stats.render_openmetrics()
+    assert "# TYPE serve_window_closes_total counter" in text
+    for by in SERVE_WINDOW_CLOSE_CAUSES:
+        n = sum(1 for b, _ in want if b == by)
+        assert m.window_closes[by] == n
+        assert f'serve_window_closes_total{{route="/",by="{by}"}} {n}' in text
+
+
+@pytest.mark.parametrize(
+    "rate, service_ms, window_ms, max_batch, seen",
+    [
+        # the serve cells: questions at 40/s, a step of 10 + 9.3 ms a question
+        (40.0, (10.0, 9.3), 25.0, 32, {"idle", "answered", "timer"}),
+        # past capacity: windows fill
+        (150.0, (10.0, 9.3), 25.0, 4, {"full"}),
+        # the answer cells: a generation of 350 ms at 1.4/s
+        (1.4, (350.0, 0.0), 25.0, 32, {"idle", "timer"}),
+    ],
+)
+def test_window_never_closes_after_its_timer(
+    monkeypatch, rate, service_ms, window_ms, max_batch, seen
+):
+    """The rule in virtual time: 400 Poisson arrivals into a route whose
+    engine answers one window at a time in a + b x members. Every window
+    closes no later than ``window_ms`` after its first request — the
+    timer that alone closed it before — or at ``max_batch``; an ``idle``
+    close holds its one request and waits nothing; every request is
+    answered and the route ends with nothing in flight."""
+    import heapq
+    import random
+    import types
+
+    from pathway_tpu.io.http import _server
+
+    class Loop:
+        def __init__(self):
+            self.now, self.seq, self.events = 0.0, 0, []
+
+        def at(self, t, fn, *args):
+            self.seq += 1
+            handle = types.SimpleNamespace(cancelled=False)
+            handle.cancel = lambda: setattr(handle, "cancelled", True)
+            heapq.heappush(self.events, (t, self.seq, handle, fn, args))
+            return handle
+
+        def call_later(self, delay, fn, *args):
+            return self.at(self.now + delay, fn, *args)
+
+    loop = Loop()
+    monkeypatch.setattr(
+        _server, "asyncio", types.SimpleNamespace(get_event_loop=lambda: loop)
+    )
+    subject, _ = _gateway(_next_port(), window_ms=window_ms, max_batch=max_batch)
+    subject._gateway_up = True  # no dispatch workers: the loop below is the engine
+    admitted, closes = {}, []
+    close = subject._close_window
+
+    def recording(window, by):
+        if window is subject._window:
+            closes.append((by, len(window), loop.now - admitted[window[0].key]))
+        close(window, by)
+
+    subject._close_window = recording
+
+    def arrive(key):
+        admitted[key] = loop.now
+        subject._inflight += 1
+        subject._join_window(_server._PendingRequest(key, {"value": key}, None))
+
+    def answer(window):
+        for p in window:
+            subject._leave(p.key)
+
+    rng = random.Random(39)
+    t = 0.0
+    for key in range(1, 401):
+        t += rng.expovariate(rate)
+        loop.at(t, arrive, key)
+    a, b = (ms / 1e3 for ms in service_ms)
+    free = 0.0
+    while loop.events:
+        loop.now, _, handle, fn, args = heapq.heappop(loop.events)
+        if handle.cancelled:
+            continue
+        fn(*args)
+        while not subject._windows_q.empty():
+            window = subject._windows_q.get_nowait()
+            free = max(loop.now, free) + a + b * len(window)
+            loop.at(free, answer, window)
+
+    assert subject._inflight == 0 and not subject._window
+    assert sum(n for _, n, _ in closes) == len(admitted) == 400
+    for by, n, wait in closes:
+        assert wait <= window_ms / 1e3 + 1e-9, (by, n, wait)
+        assert (n == max_batch) == (by == "full"), (by, n)
+        if by == "idle":
+            assert n == 1 and wait == 0.0
+        if by == "timer":
+            assert wait == pytest.approx(window_ms / 1e3)
+    causes = {by for by, _, _ in closes}
+    assert seen <= causes, causes
+    assert dict(subject.serve_metrics.window_closes) == {
+        by: sum(1 for c, _, _ in closes if c == by)
+        for by in ("idle", "answered", "timer", "full")
+    }
 
 
 def test_get_coercion_failure_returns_400_naming_field():
@@ -948,3 +1248,43 @@ def test_memory_brownout_sheds_503_then_recovers():
         assert _post(url, {"value": 7}) == 7 * 3
     finally:
         _memory.install(None)
+
+
+def test_span_clock_check_counts_window_closes(monkeypatch):
+    """``scripts/span_clock_check.py`` reads what closed each window of a
+    traced stretch from its ``gateway.queue`` spans' ``closed`` arg: the
+    windows closed inside the stretch, each trigger's share and its mean
+    members; nothing from spans that do not say."""
+    import importlib.util
+    import os
+    import sys
+
+    monkeypatch.setattr(sys, "path", list(sys.path))  # the script adds to it
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts", "span_clock_check.py",
+    )
+    spec = importlib.util.spec_from_file_location("span_clock_check", path)
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+
+    def queued(key, closed_at, window, closed):
+        return (key, "gateway.queue", closed_at - 5, closed_at, 0, None, key,
+                "window", window, "closed", closed)
+
+    ring = [
+        queued(1, 100, 1, "idle"),
+        queued(2, 200, 2, "answered"),
+        queued(3, 200, 2, "answered"),
+        queued(4, 300, 3, "timer"),
+        queued(5, 5000, 4, "timer"),  # closed after the stretch
+        (6, "gateway.pickup", 100, 104, 0, None, None, "window", 1),
+    ]
+    assert script.window_closes(ring, 0, 1000) == {
+        "windows": 3,
+        "share": {"idle": 1 / 3, "answered": 1 / 3, "timer": 1 / 3},
+        "members_mean": {"idle": 1.0, "answered": 2.0, "timer": 1.0},
+    }
+    bare = [(1, "gateway.queue", 0, 10, 0, None, 1, "window", 1)]
+    assert script.window_closes(bare, 0, 100) is None
+    assert script.window_closes([], 0, 100) is None

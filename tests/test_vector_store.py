@@ -386,10 +386,24 @@ def test_inputs_over_http_empty_corpus_and_two_questions_in_one_window():
     def ask(name, **filters):
         answers[name] = client().get_input_files(**filters)
 
-    # two questions sent together share a 400 ms window unless the
+    # a window collects while the route has a request in flight: hold the
+    # route's dispatch (its commit waits at a gate) with a first question,
+    # then two questions sent together share a 400 ms window unless the
     # machine holds one of them back: try again then
+    gate = threading.Event()
+    commit = inputs_route.commit
+
+    def held_commit():
+        gate.wait(30)
+        commit()
+
+    inputs_route.commit = held_commit
     for _ in range(5):
+        gate.clear()
         before = (metrics.requests, metrics.commits)
+        held = threading.Thread(target=ask, args=("held",))
+        held.start()
+        _wait_for(lambda: inputs_route._inflight == 1, "the held question")
         askers = [
             threading.Thread(target=ask, args=("all",)),
             threading.Thread(
@@ -401,13 +415,19 @@ def test_inputs_over_http_empty_corpus_and_two_questions_in_one_window():
         ]
         for t in askers:
             t.start()
-        for t in askers:
+        try:
+            _wait_for(lambda: inputs_route._inflight == 3, "both questions")
+        finally:
+            gate.set()
+        for t in askers + [held]:
             t.join(60)
-        assert metrics.requests == before[0] + 2
-        if metrics.commits == before[1] + 1:
+        assert metrics.requests == before[0] + 3
+        if metrics.commits == before[1] + 2:
             break
+    inputs_route.commit = commit
     # one gateway window, one commit, two answers: each its own whole list
-    assert metrics.commits == before[1] + 1
+    assert metrics.commits == before[1] + 2
+    assert sorted(m["modified_at"] for m in answers["held"]) == [0, 1, 2, 3, 4, 5]
     assert sorted(m["modified_at"] for m in answers["all"]) == [0, 1, 2, 3, 4, 5]
     assert sorted(m["modified_at"] for m in answers["odd"]) == [1, 3]
 
