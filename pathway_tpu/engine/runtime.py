@@ -23,6 +23,7 @@ from typing import Any, Callable
 from pathway_tpu.engine.nodes import Node, SourceNode
 from pathway_tpu.engine.scope import Scope
 from pathway_tpu.engine.stream import Delta, is_native_batch
+from pathway_tpu.engine.stream import array_copies as _array_copies
 from pathway_tpu.internals import device as _device
 from pathway_tpu.internals import faults as _faults
 from pathway_tpu.internals import flight as _flight
@@ -559,13 +560,14 @@ class Runtime:
             "engine.step", trace_id=time, t=time, nodes=0, short_nodes=0,
             short_ns=0,
         )
-        hashes0 = _json_hashes()
+        hashes0, copies0 = _json_hashes(), _array_copies()
         with step:
             try:
                 self._run_step(time)
             finally:
                 self._step_span = prev
                 step.args["json_hashes"] = _json_hashes() - hashes0
+                step.args["array_copies"] = _array_copies() - copies0
 
     def _run_step(self, time: int) -> None:
         """Run all nodes with pending input at `time`, in topo order.

@@ -11,6 +11,9 @@ multiplicity exactly one in table-land).
 
 from __future__ import annotations
 
+import operator
+import threading as _threading
+import zlib as _zlib
 from collections import defaultdict
 from typing import Any, Iterable
 
@@ -23,12 +26,86 @@ import numpy as _np
 from pathway_tpu.internals.api import Json as _Json
 
 
+# ndarray values frozen by copying their buffer (an object dtype, or a
+# layout other than C order): the misses only, a C-ordered array is keyed
+# in place. The ring's ``engine.step`` span carries the count's increase
+# over a step as ``array_copies`` (engine/runtime.py).
+_array_copies = 0
+_array_copies_lock = _threading.Lock()  # any thread may freeze a row
+
+
+def array_copies() -> int:
+    return _array_copies
+
+
+def _count_array_copy() -> None:
+    global _array_copies
+    with _array_copies_lock:
+        _array_copies += 1
+
+
+class FrozenArray:
+    """The frozen form of an ndarray value: the array itself, keyed by its
+    bytes without a copy of them.
+
+    Equal when shape, dtype and raw bytes are, as the bytes the form
+    before it copied were: the same NaN bits are equal, ``-0.0`` and
+    ``0.0`` are not. The hash is a crc32 over the buffer, computed on
+    first use and kept. It holds a reference, so it relies on an engine
+    value being immutable once it has entered the dataflow, as a ``Json``
+    does. The array is C-contiguous (``freeze_value`` makes it so)."""
+
+    __slots__ = ("array", "_hash")
+
+    def __init__(self, array: _np.ndarray):
+        self.array = array
+        self._hash = None
+
+    def __hash__(self):
+        h = self._hash
+        if h is None:
+            h = self._hash = _zlib.crc32(self.array)
+        return h
+
+    def __eq__(self, other):
+        if type(other) is not FrozenArray:
+            return NotImplemented
+        a, b = self.array, other.array
+        if a is b:
+            return True
+        # met on a hash match only, as a retraction finding its row
+        return (
+            a.shape == b.shape
+            and a.dtype.str == b.dtype.str
+            and a.tobytes() == b.tobytes()
+        )
+
+    def __reduce__(self):
+        return (FrozenArray, (self.array,))
+
+    def __repr__(self):
+        return f"FrozenArray({self.array!r})"
+
+
+def _copied_form(v: _np.ndarray) -> tuple:
+    # what an ndarray froze to before FrozenArray, and what shard digests
+    # still serialise
+    return ("__ndarray__", v.shape, v.dtype.str, v.tobytes())
+
+
 def freeze_value(v: Any) -> Any:
     """Hashable, equality-faithful stand-in for any engine value (ndarrays,
     Json, nested tuples) — used to key multiset state so retractions cancel
     insertions exactly."""
     if isinstance(v, _np.ndarray):
-        return ("__ndarray__", v.shape, v.dtype.str, v.tobytes())
+        if v.dtype.hasobject:
+            # its bytes are pointers: kept as the copied form they were
+            _count_array_copy()
+            return _copied_form(v)
+        if not v.flags.c_contiguous:
+            _count_array_copy()
+            v = _np.ascontiguousarray(v)
+        return FrozenArray(v)
     if isinstance(v, _Json):
         # hashable by construction: no probe, which would serialise it
         return v
@@ -56,6 +133,22 @@ def freeze_row(row: Row) -> tuple:
         return row
     except TypeError:
         return tuple(freeze_value(v) for v in row)
+
+
+def freeze_for_digest(v: Any) -> Any:
+    """``freeze_value(v)`` as the shard digests serialise it
+    (``procgroup.shard_hash``): each ``FrozenArray`` as the copied form,
+    so the digests read the bytes they read before that class."""
+    return _arrays_copied(freeze_value(v))
+
+
+def _arrays_copied(fv: Any) -> Any:
+    t = type(fv)
+    if t is FrozenArray:
+        return _copied_form(fv.array)
+    if t is tuple:
+        return tuple(map(_arrays_copied, fv))
+    return fv
 
 
 def _consolidate_py(deltas: Iterable[Delta]) -> list[Delta]:
@@ -200,7 +293,7 @@ class MultisetState:
 
     def apply_one(self, key: Key, row: Row, diff: int) -> None:
         d = self.data[key]
-        fr = freeze_row(row) if not _row_hashable(row) else row
+        fr = freeze_row(row)
         entry = d.get(fr)
         if entry is None:
             entry = [row, 0]
@@ -227,12 +320,48 @@ class MultisetState:
             yield key, [(entry[0], entry[1]) for entry in d.values()]
 
 
-def _row_hashable(row: Row) -> bool:
+def refreeze(state: Any) -> Any:
+    """Operator state loaded from a snapshot, with each ndarray key in the
+    copied form (``_copied_form``) that a tree before ``FrozenArray``
+    wrote turned into a ``FrozenArray``: a row is then keyed as this
+    tree's freezing keys it, and a retraction after the restore finds
+    it. Lists, dicts, sets and ``MultisetState`` are rewritten in place,
+    a tuple anew where it changes; anything else is returned as it is."""
+    t = type(state)
+    if t is tuple:
+        if len(state) == 4 and type(state[0]) is str and state[0] == "__ndarray__":
+            thawed = _thawed(*state[1:])
+            if thawed is not None:
+                return thawed
+        out = tuple(map(refreeze, state))
+        return state if all(map(operator.is_, out, state)) else out
+    if t is MultisetState:
+        refreeze(state.data)
+    elif isinstance(state, list):
+        state[:] = map(refreeze, state)
+    elif isinstance(state, dict):
+        items = [(refreeze(k), refreeze(v)) for k, v in state.items()]
+        state.clear()
+        state.update(items)
+    elif isinstance(state, set):
+        items = [refreeze(x) for x in state]
+        state.clear()
+        state.update(items)
+    return state
+
+
+def _thawed(shape: Any, dtype: Any, buf: Any) -> FrozenArray | None:
+    """The ``FrozenArray`` of a copied form's parts, or None where they are
+    not an ndarray's (or are an object array's, which keeps that form)."""
+    if type(shape) is not tuple or type(dtype) is not str or type(buf) is not bytes:
+        return None
     try:
-        hash(row)
-        return True
-    except TypeError:
-        return False
+        dt = _np.dtype(dtype)
+        if dt.hasobject:
+            return None
+        return FrozenArray(_np.frombuffer(buf, dtype=dt).reshape(shape))
+    except (TypeError, ValueError):
+        return None
 
 
 def negate(deltas: Iterable[Delta]) -> list[Delta]:

@@ -125,7 +125,7 @@ from typing import Any
 
 from pathway_tpu.internals.api import Pointer, _value_to_bytes
 from pathway_tpu.internals import faults as _faults
-from pathway_tpu.engine.stream import freeze_value, is_native_batch
+from pathway_tpu.engine.stream import freeze_for_digest, is_native_batch
 
 # protocol decisions (handshake acceptance, liveness verdicts, the
 # goodbye-vs-crash classification) come from the shared transition table
@@ -382,7 +382,7 @@ def shard_hash(value: Any) -> int:
     the same digest through ``protocol.shard_owner`` at the new world
     size: same bytes, same blake2b, different modulus — a pure
     re-bucketing, no re-hash of live data."""
-    b = _value_to_bytes(freeze_value(value))
+    b = _value_to_bytes(freeze_for_digest(value))
     return int.from_bytes(
         hashlib.blake2b(b, digest_size=8).digest(), "little"
     )
@@ -405,7 +405,7 @@ def stable_shard_many(values, world: int) -> list[int]:
     fallback path of ExchangeNode routes whole batches through this."""
     b2b = hashlib.blake2b
     vtb = _value_to_bytes
-    fz = freeze_value
+    fz = freeze_for_digest
     fb = int.from_bytes
     return [
         fb(b2b(vtb(fz(v)), digest_size=8).digest(), "little") % world

@@ -22,6 +22,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any
 
+from pathway_tpu.engine.stream import refreeze
 from pathway_tpu.internals import faults as _faults
 
 # Trust boundary: anyone able to write the persistence root can influence
@@ -56,6 +57,9 @@ _SAFE_NUMPY = {
     ("numpy._core.numeric", "_frombuffer"),
 }
 
+# engine classes a value may be stored as, by name
+_SAFE_CLASSES = {("pathway_tpu.engine.stream", "FrozenArray")}
+
 
 class _SafeUnpickler(pickle.Unpickler):
     def find_class(self, module, name):
@@ -67,7 +71,7 @@ class _SafeUnpickler(pickle.Unpickler):
                 module == "numpy" and name.startswith(("int", "uint", "float", "bool", "complex"))
             ):
                 return super().find_class(module, name)
-        elif module in _SAFE_MODULES:
+        elif module in _SAFE_MODULES or (module, name) in _SAFE_CLASSES:
             return super().find_class(module, name)
         raise pickle.UnpicklingError(
             f"persistence journal refuses to resolve {module}.{name}; "
@@ -423,7 +427,11 @@ class PersistenceManager:
 
     def load_operator_snapshot(self, *, key: str = "operator_snapshot"):
         raw = self.backend.read(key)
-        return pickle.loads(raw) if raw else None
+        if not raw:
+            return None
+        node_states, subject_states, fingerprint = pickle.loads(raw)
+        # state keys as this tree freezes them, whichever tree wrote them
+        return refreeze(node_states), subject_states, fingerprint
 
     # -- multi-process consistent cut (reference: tracker.rs:47,160-193 —
     # per-worker persistent storage; a snapshot timestamp only advances

@@ -22,8 +22,8 @@ promises:
    and the main loop's waits are in the ring only).
 
 4. what a commit of documents costs the engine's thread: the steps of
-   the stretch, ``json_hashes`` a step and every node's self time
-   (``commits`` in the report);
+   the stretch, ``json_hashes`` and ``array_copies`` a step and every
+   node's self time (``commits`` in the report);
 5. what a prefill chunk's latent attention went over: the chunks of the
    stretch, the cached-row ``blocks`` a chunk, and the ``kernel`` the
    ``answer.prefill`` site's first dispatch says its attention was
@@ -221,8 +221,8 @@ def idle_by_span(gaps, timeline, outside="outside every span"):
 def commits(ring, top: int = 12):
     """The stretch's steps that ran at least ten recorded nodes (a commit
     of documents, not a lone question): how many, their median length,
-    ``json_hashes`` a step (None from a tree whose step does not say) and
-    the nodes' median self ms a commit."""
+    ``json_hashes`` and ``array_copies`` a step (each None from a tree
+    whose step does not say) and the nodes' median self ms a commit."""
     from pathway_tpu.internals import flight
 
     beneath = collections.Counter()
@@ -239,13 +239,17 @@ def commits(ring, top: int = 12):
     for s in steps:
         for label, ns in nodes[s[6]]:
             by_label[label].append(ns * 1e-6)
-    hashes = [flight.args_of(s).get("json_hashes") for s in steps]
+
+    def per_step(arg):
+        n = [flight.args_of(s).get(arg) for s in steps]
+        return None if None in n else {"min": min(n), "median": statistics.median(n), "max": max(n)}
+
     ms = {k: statistics.median(v) for k, v in by_label.items() if len(v) * 2 > len(steps)}
     return {
         "steps": len(steps),
         "step_ms_median": statistics.median([(s[3] - s[2]) * 1e-6 for s in steps]),
-        "json_hashes": None if None in hashes
-        else {"min": min(hashes), "median": statistics.median(hashes), "max": max(hashes)},
+        "json_hashes": per_step("json_hashes"),
+        "array_copies": per_step("array_copies"),
         "nodes_self_ms_sum": sum(ms.values()),
         "node_self_ms_median": dict(sorted(ms.items(), key=lambda kv: -kv[1])[:top]),
     }

@@ -22,7 +22,7 @@ if __name__ == "__main__":
 
 from pathway_tpu.engine import nodes as N
 from pathway_tpu.engine.stream import (
-    MultisetState, consolidate, freeze_row, freeze_value, negate,
+    FrozenArray, MultisetState, consolidate, freeze_row, freeze_value, negate,
 )
 from pathway_tpu.internals.api import Json, json_hashes
 
@@ -123,7 +123,8 @@ def test_freeze_value_of_a_json_is_the_object_and_serialises_nothing():
     # a row made unhashable by an ndarray: its Json is kept as it is
     row = (j, "text", np.arange(3, dtype=np.float32))
     fr, n = _computed(lambda: freeze_row(row))
-    assert fr[0] is j and fr[1] == "text" and fr[2][0] == "__ndarray__"
+    assert fr[0] is j and fr[1] == "text" and type(fr[2]) is FrozenArray
+    assert fr[2].array is row[2]
     assert n == 1  # the row's own hash reached the Json before the array
     assert _computed(lambda: freeze_row(row))[1] == 0
 

@@ -395,7 +395,8 @@ def test_step_carries_json_hashes(pipeline):
     """``json_hashes`` on ``engine.step``: the Json values the process
     serialised to hash over the step (a kept hash is not counted), an int
     beside ``nodes`` / ``short_nodes`` / ``short_ns`` in the one flat
-    tuple a span leaves behind."""
+    tuple a span leaves behind; then ``array_copies``, the ndarray values
+    frozen by a copy of their bytes."""
     lo = time.monotonic_ns()
     pipeline.ingest(64, 16)  # documents carry ``_metadata``, a Json
     spans = flight.spans_between(lo, time.monotonic_ns())
@@ -404,8 +405,12 @@ def test_step_carries_json_hashes(pipeline):
         s for s in _named(spans, "engine.step") if s[S_TRACE] == add[S_TRACE]
     ]
     args = _args(step)
-    assert list(args) == ["t", "nodes", "short_nodes", "short_ns", "json_hashes"]
+    assert list(args) == [
+        "t", "nodes", "short_nodes", "short_ns", "json_hashes", "array_copies",
+    ]
     assert type(args["json_hashes"]) is int
+    # the documents' vectors are keyed where they lie, none copied
+    assert args["array_copies"] == 0
     # at least the chunk's Json, at most the three made a document
     assert 16 <= args["json_hashes"] <= 3 * 16
     assert _is_flat(step)
